@@ -14,9 +14,18 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 CACHE = os.environ.get("BENCH_CACHE", "results/bench_cache")
 
 
+def _device_tag() -> str:
+    """Platform and device kind, part of every cache key: a setup
+    pickled on one backend (a CPU run's trained model and index) must
+    never be loaded by a run on another."""
+    import jax
+    dev = jax.devices()[0]
+    return f"{dev.platform}_{dev.device_kind}".replace(" ", "-")
+
+
 def cached(name, builder):
     os.makedirs(CACHE, exist_ok=True)
-    path = os.path.join(CACHE, name + ".pkl")
+    path = os.path.join(CACHE, f"{name}_{_device_tag()}.pkl")
     if os.path.exists(path):
         with open(path, "rb") as f:
             return pickle.load(f)

@@ -24,6 +24,7 @@ def run(verbose=True):
     import jax.numpy as jnp
     from repro.core import lsh as lsh_mod
     from repro.core.pv_dbow import corpus_pairs
+    from repro.kernels.common import word_major
     from repro.kernels.hamming import ops as hops
 
     setup = text_setup(tag="wiki")
@@ -64,8 +65,9 @@ def run(verbose=True):
     db = index.shard_sig
     us_jnp = _time(lambda: lsh_mod.hamming_similarity(
         jnp.asarray(q), jnp.asarray(db), index.bits, 8.0))
+    db_wm = word_major(db)
     us_kernel = _time(lambda: hops.hamming_similarity(
-        jnp.asarray(q), jnp.asarray(db), index.bits, temperature=8.0))
+        jnp.asarray(q), db_wm, index.bits, temperature=8.0))
     csv_row("query_similarity_jnp", us_jnp, f"n_shards={db.shape[0]}")
     csv_row("query_similarity_kernel_interpret", us_kernel,
             f"n_shards={db.shape[0]}")

@@ -28,10 +28,9 @@ import os
 from typing import Dict
 
 from benchmarks.roofline import (
-    HBM_BW,
-    LINK_BW,
-    PEAK_FLOPS,
+    DRYRUN_KIND,
     analyze_record,
+    chip_peaks,
     markdown_table,
 )
 
@@ -99,12 +98,15 @@ def roofline_section(recs: Dict[str, Dict]) -> str:
     rows = [analyze_record(r) for r in recs.values()]
     rows = [r for r in rows if r]
     rows.sort(key=lambda r: (r["arch"], r["shape"]))
+    peaks = chip_peaks(DRYRUN_KIND)
     lines = [
         "## §Roofline",
         "",
         f"Terms per device (the compiled SPMD module is the per-device "
-        f"program): compute = HLO_FLOPs/{PEAK_FLOPS:.0e}, memory = "
-        f"HLO_bytes/{HBM_BW:.0e}, collective = coll_bytes/{LINK_BW:.0e}.",
+        f"program, compiled for {DRYRUN_KIND}): compute = "
+        f"HLO_FLOPs/{peaks['flops']:.0e}, memory = "
+        f"HLO_bytes/{peaks['hbm_bw']:.0e}, collective = "
+        f"coll_bytes/{peaks['link_bw']:.0e}.",
         "HLO totals come from the two-point depth probe (unrolled 1- and",
         "2-layer compiles) because XLA cost_analysis counts while-loop",
         "bodies once.  Notes: (1) the CPU backend's HLO is less fused",
@@ -449,7 +451,8 @@ def serve_section(serve: Dict) -> str:
         from benchmarks.roofline import analyze_megascan, megascan_table
         rendered.add("megascan")
         meas = mg.get("measured") or {}
-        ds = mg.get("dispatch_share") or {}
+        ds = mg.get("dispatch_share")
+        ds = ds if isinstance(ds, dict) else {}     # "not measured" off-chip
         launches = mg.get("launches") or {}
         jobs = mg.get("host_megascan_jobs")
         lines += [

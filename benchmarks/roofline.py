@@ -1,7 +1,10 @@
 """Roofline analysis from the dry-run artifacts (EXPERIMENTS.md §Roofline).
 
-Hardware model (TPU v5e per assignment):
-    peak 197 TFLOP/s bf16/chip, 819 GB/s HBM/chip, ~50 GB/s/link ICI.
+Hardware model: the published peaks of the chip a record ran on (or, for
+dry-run records, was compiled for), looked up by ``device_kind`` in
+``PEAKS``.  A device missing from the table is an error, never a
+default.  TPU v5e: 197 TFLOP/s bf16/chip, 819 GB/s HBM/chip, ~50
+GB/s/link ICI.
 
 All dry-run quantities are per-device (the compiled SPMD module is the
 per-device program; probe totals reconstruct while-loop trip counts), so
@@ -31,9 +34,27 @@ import os
 import sys
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+PEAKS: Dict[str, Dict] = {
+    "TPU v5 lite": dict(
+        flops=197e12, hbm_bw=819e9, link_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI over 4 links"),
+}
+#: The chip the dry-run records were compiled for.
+DRYRUN_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> Dict:
+    """Peaks of ``device_kind``; raises for a chip not in ``PEAKS``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add "
+            f"them to benchmarks.roofline.PEAKS with their source") from None
+
+
 # Per-launch dispatch overhead model: host-side Pallas/XLA launch plus
 # the HBM<->VMEM turnaround a fresh kernel pays before its pipeline
 # fills.  ~8 us is the conventional small-kernel launch cost on current
@@ -63,11 +84,13 @@ def analyze_megascan(rec: Dict) -> Dict:
     (scoring + one-hot reduction flops).  With double-buffered prefetch
     memory and compute overlap, so the modeled wall is
     ``dispatch + max(memory, compute)`` and ``overlap_ratio`` says how
-    much of the smaller stream the prefetch hides."""
+    much of the smaller stream the prefetch hides.  Peaks are those of
+    the record's ``device_kind``."""
+    peaks = chip_peaks(rec["device_kind"])
     launches = int(rec.get("launches", 1))
     t_dispatch = launches * DISPATCH_S
-    t_memory = float(rec.get("bytes_streamed", 0)) / HBM_BW
-    t_compute = float(rec.get("flops", 0)) / PEAK_FLOPS
+    t_memory = float(rec.get("bytes_streamed", 0)) / peaks["hbm_bw"]
+    t_compute = float(rec.get("flops", 0)) / peaks["flops"]
     terms = {"dispatch": t_dispatch, "memory": t_memory,
              "compute": t_compute}
     dominant = max(terms, key=terms.get)
@@ -89,6 +112,7 @@ def analyze_megascan(rec: Dict) -> Dict:
         "bytes_streamed": int(rec.get("bytes_streamed", 0)),
         "measured_wall_s": rec.get("measured_wall_s"),
         "double_buffer": bool(rec.get("double_buffer", False)),
+        "device_kind": rec["device_kind"],
     }
 
 
@@ -108,9 +132,10 @@ def analyze_record(rec: Dict) -> Optional[Dict]:
         bytes_dev = rec["bytes_accessed"]
         coll_dev = rec["collective_bytes_total"]
     n_dev = rec["n_devices"]
-    t_compute = flops_dev / PEAK_FLOPS
-    t_memory = bytes_dev / HBM_BW
-    t_coll = coll_dev / LINK_BW
+    peaks = chip_peaks(DRYRUN_KIND)
+    t_compute = flops_dev / peaks["flops"]
+    t_memory = bytes_dev / peaks["hbm_bw"]
+    t_coll = coll_dev / peaks["link_bw"]
     terms = {"compute": t_compute, "memory": t_memory,
              "collective": t_coll}
     dominant = max(terms, key=terms.get)
@@ -118,7 +143,8 @@ def analyze_record(rec: Dict) -> Optional[Dict]:
     useful_ratio = mf / flops_dev if flops_dev else 0.0
     # roofline fraction: useful work at peak vs modeled step time
     step_time = max(terms.values())
-    roofline_frac = (mf / PEAK_FLOPS) / step_time if step_time else 0.0
+    roofline_frac = ((mf / peaks["flops"]) / step_time
+                     if step_time else 0.0)
     return {
         "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
         "compute_s": t_compute, "memory_s": t_memory,

@@ -18,6 +18,8 @@ def main() -> None:
                     help="smaller query counts (CI mode)")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (aggregation_bench, dir_bench, index_bench,
                             recsys_bench, roofline, sensitivity_bench,
                             serve_bench)

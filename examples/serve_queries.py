@@ -118,6 +118,8 @@ def main():
     budget_on = (args.budget_err is not None
                  or args.budget_latency_ms is not None)
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.core.allocation import allocate_corpus
     from repro.core.index import build_index
     from repro.core.lsh import LSHConfig

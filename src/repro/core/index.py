@@ -52,6 +52,7 @@ from repro.core import lsh as lsh_mod
 from repro.core import pv_dbow as pv
 from repro.core.sampling import similarity_probabilities
 from repro.data.store import ShardedCorpus, atomic_savez
+from repro.kernels.common import WordMajorSigs, word_major
 from repro.runtime.generation import GenerationClock
 
 
@@ -141,6 +142,20 @@ class ApproxIndex:
             cache[role] = (2.0 * bits - 1.0).astype(np.float32)
         return cache[role]
 
+    def _kernel_sigs(self, target_sig: np.ndarray,
+                     role: str) -> "WordMajorSigs":
+        """Device-resident word-major signatures (the kernels' layout,
+        ``kernels/common.WordMajorSigs``) for the kernel routes, made and
+        uploaded once per target set; keyed by ``role`` as in
+        ``_signs_cache``."""
+        cache = getattr(self, "_kernel_sig", None)
+        if cache is None:
+            cache = {}
+            object.__setattr__(self, "_kernel_sig", cache)
+        if role not in cache:
+            cache[role] = word_major(target_sig)
+        return cache[role]
+
     def _exp_sim(self, vec: np.ndarray, target_sig: np.ndarray,
                  target_vecs: np.ndarray, role: str) -> np.ndarray:
         """exp(beta * cos) similarity of one vector against a signed set."""
@@ -166,7 +181,7 @@ class ApproxIndex:
             if self.use_kernel:
                 from repro.kernels.hamming import ops as hamming_ops
                 sims = hamming_ops.hamming_similarity(
-                    qsig, jnp.asarray(target_sig), self.bits,
+                    qsig, self._kernel_sigs(target_sig, role), self.bits,
                     temperature=self.temperature)
             else:
                 sims = lsh_mod.hamming_similarity(
@@ -194,7 +209,8 @@ class ApproxIndex:
             if self.use_kernel:
                 from repro.kernels.asym import ops as asym_ops
                 sims = asym_ops.asym_exp_similarity(
-                    jnp.asarray(vecs, jnp.float32), jnp.asarray(target_sig),
+                    jnp.asarray(vecs, jnp.float32),
+                    self._kernel_sigs(target_sig, role),
                     jnp.asarray(self.planes), self.bits,
                     temperature=self.temperature)
                 return np.asarray(sims, np.float64)
@@ -212,7 +228,7 @@ class ApproxIndex:
             if self.use_kernel:
                 from repro.kernels.hamming import ops as hamming_ops
                 sims = hamming_ops.hamming_similarity(
-                    qsig, jnp.asarray(target_sig), self.bits,
+                    qsig, self._kernel_sigs(target_sig, role), self.bits,
                     temperature=self.temperature)
             else:
                 sims = lsh_mod.hamming_similarity(
@@ -286,20 +302,19 @@ class ApproxIndex:
         return self._sum_docs_to_shards_batch(doc_sims)
 
     def _fused_device_arrays(self) -> dict:
-        """Device-resident operands for the fused kernels, uploaded once
-        and cached: re-running ``jnp.asarray`` on the [n_docs, W] doc
-        signature database per batch would push the whole index
-        host->device every ~2 ms serving window — traffic that dwarfs
-        the [B, n_docs] intermediate the fusion saves."""
+        """Device-resident operands for the fused segment-sum kernels,
+        uploaded once in the kernels' word-major layout and cached:
+        re-uploading (or re-laying-out) the [n_docs, W] doc signature
+        database per batch would push the whole index through HBM every
+        ~2 ms serving window — traffic that dwarfs the [B, n_docs]
+        intermediate the fusion saves."""
         dev = getattr(self, "_fused_dev", None)
         if dev is None:
             dev = dict(planes=jnp.asarray(self.planes, jnp.float32))
-            if self.doc_sig is not None:
-                dev["doc_sig"] = jnp.asarray(self.doc_sig)
             if self._doc_shard_ids is not None:
                 _, _, _, seg_sorted, sig_sorted = self._shard_sorted_docs()
                 dev["seg"] = jnp.asarray(seg_sorted)
-                dev["sig"] = jnp.asarray(sig_sorted)
+                dev["sig"] = word_major(sig_sorted)
             object.__setattr__(self, "_fused_dev", dev)
         return dev
 
@@ -343,7 +358,8 @@ class ApproxIndex:
             from repro.kernels.asym import ops as asym_ops
             dev = self._fused_device_arrays()
             idx, vals = asym_ops.asym_exp_topk(
-                jnp.asarray(vecs, jnp.float32), dev["doc_sig"],
+                jnp.asarray(vecs, jnp.float32),
+                self._kernel_sigs(self.doc_sig, "doc"),
                 dev["planes"], self.bits, k,
                 temperature=self.temperature)
             return (np.asarray(idx, np.int64),

@@ -172,7 +172,9 @@ class LSHIndex(NamedTuple):
         """exp-cosine similarity of ``query_vec`` to every indexed item."""
         q = self.sign(query_vec)
         if use_kernel:
+            from repro.kernels.common import word_major
             from repro.kernels.hamming import ops as hamming_ops
-            return hamming_ops.hamming_similarity(q, self.packed, self.bits,
-                                                  temperature=temperature)[0]
+            return hamming_ops.hamming_similarity(
+                q, word_major(self.packed), self.bits,
+                temperature=temperature)[0]
         return hamming_similarity(q, self.packed, self.bits, temperature)[0]

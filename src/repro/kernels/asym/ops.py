@@ -1,11 +1,13 @@
 """Public jit'd wrappers for the fused batched asym kernels.
 
-On CPU (this container) the Pallas bodies run in interpret mode; on TPU
-the same BlockSpecs compile to Mosaic.  Query rows are normalized and
-both row axes padded to tile multiples here so the kernels never see
-ragged blocks.  The fused-reduction wrappers additionally pad the
-segment axis to lane multiples and give padding docs an out-of-range
-segment slot so they cannot contribute to any sum.
+Off-TPU the Pallas bodies run in interpret mode; on TPU the same
+BlockSpecs compile to Mosaic.  Signatures come word-major and
+tile-aligned (``common.WordMajorSigs``, made once per signature set);
+query rows are normalized and padded to tile multiples here, so the
+kernels never see ragged blocks.  The fused-reduction wrappers
+additionally pad the segment axis to lane multiples and give padding
+docs an out-of-range segment slot so they cannot contribute to any
+sum.
 """
 from __future__ import annotations
 
@@ -13,7 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.asym import kernel as _k
-from repro.kernels.common import on_tpu, pad_rows
+from repro.kernels.common import (WordMajorSigs, interpret_mode,
+                                  next_pow2, on_tpu, pad_rows, sig_tile)
 
 
 def _prep_queries(query_vecs: jax.Array, tb: int):
@@ -27,54 +30,50 @@ def _prep_queries(query_vecs: jax.Array, tb: int):
     return pad_rows(q, tb), b, tb
 
 
-def asym_exp_similarity(query_vecs: jax.Array, db_packed: jax.Array,
+def asym_exp_similarity(query_vecs: jax.Array, db: WordMajorSigs,
                         planes: jax.Array, bits: int,
                         *, tb: int = 8, tm: int = 256,
                         temperature: float = 1.0) -> jax.Array:
-    """[B, dim] queries x [M, W] packed signatures -> [B, M] float32
+    """[B, dim] queries x M packed signatures -> [B, M] float32
     exp(temperature * asym-cos).  Queries may have any norm; rows are
     unit-normalized before projection (padding rows stay zero — their
     projections are zero, and the padded outputs are sliced away)."""
     q, b, tb = _prep_queries(query_vecs, tb)
-    m = db_packed.shape[0]
-    tm = min(tm, max(1, m))
-    db = pad_rows(jnp.asarray(db_packed, jnp.uint32), tm)
     out = _k.asym_similarity_kernel(
-        q, jnp.asarray(planes, jnp.float32), db, bits,
-        tb=tb, tm=tm, interpret=not on_tpu(), temperature=temperature)
-    return out[:b, :m]
+        q, jnp.asarray(planes, jnp.float32), db.t, bits,
+        tb=tb, tm=sig_tile(db, tm), interpret=interpret_mode(),
+        temperature=temperature)
+    return out[:b, :db.n]
 
 
-def asym_exp_segment_sum(query_vecs: jax.Array, db_packed: jax.Array,
+def asym_exp_segment_sum(query_vecs: jax.Array, db: WordMajorSigs,
                          planes: jax.Array, bits: int, seg_ids: jax.Array,
                          n_segments: int,
                          *, tb: int = 8, tm: int = 256,
                          temperature: float = 1.0) -> jax.Array:
-    """Fused scoring + reduction: [B, dim] x [M, W] -> [B, n_segments]
+    """Fused scoring + reduction: [B, dim] x M signatures -> [B, n_segments]
     sums of exp(temperature * asym-cos) grouped by ``seg_ids`` (the
     doc -> segment slot map, int, [M]).  The [B, M] similarity matrix
     stays in VMEM tile-by-tile and never reaches HBM.
 
-    Rows of ``db_packed`` should be segment-sorted so each TM tile
+    Rows of ``db`` should be segment-sorted so each TM tile
     reduces into a narrow band of slots (correctness holds for any
     order).  The segment axis is padded to a lane multiple in-kernel
     and sliced back here; padding docs get the out-of-range slot
     ``s_pad``, so they contribute to nothing."""
     q, b, tb = _prep_queries(query_vecs, tb)
-    m = db_packed.shape[0]
-    tm = min(tm, max(1, m))
-    db = pad_rows(jnp.asarray(db_packed, jnp.uint32), tm)
     s_pad = max(128, -(-int(n_segments) // 128) * 128)
     seg = jnp.asarray(seg_ids, jnp.int32).reshape(1, -1)
-    seg = jnp.pad(seg, ((0, 0), (0, db.shape[0] - m)),
+    seg = jnp.pad(seg, ((0, 0), (0, db.t.shape[1] - db.n)),
                   constant_values=s_pad)
     out = _k.asym_segment_sum_kernel(
-        q, jnp.asarray(planes, jnp.float32), db, seg, bits, s_pad,
-        tb=tb, tm=tm, interpret=not on_tpu(), temperature=temperature)
+        q, jnp.asarray(planes, jnp.float32), db.t, seg, bits, s_pad,
+        tb=tb, tm=sig_tile(db, tm), interpret=interpret_mode(),
+        temperature=temperature)
     return out[:b, :n_segments]
 
 
-def asym_exp_topk(query_vecs: jax.Array, db_packed: jax.Array,
+def asym_exp_topk(query_vecs: jax.Array, db: WordMajorSigs,
                   planes: jax.Array, bits: int, k: int,
                   *, tb: int = 8, tm: int = 256,
                   temperature: float = 1.0,
@@ -94,19 +93,19 @@ def asym_exp_topk(query_vecs: jax.Array, db_packed: jax.Array,
     superset of the unpadded candidates, so results are unchanged —
     but it is real extra work, so interpret mode (which tolerates
     ragged K) skips it; ``pad_lanes`` overrides the default for
-    parity tests of the padded shape off-TPU."""
+    parity tests of the padded shape off-TPU.  The tile width is a
+    power of two (the in-kernel bitonic sort); padding docs are masked
+    to -inf and never become candidates."""
     q, b, tb = _prep_queries(query_vecs, tb)
-    m = db_packed.shape[0]
-    k = min(int(k), m)
+    k = min(int(k), db.n)
     if pad_lanes is None:
         pad_lanes = on_tpu()
     kp = -(-k // 128) * 128 if pad_lanes else k
-    tm = min(tm, max(1, m))
-    tm = max(tm, kp)         # a tile must be able to hold kp candidates
-    db = pad_rows(jnp.asarray(db_packed, jnp.uint32), tm)
+    # a tile must hold kp candidates
+    tm = sig_tile(db, max(next_pow2(tm), next_pow2(kp)))
     vals, idx = _k.asym_topk_kernel(
-        q, jnp.asarray(planes, jnp.float32), db, bits, kp, m,
-        tb=tb, tm=tm, interpret=not on_tpu(), temperature=temperature)
+        q, jnp.asarray(planes, jnp.float32), db.t, bits, kp, db.n,
+        tb=tb, tm=tm, interpret=interpret_mode(), temperature=temperature)
     vals, idx = vals[:b], idx[:b]
     top_vals, pos = jax.lax.top_k(vals, k)
     top_idx = jnp.take_along_axis(idx, pos, axis=1)

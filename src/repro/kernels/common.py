@@ -1,18 +1,41 @@
 """Shared helpers for the kernel ops wrappers.
 
-Every kernel's public wrapper needs the same two things: backend
-detection (Pallas bodies run in interpret mode off-TPU) and row
-padding to tile multiples so kernels never see ragged blocks.  One
-copy here keeps the wrappers in sync.
+Every kernel's public wrapper needs the same things: one decision on
+whether Pallas bodies compile for the TPU or run in the interpreter,
+row padding to tile multiples so kernels never see ragged blocks, and
+the word-major signature layout the scoring kernels read.  One copy
+here keeps the wrappers in sync.
 """
 from __future__ import annotations
+
+import functools
+import logging
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
+_log = logging.getLogger(__name__)
+
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: exactly when JAX
+    finds no TPU.  The first interpreted call in a process says so on
+    the log, so a run that expected the chip cannot pass for one."""
+    if on_tpu():
+        return False
+    _warn_interpreted(jax.default_backend())
+    return True
+
+
+@functools.cache
+def _warn_interpreted(backend: str) -> None:
+    _log.warning("Pallas kernels run in interpret mode: JAX backend is %r, "
+                 "not a TPU", backend)
 
 
 def pad_rows(x: jax.Array, multiple: int) -> jax.Array:
@@ -22,3 +45,41 @@ def pad_rows(x: jax.Array, multiple: int) -> jax.Array:
     if pad:
         x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
     return x
+
+
+# Row alignment of every word-major signature set: a multiple of each
+# scoring tile (all powers of two up to 512), so no wrapper re-pads.
+SIG_ALIGN = 512
+
+
+class WordMajorSigs(NamedTuple):
+    """Packed LSH signatures in the layout every signature-scoring
+    kernel reads.  ``t`` is word-major [W, M'] uint32: with row-major
+    [M, W] the W (8 for 256 bits) words would sit on the 128-lane axis,
+    padded 16x in HBM, and every DMA would be unaligned.  M' is the row
+    count ``n`` zero-padded to a multiple of ``SIG_ALIGN``.  Built once
+    per signature set by ``word_major`` and kept on the device."""
+    t: jax.Array
+    n: int
+
+
+def word_major(db_packed) -> WordMajorSigs:
+    """[M, W] packed signatures -> ``WordMajorSigs``: the one place the
+    kernels' signature layout is made."""
+    db = jnp.asarray(db_packed, jnp.uint32)
+    return WordMajorSigs(pad_rows(db, SIG_ALIGN).T, int(db.shape[0]))
+
+
+def sig_tile(db: WordMajorSigs, tm: int) -> int:
+    """The row tile for ``db``: ``tm`` capped at its padded width, which
+    the tile must divide."""
+    mp = int(db.t.shape[1])
+    tm = min(int(tm), mp)
+    if mp % tm:
+        raise ValueError(f"row tile {tm} does not divide the "
+                         f"{mp} padded signature rows")
+    return tm
+
+
+def next_pow2(n: int) -> int:
+    return 1 << max(0, int(n) - 1).bit_length()

@@ -1,66 +1,64 @@
 """Public jit'd wrappers for the hamming kernel.
 
-On CPU (this container) the Pallas body runs in interpret mode; on TPU
-the same BlockSpecs compile to Mosaic.  Inputs are padded to tile
-multiples here so the kernel never sees ragged blocks.
+Off-TPU the Pallas body runs in interpret mode; on TPU the same
+BlockSpecs compile to Mosaic.  Stored signatures come word-major and
+tile-aligned (``common.WordMajorSigs``, made once per signature set);
+query signatures are padded to tile multiples here, so the kernel never
+sees ragged blocks.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import on_tpu, pad_rows
+from repro.kernels.common import (WordMajorSigs, interpret_mode, pad_rows,
+                                  sig_tile)
 from repro.kernels.hamming import kernel as _k
 
 
-def hamming_distance(q_packed: jax.Array, db_packed: jax.Array,
+def hamming_distance(q_packed: jax.Array, db: WordMajorSigs,
                      *, tn: int = 8, tm: int = 512) -> jax.Array:
-    n, m = q_packed.shape[0], db_packed.shape[0]
+    n = q_packed.shape[0]
     tn = min(tn, max(1, n))
-    tm = min(tm, max(1, m))
     q = pad_rows(jnp.asarray(q_packed, jnp.uint32), tn)
-    db = pad_rows(jnp.asarray(db_packed, jnp.uint32), tm)
-    out = _k.hamming_distance_kernel(q, db, tn=tn, tm=tm,
-                                     interpret=not on_tpu())
-    return out[:n, :m]
+    out = _k.hamming_distance_kernel(q, db.t, tn=tn, tm=sig_tile(db, tm),
+                                     interpret=interpret_mode())
+    return out[:n, :db.n]
 
 
-def hamming_similarity(q_packed: jax.Array, db_packed: jax.Array, bits: int,
+def hamming_similarity(q_packed: jax.Array, db: WordMajorSigs, bits: int,
                        *, tn: int = 8, tm: int = 512,
                        temperature: float = 1.0) -> jax.Array:
-    n, m = q_packed.shape[0], db_packed.shape[0]
+    n = q_packed.shape[0]
     tn = min(tn, max(1, n))
-    tm = min(tm, max(1, m))
     q = pad_rows(jnp.asarray(q_packed, jnp.uint32), tn)
-    db = pad_rows(jnp.asarray(db_packed, jnp.uint32), tm)
-    out = _k.hamming_similarity_kernel(q, db, bits, tn=tn, tm=tm,
-                                       interpret=not on_tpu(),
+    out = _k.hamming_similarity_kernel(q, db.t, bits, tn=tn,
+                                       tm=sig_tile(db, tm),
+                                       interpret=interpret_mode(),
                                        temperature=temperature)
-    return out[:n, :m]
+    return out[:n, :db.n]
 
 
-def hamming_segment_similarity(q_packed: jax.Array, db_packed: jax.Array,
+def hamming_segment_similarity(q_packed: jax.Array, db: WordMajorSigs,
                                bits: int, seg_ids: jax.Array,
                                n_segments: int,
                                *, tn: int = 8, tm: int = 512,
                                temperature: float = 1.0) -> jax.Array:
-    """Fused scoring + reduction: [N, W] x [M, W] -> [N, n_segments]
+    """Fused scoring + reduction: [N, W] x M signatures -> [N, n_segments]
     sums of exp(beta*cos(pi*m/L)) grouped by ``seg_ids`` (the doc ->
     segment slot map, int, [M]).  The [N, M] similarity matrix stays
-    in VMEM tile-by-tile and never reaches HBM.  Rows of ``db_packed``
+    in VMEM tile-by-tile and never reaches HBM.  Rows of ``db``
     should be segment-sorted so each TM tile reduces into a narrow
     band of slots (correctness holds for any order); padding docs get
     an out-of-range slot and contribute to nothing."""
-    n, m = q_packed.shape[0], db_packed.shape[0]
+    n = q_packed.shape[0]
     tn = min(tn, max(1, n))
-    tm = min(tm, max(1, m))
     q = pad_rows(jnp.asarray(q_packed, jnp.uint32), tn)
-    db = pad_rows(jnp.asarray(db_packed, jnp.uint32), tm)
     s_pad = max(128, -(-int(n_segments) // 128) * 128)
     seg = jnp.asarray(seg_ids, jnp.int32).reshape(1, -1)
-    seg = jnp.pad(seg, ((0, 0), (0, db.shape[0] - m)),
+    seg = jnp.pad(seg, ((0, 0), (0, db.t.shape[1] - db.n)),
                   constant_values=s_pad)
     out = _k.hamming_segment_similarity_kernel(
-        q, db, seg, bits, s_pad, tn=tn, tm=tm,
-        interpret=not on_tpu(), temperature=temperature)
+        q, db.t, seg, bits, s_pad, tn=tn, tm=sig_tile(db, tm),
+        interpret=interpret_mode(), temperature=temperature)
     return out[:n, :n_segments]

@@ -2,31 +2,22 @@
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
+from repro.kernels.common import interpret_mode, pad_rows
 from repro.kernels.kmeans import kernel as _k
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def assign_with_scores(x: jax.Array, c: jax.Array, *, tn: int = 512):
+    """(assignment int32 [N], best score float32 [N]).  N is padded to
+    the tile multiple and the centroids to a sublane multiple here; a
+    tile narrower than N is a lane multiple."""
+    n, k = x.shape[0], c.shape[0]
+    tn = n if n <= tn else tn
+    out, sc = _k.assign_kernel(pad_rows(x, tn), pad_rows(c, 8), k_valid=k,
+                               tn=tn, interpret=interpret_mode())
+    return out[0, :n], sc[0, :n]
 
 
 def assign(x: jax.Array, c: jax.Array, *, tn: int = 512) -> jax.Array:
-    """Assignment only (int32 [N]); pads N to the tile multiple."""
-    n = x.shape[0]
-    tn = min(tn, max(1, n))
-    pad = (-n) % tn
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-    out, _ = _k.assign_kernel(x, c, tn=tn, interpret=not _on_tpu())
-    return out[:n]
-
-
-def assign_with_scores(x: jax.Array, c: jax.Array, *, tn: int = 512):
-    n = x.shape[0]
-    tn = min(tn, max(1, n))
-    pad = (-n) % tn
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-    out, sc = _k.assign_kernel(x, c, tn=tn, interpret=not _on_tpu())
-    return out[:n], sc[:n]
+    """Assignment only (int32 [N])."""
+    return assign_with_scores(x, c, tn=tn)[0]

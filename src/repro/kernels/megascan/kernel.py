@@ -19,13 +19,18 @@ Two data-movement schedules produce bit-identical results:
     existing ``asym``/``hamming`` segment-sum kernels with shard-slot
     ids as the segment map) — Mosaic's BlockSpec grid pipeline already
     double-buffers the HBM->VMEM block copies;
-  * the *double-buffered DMA* schedule here (``*_segsum_db_kernel``):
-    the packed payload stays in HBM (``memory_space=ANY``) and the
-    kernel itself prefetches block i+1 into the alternate VMEM scratch
-    slot with ``pltpu.make_async_copy`` while the MXU scores block i —
-    the explicit form of the same overlap, and the schedule that keeps
-    working when the block sequence is the whole program (grid collapses
-    to query tiles, so there is no M grid axis for Mosaic to pipeline).
+  * the *double-buffered DMA* schedule here (``*_db_kernel``): the
+    packed payload and its row -> slot map stay in HBM
+    (``memory_space=ANY``) and the kernel itself prefetches block i+1
+    into the alternate VMEM scratch slot with ``pltpu.make_async_copy``
+    while the MXU scores block i — the explicit form of the same
+    overlap, and the schedule that keeps working when the block
+    sequence is the whole program (grid collapses to query tiles, so
+    there is no M grid axis for Mosaic to pipeline).  The payload is
+    word-major ([W, rows], see kernels/asym), so every block copy is a
+    lane-aligned [W, TM] slice; nothing of payload size is ever
+    resident in VMEM, which keeps a 2k-shard fleet inside the scoped
+    VMEM budget.
 
 Both accumulate per-(query, slot) partials block-by-block in a resident
 [TB, S] VMEM output — identical op shapes and identical accumulation
@@ -34,124 +39,101 @@ per-shard launch sequence over the same blocks (a slot's column only
 ever sums its own blocks, in the same order, with the same one-hot dot;
 other blocks contribute exact float zeros).
 
-Ranked epilogue (``_topk_block``): instead of ``jax.lax.top_k`` (which
-Mosaic may lower slowly), each tile runs a *lane-padded bitonic sort*
-(``bitonic_sort_desc``) — descending by value, ties broken by ascending
-index, exactly ``jax.lax.top_k``'s order — and emits only its K best
-(value, payload-position) candidates, so ranked queries never
-materialize full per-doc scores.  Compare-exchange partners are reached
-with reshape/flip (lane XOR by a power-of-two stride), which lowers to
-hardware tile shuffles; K is lane-padded to 128 multiples by the ops
-wrapper on TPU (PR 4's rule), and TM must be a power of two.
+Ranked epilogue (``kernels.blocks.topk_block``): instead of
+``jax.lax.top_k`` (which Mosaic does not lower in a kernel), each tile
+runs a *lane-padded bitonic sort* (``bitonic_sort_desc``) — descending
+by value, ties broken by ascending index, exactly ``jax.lax.top_k``'s
+order — and emits only its K best (value, payload-position)
+candidates, so ranked queries never materialize full per-doc scores.
+K is lane-padded to 128 multiples by the ops wrapper on TPU, and TM
+must be a power of two.  The DMA schedule writes each block's
+candidates back to HBM through a second 2-slot ring, so its VMEM use
+does not grow with the payload either.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.asym.kernel import _unpack_signs
-
-
-def _asym_tile(q, planes, db, bits: int, temperature: float) -> jax.Array:
-    """[TB, TM] exp(beta * cos_asym) from *values* — op-for-op the same
-    math as ``asym.kernel._exp_sim_tile`` (which reads refs), so the two
-    paths are bit-identical on identical inputs."""
-    proj = jnp.dot(q, planes.T, preferred_element_type=jnp.float32)
-    signs = _unpack_signs(db, bits)
-    scale = 1.0 / (bits * math.sqrt(2.0 / math.pi))
-    cos = jnp.dot(proj, signs.T, preferred_element_type=jnp.float32) * scale
-    cos = jnp.clip(cos, -1.0, 1.0)
-    return jnp.exp(temperature * cos)
-
-
-def _hamming_tile(q, db, bits: float, temperature: float) -> jax.Array:
-    """[TN, TM] exp(beta*cos(pi*m/L)) from values — mirrors
-    ``hamming.kernel._sim_tile``."""
-    w = q.shape[1]
-    acc = jnp.zeros((q.shape[0], db.shape[0]), jnp.int32)
-    for k in range(w):
-        x = q[:, k][:, None] ^ db[:, k][None, :]
-        acc = acc + jax.lax.population_count(x).astype(jnp.int32)
-    m = acc.astype(jnp.float32)
-    return jnp.exp(temperature * jnp.cos(jnp.pi * m / bits))
-
-
-def _segsum_block(tile: jax.Array, seg: jax.Array, out_ref) -> None:
-    """Accumulate one [TB, TM] tile into the resident [TB, S] output by
-    a one-hot dot against the row -> slot map (padding rows carry an
-    out-of-range slot, so their one-hot column is zero and they add
-    exact float zeros)."""
-    slots = jax.lax.broadcasted_iota(
-        jnp.int32, (seg.shape[0], out_ref.shape[1]), 1)
-    onehot = (seg[:, None] == slots).astype(jnp.float32)
-    out_ref[...] += jnp.dot(tile, onehot,
-                            preferred_element_type=jnp.float32)
+from repro.kernels.asym.kernel import exp_sim_tile
+from repro.kernels.blocks import segsum_block, topk_block
+from repro.kernels.hamming.kernel import sim_tile
 
 
 # ----------------------------------------------------------------------
 # double-buffered DMA schedule: grid = query tiles only; the kernel owns
 # the block loop and prefetches block j+1 while scoring block j
 # ----------------------------------------------------------------------
-def _asym_segsum_db_body(q_ref, planes_ref, slot_ref, sig_ref, out_ref,
-                         buf, sems, *, bits: int, temperature: float,
+def _prefetch_loop(sig_hbm, slot_hbm, sbuf, lbuf, sems, *, n_blocks: int,
+                   tm: int, visit) -> None:
+    """Stream the payload's TM-column blocks (signatures [W, TM] and
+    their slot row [1, TM]) through the 2-slot VMEM ring, calling
+    ``visit(j, sig_block, slot_block)`` on block j while block j+1 is
+    in flight."""
+    def copies(slot, j):
+        start = pl.multiple_of(j * tm, tm)
+        return (
+            pltpu.make_async_copy(sig_hbm.at[:, pl.ds(start, tm)],
+                                  sbuf.at[slot], sems.at[0, slot]),
+            pltpu.make_async_copy(slot_hbm.at[:, pl.ds(start, tm)],
+                                  lbuf.at[slot], sems.at[1, slot]),
+        )
+
+    for c in copies(0, 0):
+        c.start()
+
+    def step(j, carry):
+        cur = jax.lax.rem(j, 2)
+
+        @pl.when(j + 1 < n_blocks)
+        def _prefetch():
+            for c in copies(1 - cur, j + 1):
+                c.start()
+
+        for c in copies(cur, j):
+            c.wait()
+        visit(j, sbuf[cur], lbuf[cur])
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, step, 0)
+
+
+def _ring_scratch(w: int, tm: int) -> list:
+    return [pltpu.VMEM((2, w, tm), jnp.uint32),
+            pltpu.VMEM((2, 1, tm), jnp.int32),
+            pltpu.SemaphoreType.DMA((2, 2))]
+
+
+def _asym_segsum_db_body(q_ref, planes_ref, slot_hbm, sig_hbm, out_ref,
+                         sbuf, lbuf, sems, *, bits: int, temperature: float,
                          n_blocks: int, tm: int):
     q = q_ref[...]
     planes = planes_ref[...]
-
-    def dma(slot, j):
-        return pltpu.make_async_copy(sig_ref.at[pl.ds(j * tm, tm)],
-                                     buf.at[slot], sems.at[slot])
-
-    dma(0, 0).start()
     out_ref[...] = jnp.zeros_like(out_ref)
 
-    def step(j, carry):
-        cur = jax.lax.rem(j, 2)
+    def visit(j, sig, seg):
+        segsum_block(exp_sim_tile(q, planes, sig, bits, temperature), seg,
+                     out_ref)
 
-        @pl.when(j + 1 < n_blocks)
-        def _prefetch():
-            dma(jax.lax.rem(j + 1, 2), j + 1).start()
-
-        dma(cur, j).wait()
-        tile = _asym_tile(q, planes, buf[cur], bits, temperature)
-        seg = slot_ref[0, pl.ds(j * tm, tm)]
-        _segsum_block(tile, seg, out_ref)
-        return carry
-
-    jax.lax.fori_loop(0, n_blocks, step, 0)
+    _prefetch_loop(sig_hbm, slot_hbm, sbuf, lbuf, sems, n_blocks=n_blocks,
+                   tm=tm, visit=visit)
 
 
-def _hamming_segsum_db_body(q_ref, slot_ref, sig_ref, out_ref, buf, sems,
-                            *, bits: float, temperature: float,
+def _hamming_segsum_db_body(q_ref, slot_hbm, sig_hbm, out_ref, sbuf, lbuf,
+                            sems, *, bits: float, temperature: float,
                             n_blocks: int, tm: int):
     q = q_ref[...]
-
-    def dma(slot, j):
-        return pltpu.make_async_copy(sig_ref.at[pl.ds(j * tm, tm)],
-                                     buf.at[slot], sems.at[slot])
-
-    dma(0, 0).start()
     out_ref[...] = jnp.zeros_like(out_ref)
 
-    def step(j, carry):
-        cur = jax.lax.rem(j, 2)
+    def visit(j, sig, seg):
+        segsum_block(sim_tile(q, sig, bits, temperature), seg, out_ref)
 
-        @pl.when(j + 1 < n_blocks)
-        def _prefetch():
-            dma(jax.lax.rem(j + 1, 2), j + 1).start()
-
-        dma(cur, j).wait()
-        tile = _hamming_tile(q, buf[cur], bits, temperature)
-        seg = slot_ref[0, pl.ds(j * tm, tm)]
-        _segsum_block(tile, seg, out_ref)
-        return carry
-
-    jax.lax.fori_loop(0, n_blocks, step, 0)
+    _prefetch_loop(sig_hbm, slot_hbm, sbuf, lbuf, sems, n_blocks=n_blocks,
+                   tm=tm, visit=visit)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -159,7 +141,7 @@ def _hamming_segsum_db_body(q_ref, slot_ref, sig_ref, out_ref, buf, sems,
 def asym_megascan_segsum_db_kernel(
     q: jax.Array,            # [B, dim] float32, rows unit-normalized
     planes: jax.Array,       # [bits, dim] float32
-    sig: jax.Array,          # [n_blocks*TM, W] uint32, block-aligned
+    sig: jax.Array,          # [W, n_blocks*TM] uint32, block-aligned
     slot_ids: jax.Array,     # [1, n_blocks*TM] int32 row -> shard slot
     bits: int,
     n_slots: int,            # S (lane-padded by the ops wrapper)
@@ -170,30 +152,27 @@ def asym_megascan_segsum_db_kernel(
     temperature: float = 1.0,
 ) -> jax.Array:
     """[B, S] per-(query, shard-slot) partial sums, one launch for the
-    whole packed payload; signature blocks are DMA'd HBM->VMEM through a
-    2-slot scratch ring (prefetch block j+1 while scoring block j)."""
+    whole packed payload; signature and slot blocks are DMA'd
+    HBM->VMEM through a 2-slot scratch ring (prefetch block j+1 while
+    scoring block j)."""
     b, dim = q.shape
-    mp, w = sig.shape
+    w, mp = sig.shape
     assert mp % tm == 0, (mp, tm)
-    n_blocks = mp // tm
     body = functools.partial(_asym_segsum_db_body, bits=int(bits),
                              temperature=float(temperature),
-                             n_blocks=int(n_blocks), tm=int(tm))
+                             n_blocks=int(mp // tm), tm=int(tm))
     return pl.pallas_call(
         body,
         grid=(pl.cdiv(b, tb),),
         in_specs=[
             pl.BlockSpec((tb, dim), lambda i: (i, 0)),
             pl.BlockSpec((planes.shape[0], dim), lambda i: (0, 0)),
-            pl.BlockSpec((1, mp), lambda i: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((tb, n_slots), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n_slots), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((2, tm, w), jnp.uint32),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        scratch_shapes=_ring_scratch(w, tm),
         interpret=interpret,
     )(q, planes, slot_ids, sig)
 
@@ -202,7 +181,7 @@ def asym_megascan_segsum_db_kernel(
     "bits", "n_slots", "tn", "tm", "interpret", "temperature"))
 def hamming_megascan_segsum_db_kernel(
     q_packed: jax.Array,     # [N, W] uint32
-    sig: jax.Array,          # [n_blocks*TM, W] uint32, block-aligned
+    sig: jax.Array,          # [W, n_blocks*TM] uint32, block-aligned
     slot_ids: jax.Array,     # [1, n_blocks*TM] int32
     bits: int,
     n_slots: int,
@@ -213,124 +192,79 @@ def hamming_megascan_segsum_db_kernel(
     temperature: float = 1.0,
 ) -> jax.Array:
     n, w = q_packed.shape
-    mp, w2 = sig.shape
+    w2, mp = sig.shape
     assert w == w2 and mp % tm == 0, (w, w2, mp, tm)
-    n_blocks = mp // tm
     body = functools.partial(_hamming_segsum_db_body, bits=float(bits),
                              temperature=float(temperature),
-                             n_blocks=int(n_blocks), tm=int(tm))
+                             n_blocks=int(mp // tm), tm=int(tm))
     return pl.pallas_call(
         body,
         grid=(pl.cdiv(n, tn),),
         in_specs=[
             pl.BlockSpec((tn, w), lambda i: (i, 0)),
-            pl.BlockSpec((1, mp), lambda i: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((tn, n_slots), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, n_slots), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((2, tm, w), jnp.uint32),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        scratch_shapes=_ring_scratch(w, tm),
         interpret=interpret,
     )(q_packed, slot_ids, sig)
 
 
 # ----------------------------------------------------------------------
-# lane-padded bitonic per-tile top-k (the ranked-mode epilogue)
+# ranked mode: per-tile bitonic top-k candidates
 # ----------------------------------------------------------------------
-def _lane_xor_partner(x: jax.Array, stride: int) -> jax.Array:
-    """Value at lane ``l ^ stride`` for every lane — a reshape + flip of
-    adjacent ``stride``-wide groups (no gather), Mosaic-friendly for
-    power-of-two strides."""
-    tb, tm = x.shape
-    xr = x.reshape(tb, tm // (2 * stride), 2, stride)
-    return xr[:, :, ::-1, :].reshape(tb, tm)
-
-
-def bitonic_sort_desc(vals: jax.Array,
-                      idx: jax.Array) -> "tuple[jax.Array, jax.Array]":
-    """Full bitonic sort of each row of ``vals`` (lane count a power of
-    two), descending by value with ties broken by ascending ``idx`` —
-    exactly ``jax.lax.top_k``'s order — co-sorting ``idx``.  Runs as
-    log2(TM)*(log2(TM)+1)/2 vectorized compare-exchange stages; every
-    partner exchange is a reshape/flip, never a gather."""
-    tb, tm = vals.shape
-    assert tm & (tm - 1) == 0, f"lane count {tm} must be a power of two"
-    lane = jax.lax.broadcasted_iota(jnp.int32, (tb, tm), 1)
-    size = 2
-    while size <= tm:
-        stride = size // 2
-        while stride >= 1:
-            pv = _lane_xor_partner(vals, stride)
-            pi = _lane_xor_partner(idx, stride)
-            desc = (lane & size) == 0          # block sorts descending
-            is_lower = (lane & stride) == 0    # lane is lower of the pair
-            take_big = is_lower == desc
-            cur_big = (vals > pv) | ((vals == pv) & (idx < pi))
-            keep = take_big == cur_big
-            vals = jnp.where(keep, vals, pv)
-            idx = jnp.where(keep, idx, pi)
-            stride //= 2
-        size *= 2
-    return vals, idx
-
-
-def _topk_block(tile: jax.Array, seg: jax.Array, j, *, k: int, tm: int,
-                n_valid_slots: int) -> "tuple[jax.Array, jax.Array]":
-    """One tile's K best (value, global payload position) candidates:
-    padding rows (slot >= the real slot count) are masked to -inf so
-    they can never enter a candidate set, then the bitonic sort ranks
-    the tile and the first K lanes are emitted."""
-    masked = jnp.where(seg[None, :] < n_valid_slots, tile, -jnp.inf)
-    pos = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1) + j * tm
-    svals, spos = bitonic_sort_desc(masked, pos)
-    return svals[:, :k], spos[:, :k]
-
-
 def _asym_topk_stream_body(q_ref, planes_ref, db_ref, slot_ref, vals_ref,
                            idx_ref, *, bits: int, temperature: float,
                            k: int, tm: int, n_valid_slots: int):
     j = pl.program_id(1)
-    tile = _asym_tile(q_ref[...], planes_ref[...], db_ref[...], bits,
-                      temperature)
-    vals, pos = _topk_block(tile, slot_ref[0, ...], j, k=k, tm=tm,
-                            n_valid_slots=n_valid_slots)
+    tile = exp_sim_tile(q_ref[...], planes_ref[...], db_ref[...], bits,
+                        temperature)
+    vals, pos = topk_block(tile, slot_ref[...] < n_valid_slots, j * tm, k=k)
     vals_ref[...] = vals
     idx_ref[...] = pos
 
 
-def _asym_topk_db_body(q_ref, planes_ref, slot_ref, sig_ref, vals_ref,
-                       idx_ref, buf, sems, *, bits: int,
-                       temperature: float, k: int, tm: int,
-                       n_blocks: int, n_valid_slots: int):
+def _asym_topk_db_body(q_ref, planes_ref, slot_hbm, sig_hbm, vals_hbm,
+                       idx_hbm, sbuf, lbuf, sems, vbuf, ibuf, osems, *,
+                       bits: int, temperature: float, k: int, tm: int,
+                       tb: int, n_blocks: int, n_valid_slots: int):
     q = q_ref[...]
     planes = planes_ref[...]
+    row = pl.multiple_of(pl.program_id(0) * tb, tb)
 
-    def dma(slot, j):
-        return pltpu.make_async_copy(sig_ref.at[pl.ds(j * tm, tm)],
-                                     buf.at[slot], sems.at[slot])
+    def out_copies(slot, j):
+        col = pl.multiple_of(j * k, k)
+        return (
+            pltpu.make_async_copy(vbuf.at[slot],
+                                  vals_hbm.at[pl.ds(row, tb), pl.ds(col, k)],
+                                  osems.at[0, slot]),
+            pltpu.make_async_copy(ibuf.at[slot],
+                                  idx_hbm.at[pl.ds(row, tb), pl.ds(col, k)],
+                                  osems.at[1, slot]),
+        )
 
-    dma(0, 0).start()
+    def visit(j, sig, seg):
+        slot = jax.lax.rem(j, 2)
 
-    def step(j, carry):
-        cur = jax.lax.rem(j, 2)
+        @pl.when(j >= 2)
+        def _reuse():       # block j-2's write-back left this slot
+            for c in out_copies(slot, j - 2):
+                c.wait()
 
-        @pl.when(j + 1 < n_blocks)
-        def _prefetch():
-            dma(jax.lax.rem(j + 1, 2), j + 1).start()
+        tile = exp_sim_tile(q, planes, sig, bits, temperature)
+        vals, pos = topk_block(tile, seg < n_valid_slots, j * tm, k=k)
+        vbuf[slot] = vals
+        ibuf[slot] = pos
+        for c in out_copies(slot, j):
+            c.start()
 
-        dma(cur, j).wait()
-        tile = _asym_tile(q, planes, buf[cur], bits, temperature)
-        seg = slot_ref[0, pl.ds(j * tm, tm)]
-        vals, pos = _topk_block(tile, seg, j, k=k, tm=tm,
-                                n_valid_slots=n_valid_slots)
-        vals_ref[:, pl.ds(j * k, k)] = vals
-        idx_ref[:, pl.ds(j * k, k)] = pos
-        return carry
-
-    jax.lax.fori_loop(0, n_blocks, step, 0)
+    _prefetch_loop(sig_hbm, slot_hbm, sbuf, lbuf, sems, n_blocks=n_blocks,
+                   tm=tm, visit=visit)
+    for j in range(max(0, n_blocks - 2), n_blocks):
+        for c in out_copies(j % 2, j):
+            c.wait()
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -338,7 +272,7 @@ def _asym_topk_db_body(q_ref, planes_ref, slot_ref, sig_ref, vals_ref,
 def asym_megascan_topk_kernel(
     q: jax.Array,            # [B, dim] float32, rows unit-normalized
     planes: jax.Array,       # [bits, dim] float32
-    sig: jax.Array,          # [n_blocks*TM, W] uint32, block-aligned
+    sig: jax.Array,          # [W, n_blocks*TM] uint32, block-aligned
     slot_ids: jax.Array,     # [1, n_blocks*TM] int32
     bits: int,
     k: int,
@@ -354,7 +288,7 @@ def asym_megascan_topk_kernel(
     candidates only; the ops wrapper groups candidates by shard slot
     and runs the cheap final per-slot top-k."""
     b, dim = q.shape
-    mp, w = sig.shape
+    w, mp = sig.shape
     assert mp % tm == 0 and k <= tm, (mp, tm, k)
     n_blocks = mp // tm
     body = functools.partial(_asym_topk_stream_body, bits=int(bits),
@@ -366,7 +300,7 @@ def asym_megascan_topk_kernel(
         in_specs=[
             pl.BlockSpec((tb, dim), lambda i, j: (i, 0)),
             pl.BlockSpec((planes.shape[0], dim), lambda i, j: (0, 0)),
-            pl.BlockSpec((tm, w), lambda i, j: (j, 0)),
+            pl.BlockSpec((w, tm), lambda i, j: (0, j)),
             pl.BlockSpec((1, tm), lambda i, j: (0, j)),
         ],
         out_specs=(
@@ -398,15 +332,16 @@ def asym_megascan_topk_db_kernel(
     temperature: float = 1.0,
 ) -> "tuple[jax.Array, jax.Array]":
     """Double-buffered DMA schedule of ``asym_megascan_topk_kernel`` —
-    same per-block candidates, signature blocks prefetched through the
-    2-slot VMEM scratch ring while the current block is scored."""
+    same per-block candidates; signature blocks are prefetched through
+    the 2-slot input ring while the current block is scored, and each
+    block's [TB, K] candidates leave through a 2-slot output ring."""
     b, dim = q.shape
-    mp, w = sig.shape
+    w, mp = sig.shape
     assert mp % tm == 0 and k <= tm, (mp, tm, k)
     n_blocks = mp // tm
     body = functools.partial(_asym_topk_db_body, bits=int(bits),
                              temperature=float(temperature), k=int(k),
-                             tm=int(tm), n_blocks=int(n_blocks),
+                             tm=int(tm), tb=int(tb), n_blocks=int(n_blocks),
                              n_valid_slots=int(n_valid_slots))
     return pl.pallas_call(
         body,
@@ -414,20 +349,21 @@ def asym_megascan_topk_db_kernel(
         in_specs=[
             pl.BlockSpec((tb, dim), lambda i: (i, 0)),
             pl.BlockSpec((planes.shape[0], dim), lambda i: (0, 0)),
-            pl.BlockSpec((1, mp), lambda i: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=(
-            pl.BlockSpec((tb, n_blocks * k), lambda i: (i, 0)),
-            pl.BlockSpec((tb, n_blocks * k), lambda i: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b, n_blocks * k), jnp.float32),
             jax.ShapeDtypeStruct((b, n_blocks * k), jnp.int32),
         ),
-        scratch_shapes=[
-            pltpu.VMEM((2, tm, w), jnp.uint32),
-            pltpu.SemaphoreType.DMA((2,)),
+        scratch_shapes=_ring_scratch(w, tm) + [
+            pltpu.VMEM((2, tb, k), jnp.float32),
+            pltpu.VMEM((2, tb, k), jnp.int32),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
         interpret=interpret,
     )(q, planes, slot_ids, sig)

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from repro.data.store import plan_blocked_layout
 from repro.kernels.asym import kernel as _ka
 from repro.kernels.asym.ops import _prep_queries
-from repro.kernels.common import on_tpu, pad_rows
+from repro.kernels.common import interpret_mode, on_tpu, pad_rows
 from repro.kernels.hamming import kernel as _kh
 from repro.kernels.megascan import kernel as _km
 
@@ -47,14 +47,15 @@ def _lane_pad(n: int) -> int:
 class MegascanPayload:
     """Block-aligned packed multi-shard signature payload.
 
-    ``sig`` rows are grouped per shard slot, each slot padded to a TM
-    multiple (padding rows are zero signatures with slot ``slot_pad``,
-    an out-of-range slot that reduces into nothing); ``slots`` maps
-    every row to its shard slot; ``doc_idx`` maps every row back to the
-    global doc id (-1 for padding); ``block_slot[j]`` names the single
-    slot TM-block ``j`` belongs to."""
+    ``sig_t`` is word-major ([W, n_rows], the layout the kernels
+    stream); its row (column) axis is grouped per shard slot, each slot
+    padded to a TM multiple (padding rows are zero signatures with slot
+    ``slot_pad``, an out-of-range slot that reduces into nothing);
+    ``slots`` maps every row to its shard slot; ``doc_idx`` maps every
+    row back to the global doc id (-1 for padding); ``block_slot[j]``
+    names the single slot TM-block ``j`` belongs to."""
 
-    sig: jax.Array          # [n_rows, W] uint32, device-resident
+    sig_t: jax.Array        # [W, n_rows] uint32, device-resident
     slots: jax.Array        # [1, n_rows] int32, device-resident
     doc_idx: np.ndarray     # [n_rows] int64, -1 on padding rows
     counts: np.ndarray      # [n_slots] int64 real rows per slot
@@ -66,6 +67,11 @@ class MegascanPayload:
     n_rows: int
 
     @property
+    def sig(self) -> jax.Array:
+        """[n_rows, W] row-major view (the oracles' layout)."""
+        return self.sig_t.T
+
+    @property
     def slot_pad(self) -> int:
         """Lane-padded slot-axis width (also the padding rows' slot)."""
         return _lane_pad(self.n_slots)
@@ -73,7 +79,7 @@ class MegascanPayload:
     @property
     def nbytes_streamed(self) -> int:
         """HBM bytes the scan streams through VMEM per launch."""
-        return int(self.sig.size * 4 + self.slots.size * 4)
+        return int(self.sig_t.size * 4 + self.slots.size * 4)
 
 
 def build_payload(segments: Sequence[Tuple[np.ndarray, np.ndarray]],
@@ -108,7 +114,8 @@ def build_payload(segments: Sequence[Tuple[np.ndarray, np.ndarray]],
     if shard_ids is None:
         shard_ids = range(n_slots)
     return MegascanPayload(
-        sig=jnp.asarray(sig), slots=jnp.asarray(slots.reshape(1, -1)),
+        sig_t=jnp.asarray(np.ascontiguousarray(sig.T)),
+        slots=jnp.asarray(slots.reshape(1, -1)),
         doc_idx=doc_idx, counts=counts, block_slot=block_slot,
         shard_ids=tuple(int(s) for s in shard_ids),
         tm=int(tm), n_slots=n_slots, n_blocks=int(block_slot.shape[0]),
@@ -130,7 +137,7 @@ def megascan_segment_sums(payload: MegascanPayload, queries: jax.Array,
     explicit DMA schedule on TPU, Mosaic's BlockSpec grid pipeline in
     interpret mode); both are bit-identical."""
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = interpret_mode()
     if double_buffer is None:
         double_buffer = on_tpu()
     s_pad = payload.slot_pad
@@ -141,12 +148,12 @@ def megascan_segment_sums(payload: MegascanPayload, queries: jax.Array,
         pl_ = jnp.asarray(planes, jnp.float32)
         if double_buffer:
             out = _km.asym_megascan_segsum_db_kernel(
-                q, pl_, payload.sig, payload.slots, bits, s_pad,
+                q, pl_, payload.sig_t, payload.slots, bits, s_pad,
                 tb=tb, tm=payload.tm, interpret=interpret,
                 temperature=temperature)
         else:
             out = _ka.asym_segment_sum_kernel(
-                q, pl_, payload.sig, payload.slots, bits, s_pad,
+                q, pl_, payload.sig_t, payload.slots, bits, s_pad,
                 tb=tb, tm=payload.tm, interpret=interpret,
                 temperature=temperature)
     elif mode == "hamming":
@@ -158,12 +165,12 @@ def megascan_segment_sums(payload: MegascanPayload, queries: jax.Array,
         qp = pad_rows(qp, tb)
         if double_buffer:
             out = _km.hamming_megascan_segsum_db_kernel(
-                qp, payload.sig, payload.slots, bits, s_pad,
+                qp, payload.sig_t, payload.slots, bits, s_pad,
                 tn=tb, tm=payload.tm, interpret=interpret,
                 temperature=temperature)
         else:
             out = _kh.hamming_segment_similarity_kernel(
-                qp, payload.sig, payload.slots, bits, s_pad,
+                qp, payload.sig_t, payload.slots, bits, s_pad,
                 tn=tb, tm=payload.tm, interpret=interpret,
                 temperature=temperature)
     else:
@@ -185,7 +192,7 @@ def megascan_topk(payload: MegascanPayload, queries: jax.Array,
     bitonic candidates (K lane-padded on TPU, PR 4's rule); the final
     per-slot reduction over <= blocks*K candidates happens here."""
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = interpret_mode()
     if double_buffer is None:
         double_buffer = on_tpu()
     if pad_lanes is None:
@@ -203,7 +210,7 @@ def megascan_topk(payload: MegascanPayload, queries: jax.Array,
     kernel = (_km.asym_megascan_topk_db_kernel if double_buffer
               else _km.asym_megascan_topk_kernel)
     cvals, cpos = kernel(
-        q, jnp.asarray(planes, jnp.float32), payload.sig, payload.slots,
+        q, jnp.asarray(planes, jnp.float32), payload.sig_t, payload.slots,
         bits, kp, payload.n_slots, tb=tb, tm=payload.tm,
         interpret=interpret, temperature=temperature)
     cvals = np.asarray(cvals[:b])          # [B, n_blocks*kp] float32
@@ -334,6 +341,7 @@ class MegascanSpec:
         self.stats["group_launches"] += 1
         self.last_record = {
             "kind": "megascan", "mode": self.mode,
+            "device_kind": jax.devices()[0].device_kind,
             "ranked": self.ranked_k is not None, "launches": 1,
             "shards": len(ids), "blocks": payload.n_blocks,
             "rows": payload.n_rows, "queries": self.n_queries,

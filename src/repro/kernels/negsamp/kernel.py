@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.blocks import F32
+
 
 def _negsamp_kernel(d_ref, w_ref, wn_ref, loss_ref, gd_ref, gw_ref, gwn_ref,
                     *, temperature: float):
@@ -35,19 +37,21 @@ def _negsamp_kernel(d_ref, w_ref, wn_ref, loss_ref, gd_ref, gw_ref, gwn_ref,
     wn = wn_ref[...]               # [TB, K, dim]
     t = temperature
 
-    pos = jnp.sum(w * d, axis=-1) * t                   # [TB]
-    neg = jnp.einsum("bkd,bd->bk", wn, d,
-                     preferred_element_type=jnp.float32) * t  # [TB, K]
+    pos = jnp.sum(w * d, axis=-1, keepdims=True) * t    # [TB, 1]
+    neg = jnp.einsum("bkd,bod->bko", wn, d[:, None, :], precision=F32,
+                     preferred_element_type=jnp.float32)[..., 0] * t  # [TB, K]
 
     # loss pieces: softplus(-pos) + sum softplus(neg)
-    loss_ref[...] = jax.nn.softplus(-pos) + jax.nn.softplus(neg).sum(axis=-1)
+    loss_ref[...] = (jax.nn.softplus(-pos)
+                     + jax.nn.softplus(neg).sum(axis=-1, keepdims=True))
 
-    gpos = (jax.nn.sigmoid(pos) - 1.0) * t              # dL/d(w.d)  [TB]
+    gpos = (jax.nn.sigmoid(pos) - 1.0) * t              # dL/d(w.d)  [TB, 1]
     gneg = jax.nn.sigmoid(neg) * t                      # dL/d(wn.d) [TB, K]
 
-    gd_ref[...] = gpos[:, None] * w + jnp.einsum(
-        "bk,bkd->bd", gneg, wn, preferred_element_type=jnp.float32)
-    gw_ref[...] = gpos[:, None] * d
+    gd_ref[...] = gpos * w + jnp.einsum(
+        "bok,bkd->bod", gneg[:, None, :], wn, precision=F32,
+        preferred_element_type=jnp.float32)[:, 0]
+    gw_ref[...] = gpos * d
     gwn_ref[...] = gneg[:, :, None] * d[:, None, :]
 
 
@@ -61,7 +65,16 @@ def negsamp_grads_kernel(
     interpret: bool = False,
     temperature: float = 1.0,
 ):
-    """Returns (loss [B], grad_d [B,dim], grad_w [B,dim], grad_wn [B,K,dim])."""
+    """Returns (loss [B, 1], grad_d [B, dim], grad_w [B, dim],
+    grad_wn [B, K, dim]).
+
+    HARDWARE ADAPTATION note: both per-example contractions are written
+    as batched matmuls whose operands each keep a non-contracting axis
+    (a unit axis where the math has none) — Mosaic cannot express a
+    batched dot in which one side has no free axis.  The loss leaves as
+    a [B, 1] column: a 1-D [B] block would have to match XLA's
+    1024-element tiling of the array, and the per-row loss is a
+    sublane vector, not a lane row."""
     b, dim = d.shape
     k = wn.shape[1]
     grid = (pl.cdiv(b, tb),)
@@ -74,13 +87,13 @@ def negsamp_grads_kernel(
             pl.BlockSpec((tb, k, dim), lambda i: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tb,), lambda i: (i,)),
+            pl.BlockSpec((tb, 1), lambda i: (i, 0)),
             pl.BlockSpec((tb, dim), lambda i: (i, 0)),
             pl.BlockSpec((tb, dim), lambda i: (i, 0)),
             pl.BlockSpec((tb, k, dim), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b,), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, dim), jnp.float32),
             jax.ShapeDtypeStruct((b, dim), jnp.float32),
             jax.ShapeDtypeStruct((b, k, dim), jnp.float32),

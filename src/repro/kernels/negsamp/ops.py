@@ -11,31 +11,20 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
+from repro.kernels.common import interpret_mode, pad_rows
 from repro.kernels.negsamp import kernel as _k
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _pad_batch(x: jax.Array, multiple: int) -> jax.Array:
-    pad = (-x.shape[0]) % multiple
-    if pad:
-        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
-    return x
 
 
 def negsamp_grads(d: jax.Array, w: jax.Array, wn: jax.Array, *, tb: int = 256,
                   temperature: float = 1.0):
+    """(loss [B], grad_d [B, dim], grad_w [B, dim], grad_wn [B, K, dim])."""
     b = d.shape[0]
-    tb = min(tb, max(1, b))
-    dp, wp, wnp = _pad_batch(d, tb), _pad_batch(w, tb), _pad_batch(wn, tb)
-    loss, gd, gw, gwn = _k.negsamp_grads_kernel(dp, wp, wnp, tb=tb,
-                                                interpret=not _on_tpu(),
-                                                temperature=temperature)
-    return loss[:b], gd[:b], gw[:b], gwn[:b]
+    tb = b if b <= tb else tb
+    loss, gd, gw, gwn = _k.negsamp_grads_kernel(
+        pad_rows(d, tb), pad_rows(w, tb), pad_rows(wn, tb), tb=tb,
+        interpret=interpret_mode(), temperature=temperature)
+    return loss[:b, 0], gd[:b], gw[:b], gwn[:b]
 
 
 @functools.partial(jax.jit, static_argnames=("negatives", "lr", "unit_norm", "temperature"))

@@ -7,6 +7,16 @@ XLA_FLAGS before the first jax call.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AbstractMesh, AxisType
+
+
+def make_mesh(shape, axes):
+    """A device mesh whose axes are all ``Auto``: the sharding rules
+    (``distributed.sharding``) place arrays with sharding constraints,
+    which only Auto axes accept (``jax.make_mesh`` defaults to
+    Explicit)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,15 +25,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     across pods, FSDP within a pod, TP/EP on model."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this host has (1 CPU device in the dev container) laid
     out as a (data, model) mesh — lets the same pjit code paths run in
     tests."""
-    n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return make_mesh((len(jax.devices()), 1), ("data", "model"))
 
 
 def make_placement_mesh(n_hosts: int, *, model: int = 1):
@@ -34,5 +43,4 @@ def make_placement_mesh(n_hosts: int, *, model: int = 1):
     machine) and a real pod deployment configure placement the same
     way: swap this for ``make_production_mesh()`` and nothing else
     changes."""
-    from jax.sharding import AbstractMesh
-    return AbstractMesh((("data", int(n_hosts)), ("model", int(model))))
+    return AbstractMesh((int(n_hosts), int(model)), ("data", "model"))

@@ -52,6 +52,7 @@ first, then drains the window, then the pools.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import threading
 from typing import Any, Callable, List, Optional
 
@@ -67,6 +68,8 @@ from repro.runtime.generation import Generation, GenerationClock
 from repro.runtime.placement import HostGroupExecutor, PlacementMap
 from repro.runtime.qcache import QueryCacheConfig, SemanticQueryCache
 from repro.runtime.window import BatchWindow
+
+_log = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -304,6 +307,7 @@ class Ingestor:
                 if docs:
                     self.step(list(docs))
             except Exception as e:  # noqa: BLE001 - surfaced in record()
+                _log.exception("ingest step failed; the writer stops")
                 self.errors.append(f"{type(e).__name__}: {e}")
                 break
             self._stop.wait(self._refresh_interval_s)

@@ -75,6 +75,7 @@ polluting a later job's accounting.
 from __future__ import annotations
 
 import heapq
+import logging
 import queue
 import threading
 import time
@@ -82,6 +83,8 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Dict, Optional, Sequence
 
 import numpy as np
+
+_log = logging.getLogger(__name__)
 
 
 class ShardTaskError(RuntimeError):
@@ -483,7 +486,9 @@ class ShardTaskExecutor:
                     results[sid] = res
                     durations.append(now - t_start)
                     lost.discard(sid)   # late speculative success
-            except Exception:
+            except Exception as exc:
+                _log.warning("shard %d task failed (attempt %d): %s: %s",
+                             sid, attempt, type(exc).__name__, exc)
                 with lock:
                     live[sid].pop(attempt, None)
                 if sid in results or fatal is not None:
@@ -561,6 +566,9 @@ class ShardTaskExecutor:
                 results = spec.run_group(ids, queries_of)
                 break
             except Exception as exc:
+                _log.warning("megascan group of %d shards failed "
+                             "(attempt %d): %s: %s", len(ids), attempt,
+                             type(exc).__name__, exc)
                 if attempt > self.max_retries:
                     raise ShardTaskError(
                         f"megascan group {ids} failed after "

@@ -3,6 +3,10 @@ import sys
 
 # Tests must see the real device count (1 CPU), never the dry-run's 512.
 os.environ.pop("XLA_FLAGS", None)
+# The suite runs on the CPU even where a TPU is attached: a test run
+# must never take the chip.  Kernel compiles for the TPU go through a
+# described topology (tests/test_tpu_compile.py), not a device.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np
 import pytest

@@ -60,6 +60,7 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import (make_train_step, params_shardings,
                                     opt_state_shardings, batch_shardings)
     from repro.models import model as M
@@ -67,7 +68,7 @@ _SUBPROCESS_PROG = textwrap.dedent("""
 
     cfg = get_config("smollm_360m", smoke=True)
     opt_cfg = AdamWConfig(lr=1e-3)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     key = jax.random.PRNGKey(0)
     batch = {
         "tokens": jax.random.randint(key, (8, 32), 0, cfg.vocab_size),

@@ -11,6 +11,7 @@ import pytest
 from repro.core import lsh as lsh_mod
 from repro.kernels.asym import ops as aops
 from repro.kernels.asym import ref as aref
+from repro.kernels.common import SIG_ALIGN, sig_tile, word_major
 from repro.kernels.hamming import ops as hops
 from repro.kernels.hamming import ref as href
 
@@ -38,8 +39,8 @@ def _asym_setup(b, m, dim, bits, seed):
 def test_asym_segment_sum_matches_unfused(b, m, s, dim, bits, temp):
     rng, q, planes, db = _asym_setup(b, m, dim, bits, seed=b * 100 + m)
     seg = np.sort(rng.integers(0, s, m)).astype(np.int32)
-    got = aops.asym_exp_segment_sum(q, db, planes, bits, seg, s,
-                                    temperature=temp)
+    got = aops.asym_exp_segment_sum(q, word_major(db), planes, bits, seg,
+                                    s, temperature=temp)
     # unfused reference: full [B, M] matrix, then a numpy segment reduce
     sims = np.asarray(aref.asym_exp_similarity_ref(q, db, planes, bits, temp),
                       np.float64)
@@ -50,17 +51,18 @@ def test_asym_segment_sum_matches_unfused(b, m, s, dim, bits, temp):
 
 def test_asym_segment_sum_empty_and_unsorted_segments():
     rng, q, planes, db = _asym_setup(4, 200, 32, 128, seed=0)
+    wm = word_major(db)
     s = 16
     # all docs in one segment: every other slot must be exactly zero
     seg = np.full(200, 5, np.int32)
-    got = np.asarray(aops.asym_exp_segment_sum(q, db, planes, 128, seg, s))
+    got = np.asarray(aops.asym_exp_segment_sum(q, wm, planes, 128, seg, s))
     assert (got[:, 5] > 0).all()
     mask = np.ones(s, bool)
     mask[5] = False
     np.testing.assert_array_equal(got[:, mask], 0.0)
     # correctness must not depend on segment-sorted doc order
     seg = rng.integers(0, s, 200).astype(np.int32)
-    got = np.asarray(aops.asym_exp_segment_sum(q, db, planes, 128, seg, s))
+    got = np.asarray(aops.asym_exp_segment_sum(q, wm, planes, 128, seg, s))
     sims = np.asarray(aref.asym_exp_similarity_ref(q, db, planes, 128, 1.0),
                       np.float64)
     want = np.stack([np.bincount(seg, weights=row, minlength=s)
@@ -77,7 +79,7 @@ def test_hamming_segment_sum_matches_unfused(n, m, s, bits, temp):
     q = jnp.asarray(rng.integers(0, 2**32, (n, w), dtype=np.uint32))
     db = jnp.asarray(rng.integers(0, 2**32, (m, w), dtype=np.uint32))
     seg = np.sort(rng.integers(0, s, m)).astype(np.int32)
-    got = hops.hamming_segment_similarity(q, db, bits, seg, s,
+    got = hops.hamming_segment_similarity(q, word_major(db), bits, seg, s,
                                           temperature=temp)
     sims = np.asarray(href.hamming_similarity_ref(q, db, bits),
                       np.float64) ** temp
@@ -97,7 +99,8 @@ def test_hamming_segment_sum_matches_unfused(n, m, s, bits, temp):
 ])
 def test_asym_topk_matches_argsort(b, m, k, dim, bits, temp):
     _, q, planes, db = _asym_setup(b, m, dim, bits, seed=b + m + k)
-    idx, vals = aops.asym_exp_topk(q, db, planes, bits, k, temperature=temp)
+    idx, vals = aops.asym_exp_topk(q, word_major(db), planes, bits, k,
+                                    temperature=temp)
     sims = np.asarray(aref.asym_exp_similarity_ref(q, db, planes, bits, temp))
     order = np.argsort(-sims, axis=1, kind="stable")[:, :k]
     want_vals = np.take_along_axis(sims, order, axis=1)
@@ -119,9 +122,9 @@ def test_asym_topk_lane_padding_is_invisible(b, m, k):
     interpret mode) must return exactly what the unpadded path does —
     padding only widens the per-tile candidate sets."""
     _, q, planes, db = _asym_setup(b, m, 32, 64, seed=b * m + k)
-    idx_p, vals_p = aops.asym_exp_topk(q, db, planes, 64, k,
+    idx_p, vals_p = aops.asym_exp_topk(q, word_major(db), planes, 64, k,
                                        temperature=4.0, pad_lanes=True)
-    idx_u, vals_u = aops.asym_exp_topk(q, db, planes, 64, k,
+    idx_u, vals_u = aops.asym_exp_topk(q, word_major(db), planes, 64, k,
                                        temperature=4.0, pad_lanes=False)
     np.testing.assert_allclose(np.asarray(vals_p), np.asarray(vals_u),
                                rtol=1e-6)
@@ -167,6 +170,34 @@ def test_index_topk_fused_matches_argsort(small_corpus, built_index):
                               idx.doc_vecs, "doc")
     picked = np.take_along_axis(sims, ids_f, axis=1)
     np.testing.assert_allclose(picked, vals_f, rtol=1e-4)
+
+
+def test_word_major_layout_is_padded_transpose():
+    db = np.arange(3 * 700, dtype=np.uint32).reshape(700, 3)
+    wm = word_major(db)
+    assert wm.n == 700 and wm.t.shape == (3, 2 * SIG_ALIGN)
+    np.testing.assert_array_equal(np.asarray(wm.t)[:, :700], db.T)
+    np.testing.assert_array_equal(np.asarray(wm.t)[:, 700:], 0)
+    assert sig_tile(wm, 4096) == 2 * SIG_ALIGN
+    with pytest.raises(ValueError, match="does not divide"):
+        sig_tile(wm, 384)
+
+
+def test_index_uploads_word_major_signatures_once(small_corpus, built_index):
+    """The served planning path reads the index's cached word-major
+    signatures: no per-batch re-layout of the signature database."""
+    idx = _doc_kernel_index(built_index, small_corpus, "asym")
+    idx.shard_similarities_batch(QUERIES)
+    sig = idx._fused_device_arrays()["sig"]
+    n, w = idx.doc_sig.shape
+    assert sig.n == n
+    assert sig.t.shape == (w, -(-n // SIG_ALIGN) * SIG_ALIGN)
+    idx.shard_similarities_batch(QUERIES[:2])
+    idx.topk_doc_similarities_batch(QUERIES, k=3)
+    doc = idx._kernel_sigs(idx.doc_sig, "doc")
+    idx.topk_doc_similarities_batch(QUERIES[:1], k=3)
+    assert idx._fused_device_arrays()["sig"] is sig
+    assert idx._kernel_sigs(idx.doc_sig, "doc") is doc
 
 
 def test_index_topk_requires_doc_vectors(built_index):

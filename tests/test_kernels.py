@@ -7,6 +7,7 @@ import pytest
 
 from repro.kernels.asym import ops as aops
 from repro.kernels.asym import ref as aref
+from repro.kernels.common import word_major
 from repro.kernels.hamming import ops as hops
 from repro.kernels.hamming import ref as href
 from repro.kernels.kmeans import ops as kops
@@ -25,7 +26,7 @@ def test_hamming_distance_matches_ref(n, m, words):
     rng = np.random.default_rng(n * 100 + m)
     q = jnp.asarray(rng.integers(0, 2**32, (n, words), dtype=np.uint32))
     db = jnp.asarray(rng.integers(0, 2**32, (m, words), dtype=np.uint32))
-    got = hops.hamming_distance(q, db)
+    got = hops.hamming_distance(q, word_major(db))
     want = href.hamming_distance_ref(q, db)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -36,7 +37,7 @@ def test_hamming_similarity_matches_ref(bits, temp):
     w = bits // 32
     q = jnp.asarray(rng.integers(0, 2**32, (4, w), dtype=np.uint32))
     db = jnp.asarray(rng.integers(0, 2**32, (300, w), dtype=np.uint32))
-    got = hops.hamming_similarity(q, db, bits, temperature=temp)
+    got = hops.hamming_similarity(q, word_major(db), bits, temperature=temp)
     want = href.hamming_similarity_ref(q, db, bits) ** temp
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5)
 
@@ -55,7 +56,8 @@ def test_asym_similarity_matches_ref(b, m, dim, bits, temp):
     x = jnp.asarray(rng.normal(size=(m, dim)).astype(np.float32))
     planes = lsh_mod.hyperplanes(lsh_mod.LSHConfig(bits=bits), dim)
     db = lsh_mod.pack_bits(lsh_mod.signature_bits(x, planes))
-    got = aops.asym_exp_similarity(q, db, planes, bits, temperature=temp)
+    got = aops.asym_exp_similarity(q, word_major(db), planes, bits,
+                                   temperature=temp)
     want = aref.asym_exp_similarity_ref(q, db, planes, bits, temperature=temp)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=3e-5)
 
@@ -70,7 +72,7 @@ def test_asym_kernel_matches_single_query_asymmetric_cosine():
     planes = lsh_mod.hyperplanes(lsh_mod.LSHConfig(bits=bits), dim)
     db = lsh_mod.pack_bits(lsh_mod.signature_bits(x, planes))
     got = np.asarray(aops.asym_exp_similarity(
-        jnp.asarray(q), db, planes, bits, temperature=temp))
+        jnp.asarray(q), word_major(db), planes, bits, temperature=temp))
     for i in range(q.shape[0]):
         cos = lsh_mod.asymmetric_cosine(jnp.asarray(q[i]), db, planes, bits)
         np.testing.assert_allclose(got[i], np.exp(temp * np.asarray(cos)),

@@ -14,8 +14,9 @@ import pytest
 from repro.core import lsh as lsh_mod
 from repro.data.store import plan_blocked_layout
 from repro.kernels.asym import ops as aops
+from repro.kernels.blocks import bitonic_sort_desc
+from repro.kernels.common import word_major
 from repro.kernels.hamming import ops as hops
-from repro.kernels.megascan import kernel as mker
 from repro.kernels.megascan import ops as mops
 from repro.kernels.megascan import ref as mref
 
@@ -100,11 +101,11 @@ def test_megascan_segsum_matches_oracle_and_fused(mode):
         np.full(c, i, np.int32) for i, c in enumerate(RAGGED)])
     if mode == "asym":
         fused = aops.asym_exp_segment_sum(
-            q, jnp.asarray(real), planes, bits, seg_ids, len(RAGGED),
+            q, word_major(real), planes, bits, seg_ids, len(RAGGED),
             temperature=4.0)
     else:
         fused = hops.hamming_segment_similarity(
-            q, jnp.asarray(real), bits, seg_ids, len(RAGGED),
+            q, word_major(real), bits, seg_ids, len(RAGGED),
             temperature=4.0)
     np.testing.assert_allclose(got, np.asarray(fused), rtol=1e-4)
 
@@ -164,7 +165,7 @@ def test_bitonic_sort_desc_matches_lax_topk_with_ties(tm):
     vals = jnp.asarray(
         rng.integers(0, tm // 2, (6, tm)).astype(np.float32))
     idx = jax.lax.broadcasted_iota(jnp.int32, (6, tm), 1)
-    sv, si = mker.bitonic_sort_desc(vals, idx)
+    sv, si = bitonic_sort_desc(vals, idx)
     tv, ti = jax.lax.top_k(vals, tm)
     np.testing.assert_array_equal(np.asarray(sv), np.asarray(tv))
     np.testing.assert_array_equal(np.asarray(si), np.asarray(ti))
@@ -174,7 +175,7 @@ def test_bitonic_sort_rejects_non_power_of_two():
     vals = jnp.zeros((2, 12), jnp.float32)
     idx = jnp.zeros((2, 12), jnp.int32)
     with pytest.raises(AssertionError):
-        mker.bitonic_sort_desc(vals, idx)
+        bitonic_sort_desc(vals, idx)
 
 
 def test_megascan_topk_matches_oracle_and_schedules():

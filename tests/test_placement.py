@@ -76,7 +76,7 @@ def test_from_mesh_reads_residency_axes():
     assert len(np.unique(pm.primary)) == 4
     # pod x data both count as residency axes
     from jax.sharding import AbstractMesh
-    mesh = AbstractMesh((("pod", 2), ("data", 3), ("model", 4)))
+    mesh = AbstractMesh((2, 3, 4), ("pod", "data", "model"))
     assert PlacementMap.from_mesh(mesh, 12).n_hosts == 6
 
 
