@@ -536,7 +536,8 @@ def _run_paced_window(corpus, index, queries, rate, executor, seed,
                   escalated=window.stats["escalated"],
                   degraded=window.stats["degraded"],
                   retry_hints=retry_hints, results=results,
-                  last_budget=window.last_budget)
+                  last_budget=(window.engine.last_report.budget
+                               if window.engine.last_report else None))
     return (sojourns, len(served) / wall, dict(window.stats),
             len(served) / batches, extras)
 

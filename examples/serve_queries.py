@@ -126,6 +126,7 @@ def main():
     from repro.core.pv_dbow import PVDBOWConfig, train_pv_dbow
     from repro.core.queries import (BatchQuery, QueryBatch, parse_boolean,
                                     precision_at_k, recall)
+    from repro.core.queries.batch import STAGES
     from repro.data.corpus import SyntheticCorpusConfig, generate_text_corpus
     from repro.data.store import ShardedCorpus
     from repro.launch.serve_stack import ServeConfig, build_serving_stack
@@ -320,6 +321,16 @@ def main():
           + (f"; pressure escalations: {ws['escalated']}, "
              f"served degraded: {ws['degraded']}"
              if ws.get("escalated") or ws.get("degraded") else ""))
+    served, batches = max(ws["served"], 1), max(ws["batches"], 1)
+    print(f"   queue per query: head-of-line "
+          f"{ws['queue_hol_us'] / served / 1e3:.2f} ms, window delay "
+          f"{ws['queue_delay_us'] / served / 1e3:.2f} ms")
+    print("   stages per window (ms): " + ", ".join(
+        f"{k} {ws[k + '_us'] / batches / 1e3:.2f}"
+        for k in STAGES)
+          + f"; scan CPU {100 * ws['scan_cpu_us'] / max(ws['scan_us'], 1):.0f}%"
+          f", {ws['plan_shards'] / max(ws['shard_visits'], 1):.2f} plan "
+          f"shards per shard visit")
     if controller is not None and controller.current_plan is not None:
         plan = controller.current_plan
         scan = controller.scan_fraction
@@ -338,7 +349,7 @@ def main():
         print(f"   budget: count 95% CIs cover the exact answer "
               f"{cover}/{n_counts}; mean achieved rate "
               f"{np.mean(rates):.2f} (nominal {args.rate})")
-        audit = window.last_budget
+        audit = window.engine.last_report.budget
         if audit:
             print(f"   planner audit (last window): pressure "
                   f"{audit['pressure']:.2f}, {audit['degraded']}/"

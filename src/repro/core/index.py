@@ -54,6 +54,7 @@ from repro.core.sampling import similarity_probabilities
 from repro.data.store import ShardedCorpus, atomic_savez
 from repro.kernels.common import WordMajorSigs, word_major
 from repro.runtime.generation import GenerationClock
+from repro.utils import spans
 
 
 @dataclasses.dataclass
@@ -213,7 +214,8 @@ class ApproxIndex:
                     self._kernel_sigs(target_sig, role),
                     jnp.asarray(self.planes), self.bits,
                     temperature=self.temperature)
-                return np.asarray(sims, np.float64)
+                with spans.span("emapprox.plan.wait"):
+                    return np.asarray(sims, np.float64)
             q = np.asarray(vecs, np.float64)
             q = q / np.maximum(
                 np.linalg.norm(q, axis=-1, keepdims=True), 1e-9)
@@ -234,7 +236,8 @@ class ApproxIndex:
                 sims = lsh_mod.hamming_similarity(
                     qsig, jnp.asarray(target_sig), self.bits,
                     temperature=self.temperature)
-            return np.asarray(sims, np.float64)
+            with spans.span("emapprox.plan.wait"):
+                return np.asarray(sims, np.float64)
         q = np.asarray(vecs, np.float64)
         q = q / np.maximum(np.linalg.norm(q, axis=-1, keepdims=True), 1e-9)
         return np.exp(self.temperature * (q @ target_vecs.astype(np.float64).T))
@@ -338,7 +341,9 @@ class ApproxIndex:
             out = hamming_ops.hamming_segment_similarity(
                 qsig, dev["sig"], self.bits, dev["seg"], n_shards,
                 temperature=self.temperature)
-        return np.asarray(out, np.float64)
+        # the blocking copy is the planner's wait for the device
+        with spans.span("emapprox.plan.wait"):
+            return np.asarray(out, np.float64)
 
     def topk_doc_similarities_batch(
             self, queries: Sequence[Sequence[int]], k: int = 10, *,

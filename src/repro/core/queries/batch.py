@@ -78,6 +78,7 @@ Three serving-side extensions ride on the same machinery:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -110,6 +111,10 @@ from repro.data.store import (
 )
 from repro.runtime.generation import Generation
 from repro.runtime.qcache import query_cache_vectors, query_key, sampler_class
+from repro.utils import spans
+
+# the stage spans whose self seconds every ExecutionReport carries
+STAGES = ("probe", "plan", "plan_wait", "sample", "scan", "reduce")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,6 +131,16 @@ class ExecutionReport:
     slot is an empty array; ``cache`` carries the batch's cache outcome
     counts (hits / near_hits / misses / bypassed) when the engine has a
     ``SemanticQueryCache`` attached, None otherwise.
+
+    ``batch`` is the engine's sequence number for the call, the
+    ``batch=`` of its ``emapprox.*`` profiler spans; ``elapsed_s`` is
+    the call's wall time.  ``stages`` holds the self seconds of each
+    stage span (``STAGES``: cache probe, planning outside its device
+    wait, the planner's device wait, the draws, the shared scan with
+    its gather, the reduce with cache populate and budget feedback).
+    ``counters``: ``shard_visits`` (distinct shards scanned, the union
+    of ``plan``), ``plan_shards`` (the sum of the per-query plan sizes)
+    and ``scan_cpu_us`` (process CPU time over the scan, every thread).
     """
     n_queries: int
     rate: float                          # nominal rate passed to execute
@@ -136,6 +151,9 @@ class ExecutionReport:
     budget: Optional[Dict[str, Any]] = None
     degraded: Optional[Dict[str, Any]] = None
     cache: Optional[Dict[str, int]] = None
+    batch: int = 0
+    stages: Dict[str, float] = dataclasses.field(default_factory=dict)
+    counters: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def record(self) -> Dict[str, Any]:
         """JSON-serializable view (numpy arrays become int lists)."""
@@ -148,7 +166,10 @@ class ExecutionReport:
             balance=self.balance,
             budget=self.budget,
             degraded=self.degraded,
-            cache=self.cache)
+            cache=self.cache,
+            batch=int(self.batch),
+            stages={k: float(v) for k, v in self.stages.items()},
+            counters={k: int(v) for k, v in self.counters.items()})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -248,6 +269,7 @@ class QueryBatch:
         self.cache = cache
         # the typed record of the most recent execute() call
         self.last_report: Optional[ExecutionReport] = None
+        self._batch_seq = itertools.count(1)
 
     # ------------------------------------------------------------------
     # the world: (corpus, index) behind one atomic reference
@@ -415,7 +437,21 @@ class QueryBatch:
         queries and pressure-degraded batches bypass the cache in both
         directions: a planned-rate or partial answer is a
         point-in-time decision, never replayable as full fidelity.
+
+        The call is the profiler span ``emapprox.execute``, with the
+        stage spans ``emapprox.probe`` / ``.plan`` (``.plan.wait``
+        inside it) / ``.sample`` / ``.scan`` (``.gather`` inside it) /
+        ``.reduce`` nested under it on the calling thread, all tagged
+        with the call's ``batch`` number; their self seconds land on
+        ``last_report.stages``.
         """
+        with spans.batch(next(self._batch_seq), STAGES,
+                         n=len(queries)) as rec:
+            return self._execute(queries, rate, rng, pressure, rec)
+
+    def _execute(self, queries: Sequence[BatchQuery], rate: float,
+                 rng: Optional[np.random.Generator], pressure: float,
+                 rec: spans.Batch) -> List[Any]:
         rng = rng or np.random.default_rng(0)
         t0 = time.perf_counter()
         # RCU entry: read the generation BEFORE capturing the corpus /
@@ -443,26 +479,27 @@ class QueryBatch:
         cache_meta: Optional[Dict[str, int]] = None
         sigs = qkeys = None
         if self.cache is not None and n:
-            sigs = index.query_signatures(
-                query_cache_vectors(index, queries))
-            qkeys = [query_key(q) for q in queries]
-            bypassed = 0
-            for i, q in enumerate(queries):
-                if pressure > 0.0 or q.budget is not None:
-                    bypassed += 1
-                    self.cache.stats["bypassed"] += 1
-                    continue
-                outcome, entry = self.cache.lookup(
-                    sigs[i], qkeys[i], sampler_class(q.kind),
-                    rates[i], epoch)
-                if outcome == "hit":
-                    hits[i] = entry
-                elif outcome == "near":
-                    near[i] = entry
-            cache_meta = dict(
-                hits=len(hits), near_hits=len(near),
-                misses=n - len(hits) - len(near) - bypassed,
-                bypassed=bypassed)
+            with spans.span("emapprox.probe"):
+                sigs = index.query_signatures(
+                    query_cache_vectors(index, queries))
+                qkeys = [query_key(q) for q in queries]
+                bypassed = 0
+                for i, q in enumerate(queries):
+                    if pressure > 0.0 or q.budget is not None:
+                        bypassed += 1
+                        self.cache.stats["bypassed"] += 1
+                        continue
+                    outcome, entry = self.cache.lookup(
+                        sigs[i], qkeys[i], sampler_class(q.kind),
+                        rates[i], epoch)
+                    if outcome == "hit":
+                        hits[i] = entry
+                    elif outcome == "near":
+                        near[i] = entry
+                cache_meta = dict(
+                    hits=len(hits), near_hits=len(near),
+                    misses=n - len(hits) - len(near) - bypassed,
+                    bypassed=bypassed)
 
         all_ids = np.arange(n_shards, dtype=np.int64)
         uniform = np.full(n_shards, 1.0 / n_shards, np.float64)
@@ -477,8 +514,9 @@ class QueryBatch:
             for i in need:
                 samples[i], plan[i] = census, all_ids
         elif need:
-            rows = self._probability_rows(
-                [queries[i] for i in need], corpus, index)
+            with spans.span("emapprox.plan"):
+                rows = self._probability_rows(
+                    [queries[i] for i in need], corpus, index)
             # aggregation keeps the with-replacement multiset (the
             # Hansen-Hurwitz estimator needs it); retrieval unions docs
             # over the sample, so it draws distinct shards — same
@@ -488,15 +526,17 @@ class QueryBatch:
             # precise path draws nothing; cache-resolved queries draw
             # nothing either, so the misses' draw sequence matches a
             # batch of only the misses.
-            for i, row in zip(need, rows):
-                r, q = rates[i], queries[i]
-                if r >= 1.0:
-                    samples[i], plan[i] = census, all_ids
-                    continue
-                rows_by_pos[i] = row
-                samples[i] = (pps_sample(row, r, rng) if q.kind == "count"
-                              else pps_sample_distinct(row, r, rng))
-                plan[i] = unique_shards(samples[i])
+            with spans.span("emapprox.sample"):
+                for i, row in zip(need, rows):
+                    r, q = rates[i], queries[i]
+                    if r >= 1.0:
+                        samples[i], plan[i] = census, all_ids
+                        continue
+                    rows_by_pos[i] = row
+                    samples[i] = (pps_sample(row, r, rng)
+                                  if q.kind == "count"
+                                  else pps_sample_distinct(row, r, rng))
+                    plan[i] = unique_shards(samples[i])
 
         if index is not None:
             doc_freq = index.doc_freq
@@ -511,19 +551,22 @@ class QueryBatch:
         # empty, and an all-hit batch skips executor dispatch entirely
         empty = np.zeros(0, np.int64)
         scan_plan = [empty if i in hits else plan[i] for i in range(n)]
-        if n and len(hits) == n:
-            per_query: List[Dict[int, Any]] = [{} for _ in range(n)]
-            job, balance = None, None
-        elif self.executor is not None:
-            per_query = self.executor.map_shard_batch(
-                corpus, scan_plan, fns)
-            job = getattr(self.executor, "last_job", None)
-            balance = (dict(job["balance"])
-                       if isinstance(job, dict) and "balance" in job
-                       else None)
-        else:
-            per_query = self._inline_shared_scan(scan_plan, fns, corpus)
-            job, balance = None, None
+        cpu0 = time.process_time()
+        with spans.span("emapprox.scan"):
+            if n and len(hits) == n:
+                per_query: List[Dict[int, Any]] = [{} for _ in range(n)]
+                job, balance = None, None
+            elif self.executor is not None:
+                per_query = self.executor.map_shard_batch(
+                    corpus, scan_plan, fns)
+                job = getattr(self.executor, "last_job", None)
+                balance = (dict(job["balance"])
+                           if isinstance(job, dict) and "balance" in job
+                           else None)
+            else:
+                per_query = self._inline_shared_scan(scan_plan, fns, corpus)
+                job, balance = None, None
+        scan_cpu_s = time.process_time() - cpu0
 
         # partial gather (allow_partial executors only): shards whose
         # hosts all died never produced results — each affected query
@@ -542,33 +585,42 @@ class QueryBatch:
                 degraded_queries=sum(1 for k in lost_per_query if k),
                 lost_per_query=lost_per_query)
 
+        # every result carries the batch's wall time up to the reduce
         elapsed = time.perf_counter() - t0
-        results = [
-            hits[i].result._replace(elapsed_s=elapsed) if i in hits
-            else self._reduce(queries[i], samples[i], plan[i], per_query[i],
-                              elapsed, rates[i] >= 1.0, n_shards,
-                              lost=lost_per_query[i])
-            for i in range(n)]
+        with spans.span("emapprox.reduce"):
+            results = [
+                hits[i].result._replace(elapsed_s=elapsed) if i in hits
+                else self._reduce(queries[i], samples[i], plan[i],
+                                  per_query[i], elapsed, rates[i] >= 1.0,
+                                  n_shards, lost=lost_per_query[i])
+                for i in range(n)]
 
-        # populate: misses and near-hits insert their own full-fidelity
-        # entries; degraded answers (lost draws) never enter the cache
-        if self.cache is not None and n:
-            for i, q in enumerate(queries):
-                if (i in hits or pressure > 0.0 or q.budget is not None
-                        or lost_per_query[i]):
-                    continue
-                self.cache.insert(
-                    sigs[i], qkeys[i], sampler_class(q.kind), rates[i],
-                    probs=rows_by_pos.get(i), sample=samples[i],
-                    plan=plan[i], result=results[i], epoch=epoch)
+            # populate: misses and near-hits insert their own
+            # full-fidelity entries; degraded answers (lost draws) never
+            # enter the cache
+            if self.cache is not None and n:
+                for i, q in enumerate(queries):
+                    if (i in hits or pressure > 0.0 or q.budget is not None
+                            or lost_per_query[i]):
+                        continue
+                    self.cache.insert(
+                        sigs[i], qkeys[i], sampler_class(q.kind), rates[i],
+                        probs=rows_by_pos.get(i), sample=samples[i],
+                        plan=plan[i], result=results[i], epoch=epoch)
 
-        budget = self._feedback(queries, rates, results, audit, job,
-                                degraded)
+            budget = self._feedback(queries, rates, results, audit, job,
+                                    degraded)
+        sizes = [len(p) for p in scan_plan]
+        visits = (len(np.unique(np.concatenate(scan_plan)))
+                  if sum(sizes) else 0)
         self.last_report = ExecutionReport(
-            n_queries=n, rate=float(rate), elapsed_s=elapsed,
+            n_queries=n, rate=float(rate),
+            elapsed_s=time.perf_counter() - t0,
             rates=tuple(float(r) for r in rates), plan=tuple(scan_plan),
             balance=balance, budget=budget, degraded=degraded,
-            cache=cache_meta)
+            cache=cache_meta, batch=rec.seq, stages=dict(rec.stages),
+            counters=dict(shard_visits=visits, plan_shards=sum(sizes),
+                          scan_cpu_us=round(scan_cpu_s * 1e6)))
         return results
 
     def _generation(self) -> Generation:

@@ -176,6 +176,7 @@ def asym_similarity_kernel(
         out_specs=pl.BlockSpec((tb, tm), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
         interpret=interpret,
+        name="asym_similarity_kernel",
     )(q, planes, db_t)
 
 
@@ -217,6 +218,7 @@ def asym_segment_sum_kernel(
         out_specs=pl.BlockSpec((tb, n_segments), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n_segments), jnp.float32),
         interpret=interpret,
+        name="asym_segment_sum_kernel",
     )(q, planes, db_t, seg_ids)
 
 
@@ -271,4 +273,5 @@ def asym_topk_kernel(
             jax.ShapeDtypeStruct((b, jm * k), jnp.int32),
         ),
         interpret=interpret,
+        name="asym_topk_kernel",
     )(q, planes, db_t)

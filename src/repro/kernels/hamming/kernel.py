@@ -79,7 +79,7 @@ def _segsum_similarity_kernel(q_ref, db_ref, seg_ref, out_ref, *,
 
 
 def _tiled_call(kernel_fn, q, db_t, out_dtype, tn: int, tm: int,
-                interpret: bool):
+                interpret: bool, name: str):
     n, w = q.shape
     w2, m = db_t.shape
     assert w == w2, (w, w2)
@@ -94,6 +94,7 @@ def _tiled_call(kernel_fn, q, db_t, out_dtype, tn: int, tm: int,
         out_specs=pl.BlockSpec((tn, tm), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, m), out_dtype),
         interpret=interpret,
+        name=name,
     )(q, db_t)
 
 
@@ -108,7 +109,7 @@ def hamming_distance_kernel(
 ) -> jax.Array:
     """[N, W] x [W, M] uint32 -> [N, M] int32."""
     return _tiled_call(_distance_kernel, q_packed, db_t, jnp.int32,
-                       tn, tm, interpret)
+                       tn, tm, interpret, "hamming_distance_kernel")
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "tn", "tm", "interpret",
@@ -127,7 +128,7 @@ def hamming_similarity_kernel(
     kernel = functools.partial(_similarity_kernel, bits=float(bits),
                                temperature=float(temperature))
     return _tiled_call(kernel, q_packed, db_t, jnp.float32,
-                       tn, tm, interpret)
+                       tn, tm, interpret, "hamming_similarity_kernel")
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "n_segments", "tn", "tm",
@@ -166,4 +167,5 @@ def hamming_segment_similarity_kernel(
         out_specs=pl.BlockSpec((tn, n_segments), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, n_segments), jnp.float32),
         interpret=interpret,
+        name="hamming_segment_similarity_kernel",
     )(q_packed, db_t, seg_ids)

@@ -72,4 +72,5 @@ def assign_kernel(
             jax.ShapeDtypeStruct((1, n), jnp.float32),
         ],
         interpret=interpret,
+        name="kmeans_assign_kernel",
     )(x, c)

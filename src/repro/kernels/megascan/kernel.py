@@ -174,6 +174,7 @@ def asym_megascan_segsum_db_kernel(
         out_shape=jax.ShapeDtypeStruct((b, n_slots), jnp.float32),
         scratch_shapes=_ring_scratch(w, tm),
         interpret=interpret,
+        name="asym_megascan_segsum_db_kernel",
     )(q, planes, slot_ids, sig)
 
 
@@ -209,6 +210,7 @@ def hamming_megascan_segsum_db_kernel(
         out_shape=jax.ShapeDtypeStruct((n, n_slots), jnp.float32),
         scratch_shapes=_ring_scratch(w, tm),
         interpret=interpret,
+        name="hamming_megascan_segsum_db_kernel",
     )(q_packed, slot_ids, sig)
 
 
@@ -312,6 +314,7 @@ def asym_megascan_topk_kernel(
             jax.ShapeDtypeStruct((b, n_blocks * k), jnp.int32),
         ),
         interpret=interpret,
+        name="asym_megascan_topk_kernel",
     )(q, planes, sig, slot_ids)
 
 
@@ -366,4 +369,5 @@ def asym_megascan_topk_db_kernel(
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
         interpret=interpret,
+        name="asym_megascan_topk_db_kernel",
     )(q, planes, slot_ids, sig)

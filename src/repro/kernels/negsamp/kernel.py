@@ -99,4 +99,5 @@ def negsamp_grads_kernel(
             jax.ShapeDtypeStruct((b, k, dim), jnp.float32),
         ],
         interpret=interpret,
+        name="negsamp_grads_kernel",
     )(d, w, wn)

@@ -84,6 +84,8 @@ from typing import Any, Callable, Dict, Optional, Sequence
 
 import numpy as np
 
+from repro.utils import spans
+
 _log = logging.getLogger(__name__)
 
 
@@ -163,9 +165,10 @@ def run_shared_scan(
 
     by_shard = mapper(corpus, sorted(queries_of), shared_scan)
     out: list = [{} for _ in plan]
-    for sid, per_query in by_shard.items():
-        for qi, res in per_query.items():
-            out[qi][sid] = res
+    with spans.span("emapprox.gather"):
+        for sid, per_query in by_shard.items():
+            for qi, res in per_query.items():
+                out[qi][sid] = res
     return out
 
 

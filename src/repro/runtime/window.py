@@ -52,7 +52,26 @@ accuracy before availability, and every shed carries the controller's
 ``retry_after_s`` hint so callers back off one serving cycle.  The
 dispatcher forwards the controller's current pressure to each
 ``engine.execute`` call, and the engine's per-batch budget audit
-(planned vs realized rates and errors) lands on ``last_budget``.
+(planned vs realized rates and errors) lands on its
+``last_report.budget``.
+
+``stats`` holds monotone counters, so two reads diff to a window's
+worth.  Beside the batch and close-reason counts:
+
+  * ``queue_hol_us`` / ``queue_delay_us`` split each served query's
+    wait, from ``submit`` to the moment its batch goes to the engine:
+    head-of-line time is the part during which the dispatcher was
+    executing earlier batches (a cumulative busy clock, read at
+    ``submit``), delay the rest — the open window waiting for its
+    deadline or size;
+  * ``<stage>_us`` sums the self seconds of the engine's stage spans
+    (``ExecutionReport.stages``: probe, plan, plan_wait, sample, scan,
+    reduce) and ``scan_cpu_us`` / ``shard_visits`` / ``plan_shards``
+    its counters, once per batch that reports them.
+
+Each batch runs under the profiler span ``emapprox.window`` (``n``,
+``reason`` and the engine's ``batch`` number), the parent of the
+engine's ``emapprox.execute``.
 """
 from __future__ import annotations
 
@@ -64,6 +83,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.runtime.controller import Backpressure, WindowController
+from repro.utils import spans
 
 
 class BatchWindow:
@@ -96,20 +116,25 @@ class BatchWindow:
         self.max_pending = max_pending
         self._rng = rng or np.random.default_rng(0)
         self._wake = threading.Condition()
-        self._pending: List[Tuple[Any, Future]] = []
+        # (query, future, submit time, dispatcher busy clock at submit)
+        self._pending: List[Tuple[Any, Future, float, float]] = []
         self._first_arrival: Optional[float] = None
         self._flush = False
         self._closed = False
+        # seconds the dispatcher has spent on finished batches, and the
+        # start of the batch it is on (None between batches)
+        self._busy_s = 0.0
+        self._busy_since: Optional[float] = None
         self.stats: Dict[str, int] = {
             "batches": 0, "served": 0, "cancelled": 0, "shed": 0,
             "escalated": 0, "degraded": 0, "batch_retries": 0,
             "closed_by_size": 0, "closed_by_deadline": 0,
             "closed_by_flush": 0,
+            "queue_hol_us": 0, "queue_delay_us": 0,
+            "probe_us": 0, "plan_us": 0, "plan_wait_us": 0,
+            "sample_us": 0, "scan_us": 0, "reduce_us": 0,
+            "scan_cpu_us": 0, "shard_visits": 0, "plan_shards": 0,
         }
-        # the engine's budget audit for the most recent batch (planned
-        # vs realized per-query rates/errors), when the engine keeps
-        # one (QueryBatch with a RatePlanner) — None otherwise
-        self.last_budget: Optional[Dict[str, Any]] = None
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="batch-window")
         self._thread.start()
@@ -157,7 +182,10 @@ class BatchWindow:
                     raise Backpressure(len(self._pending), util, retry)
             if self.controller is not None:
                 self.controller.observe_arrival(now)
-            self._pending.append((query, fut))
+            busy = self._busy_s
+            if self._busy_since is not None:
+                busy += now - self._busy_since
+            self._pending.append((query, fut, now, busy))
             if self._first_arrival is None:
                 self._first_arrival = now
             self._wake.notify_all()
@@ -211,6 +239,10 @@ class BatchWindow:
                     self._wake.wait(timeout=remaining)
                 batch = self._pending[: max_batch]
                 del self._pending[: max_batch]
+                # the batch goes to the engine now: the dispatcher is
+                # busy from here until _run_batch has folded its stats
+                start = self._busy_since = time.perf_counter()
+                busy = self._busy_s
                 if len(batch) >= max_batch:
                     reason = "size"
                 elif self._flush or self._closed:
@@ -224,7 +256,7 @@ class BatchWindow:
                                        if self._pending else None)
                 if not self._pending:
                     self._flush = False
-            self._run_batch(batch, reason)
+            self._run_batch(batch, reason, start, busy)
 
     def _execute_once_retried(self, queries: List[Any],
                               kwargs: Dict[str, Any]) -> List[Any]:
@@ -250,20 +282,67 @@ class BatchWindow:
             return self.engine.execute(queries, self.rate,
                                        rng=self._rng, **kwargs)
 
-    def _run_batch(self, batch: List[Tuple[Any, Future]],
-                   reason: str) -> None:
+    def _run_batch(self, batch: List[Tuple[Any, Future, float, float]],
+                   reason: str, start: float, busy: float) -> None:
+        """Execute one closed window that went to the engine at
+        ``start``, when the dispatcher's busy clock read ``busy``."""
+        with spans.span("emapprox.window", n=len(batch),
+                        reason=reason) as ann:
+            claimed, service_s, pressure = self._serve(batch)
+            # a failed batch leaves the previous batch's report behind
+            report = (getattr(self.engine, "last_report", None)
+                      if service_s is not None else None)
+            seq = getattr(report, "batch", None)
+            if seq is not None:
+                ann.set_metadata(batch=seq)
+        with self._wake:
+            self._busy_s += time.perf_counter() - start
+            self._busy_since = None
+            self.stats["cancelled"] += len(batch) - len(claimed)
+            if not claimed:
+                return
+            self.stats["batches"] += 1
+            self.stats["served"] += len(claimed)
+            if pressure > 0.0:
+                self.stats["degraded"] += len(claimed)
+            self.stats[f"closed_by_{reason}"] += 1
+            hol = sum(busy - b for _, _, _, b in claimed)
+            wait = sum(start - t for _, _, t, _ in claimed)
+            self.stats["queue_hol_us"] += round(max(hol, 0.0) * 1e6)
+            self.stats["queue_delay_us"] += round(max(wait - hol, 0.0) * 1e6)
+            for key, sec in getattr(report, "stages", {}).items():
+                key += "_us"
+                self.stats[key] = self.stats.get(key, 0) + round(sec * 1e6)
+            for key, count in getattr(report, "counters", {}).items():
+                self.stats[key] = self.stats.get(key, 0) + int(count)
+            if self.controller is not None and service_s is not None:
+                # the executor's per-job telemetry attributes the batch
+                # cost: scan_s is the shared-scan share of service_s
+                # (for a host group, the cross-host critical path)
+                executor = getattr(self.engine, "executor", None)
+                job = getattr(executor, "last_job", None)
+                scan_s = job["wall_s"] if job else None
+                # semantic-cache exact hits never touched the executor;
+                # keep them out of the fitted batch cost model
+                cache_meta = getattr(report, "cache", None)
+                cached_n = cache_meta.get("hits", 0) if cache_meta else 0
+                self.controller.observe_batch(len(claimed), service_s,
+                                              scan_s, cached=cached_n)
+
+    def _serve(self, batch: List[Tuple[Any, Future, float, float]]):
+        """Claim the batch's futures, run the claimed queries through
+        the engine and resolve the futures; returns (claimed entries,
+        service seconds or None on failure, pressure forwarded)."""
         # Claim every future before executing: a caller may have
         # cancel()ed while it sat PENDING in the window.  Marking the
         # survivors RUNNING means no later cancel can win the race and
         # make set_result raise InvalidStateError (which would kill the
         # dispatcher thread for good).
-        claimed = [(q, f) for q, f in batch
-                   if f.set_running_or_notify_cancel()]
-        dropped = len(batch) - len(claimed)
+        claimed = [e for e in batch if e[1].set_running_or_notify_cancel()]
         service_s = None
         pressure = 0.0
         if claimed:
-            queries = [q for q, _ in claimed]
+            queries = [e[0] for e in claimed]
             # an accuracy-elastic engine takes the controller's current
             # degradation pressure with the batch; plain engines keep
             # the legacy signature (the kwarg would be a TypeError)
@@ -276,33 +355,10 @@ class BatchWindow:
             try:
                 results = self._execute_once_retried(queries, kwargs)
             except BaseException as exc:  # deliver failures to every waiter
-                for _, fut in claimed:
-                    fut.set_exception(exc)
+                for e in claimed:
+                    e[1].set_exception(exc)
             else:
                 service_s = time.perf_counter() - t0
-                for (_, fut), res in zip(claimed, results):
-                    fut.set_result(res)
-        with self._wake:
-            self.stats["cancelled"] += dropped
-            if not claimed:
-                return
-            self.stats["batches"] += 1
-            self.stats["served"] += len(claimed)
-            if pressure > 0.0:
-                self.stats["degraded"] += len(claimed)
-            self.last_budget = getattr(self.engine, "last_budget", None)
-            self.stats[f"closed_by_{reason}"] += 1
-            if self.controller is not None and service_s is not None:
-                # the executor's per-job telemetry attributes the batch
-                # cost: scan_s is the shared-scan share of service_s
-                # (for a host group, the cross-host critical path)
-                executor = getattr(self.engine, "executor", None)
-                job = getattr(executor, "last_job", None)
-                scan_s = job["wall_s"] if job else None
-                # semantic-cache exact hits never touched the executor;
-                # keep them out of the fitted batch cost model
-                report = getattr(self.engine, "last_report", None)
-                cache_meta = getattr(report, "cache", None)
-                cached_n = cache_meta.get("hits", 0) if cache_meta else 0
-                self.controller.observe_batch(len(claimed), service_s,
-                                              scan_s, cached=cached_n)
+                for e, res in zip(claimed, results):
+                    e[1].set_result(res)
+        return claimed, service_s, pressure

@@ -54,49 +54,30 @@ def shape(topo):
                                                     sharding=one_chip)
 
 
-def _compile(fn, *args, **static):
+def _compile(shape, name):
+    fn, args, static = _cases(shape)[name]
     compiled = fn.lower(*args, **static).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_asym_similarity_compiles(shape):
-    m = _pad(N_DOCS, TM)
-    _compile(asym_k.asym_similarity_kernel,
-             shape((B, DIM), jnp.float32), shape((BITS, DIM), jnp.float32),
-             shape((W, m), jnp.uint32), bits=BITS, tm=TM, interpret=False,
-             temperature=8.0)
+    _compile(shape, "asym_similarity_kernel")
 
 
 def test_asym_segment_sum_compiles(shape):
-    m = _pad(N_DOCS, TM)
-    _compile(asym_k.asym_segment_sum_kernel,
-             shape((B, DIM), jnp.float32), shape((BITS, DIM), jnp.float32),
-             shape((W, m), jnp.uint32), shape((1, m), jnp.int32),
-             bits=BITS, n_segments=SLOT_PAD, tm=TM, interpret=False,
-             temperature=8.0)
+    _compile(shape, "asym_segment_sum_kernel")
 
 
 def test_asym_topk_compiles(shape):
-    m = _pad(N_DOCS, TM)
-    _compile(asym_k.asym_topk_kernel,
-             shape((B, DIM), jnp.float32), shape((BITS, DIM), jnp.float32),
-             shape((W, m), jnp.uint32), bits=BITS, k=KP, m_total=N_DOCS,
-             tm=TM, interpret=False, temperature=8.0)
+    _compile(shape, "asym_topk_kernel")
 
 
 def test_hamming_similarity_compiles(shape):
-    m = _pad(N_DOCS, 512)
-    _compile(ham_k.hamming_similarity_kernel,
-             shape((B, W), jnp.uint32), shape((W, m), jnp.uint32),
-             bits=BITS, tm=512, interpret=False, temperature=8.0)
+    _compile(shape, "hamming_similarity_kernel")
 
 
 def test_hamming_segment_sum_compiles(shape):
-    m = _pad(N_DOCS, 512)
-    _compile(ham_k.hamming_segment_similarity_kernel,
-             shape((B, W), jnp.uint32), shape((W, m), jnp.uint32),
-             shape((1, m), jnp.int32), bits=BITS, n_segments=SLOT_PAD,
-             tm=512, interpret=False, temperature=8.0)
+    _compile(shape, "hamming_segment_similarity_kernel")
 
 
 def _payload_rows():
@@ -107,36 +88,94 @@ def _payload_rows():
 
 
 def test_megascan_dma_segment_sum_compiles(shape):
-    rows = _payload_rows()
-    _compile(mega_k.asym_megascan_segsum_db_kernel,
-             shape((B, DIM), jnp.float32), shape((BITS, DIM), jnp.float32),
-             shape((W, rows), jnp.uint32), shape((1, rows), jnp.int32),
-             bits=BITS, n_slots=SLOT_PAD, tm=TM, interpret=False,
-             temperature=8.0)
+    _compile(shape, "asym_megascan_segsum_db_kernel")
 
 
 @pytest.mark.parametrize("kernel", ["asym_megascan_topk_kernel",
                                     "asym_megascan_topk_db_kernel"])
 def test_megascan_topk_compiles(shape, kernel):
-    rows = _payload_rows()
-    _compile(getattr(mega_k, kernel),
-             shape((B, DIM), jnp.float32), shape((BITS, DIM), jnp.float32),
-             shape((W, rows), jnp.uint32), shape((1, rows), jnp.int32),
-             bits=BITS, k=KP, n_valid_slots=SLOTS, tm=TM, interpret=False,
-             temperature=8.0)
+    _compile(shape, kernel)
 
 
 def test_negsamp_grads_compile(shape):
-    pairs, negatives = 8_192, 5
-    _compile(ns_k.negsamp_grads_kernel,
-             shape((pairs, DIM), jnp.float32),
-             shape((pairs, DIM), jnp.float32),
-             shape((pairs, negatives, DIM), jnp.float32),
-             tb=256, interpret=False, temperature=8.0)
+    _compile(shape, "negsamp_grads_kernel")
 
 
 def test_kmeans_assign_compiles(shape):
-    _compile(km_k.assign_kernel,
-             shape((_pad(N_DOCS, 512), DIM), jnp.float32),
-             shape((_pad(SLOTS, 8), DIM), jnp.float32),
-             k_valid=SLOTS, tn=512, interpret=False)
+    _compile(shape, "kmeans_assign_kernel")
+
+
+def _cases(shape):
+    """Pallas kernel name -> (jitted entry point, abstract args, static
+    args), at the widths above."""
+    m, mh, rows = _pad(N_DOCS, TM), _pad(N_DOCS, 512), _payload_rows()
+    q, planes = shape((B, DIM), jnp.float32), shape((BITS, DIM), jnp.float32)
+    qsig = shape((B, W), jnp.uint32)
+    db, dbh = shape((W, m), jnp.uint32), shape((W, mh), jnp.uint32)
+    seg, segh = shape((1, m), jnp.int32), shape((1, mh), jnp.int32)
+    pay, slot = shape((W, rows), jnp.uint32), shape((1, rows), jnp.int32)
+    common = dict(interpret=False, temperature=8.0)
+    topk = dict(bits=BITS, k=KP, n_valid_slots=SLOTS, tm=TM, **common)
+    return {
+        "asym_similarity_kernel": (asym_k.asym_similarity_kernel,
+                                   (q, planes, db),
+                                   dict(bits=BITS, tm=TM, **common)),
+        "asym_segment_sum_kernel": (asym_k.asym_segment_sum_kernel,
+                                    (q, planes, db, seg),
+                                    dict(bits=BITS, n_segments=SLOT_PAD,
+                                         tm=TM, **common)),
+        "asym_topk_kernel": (asym_k.asym_topk_kernel, (q, planes, db),
+                             dict(bits=BITS, k=KP, m_total=N_DOCS, tm=TM,
+                                  **common)),
+        "hamming_distance_kernel": (ham_k.hamming_distance_kernel,
+                                    (qsig, dbh),
+                                    dict(tm=512, interpret=False)),
+        "hamming_similarity_kernel": (ham_k.hamming_similarity_kernel,
+                                      (qsig, dbh),
+                                      dict(bits=BITS, tm=512, **common)),
+        "hamming_segment_similarity_kernel": (
+            ham_k.hamming_segment_similarity_kernel, (qsig, dbh, segh),
+            dict(bits=BITS, n_segments=SLOT_PAD, tm=512, **common)),
+        "asym_megascan_segsum_db_kernel": (
+            mega_k.asym_megascan_segsum_db_kernel, (q, planes, pay, slot),
+            dict(bits=BITS, n_slots=SLOT_PAD, tm=TM, **common)),
+        "hamming_megascan_segsum_db_kernel": (
+            mega_k.hamming_megascan_segsum_db_kernel, (qsig, pay, slot),
+            dict(bits=BITS, n_slots=SLOT_PAD, tm=TM, **common)),
+        "asym_megascan_topk_kernel": (mega_k.asym_megascan_topk_kernel,
+                                      (q, planes, pay, slot), topk),
+        "asym_megascan_topk_db_kernel": (
+            mega_k.asym_megascan_topk_db_kernel, (q, planes, pay, slot),
+            topk),
+        "negsamp_grads_kernel": (
+            ns_k.negsamp_grads_kernel,
+            (shape((8_192, DIM), jnp.float32),
+             shape((8_192, DIM), jnp.float32),
+             shape((8_192, 5, DIM), jnp.float32)),
+            dict(tb=256, **common)),
+        "kmeans_assign_kernel": (
+            km_k.assign_kernel,
+            (shape((_pad(N_DOCS, 512), DIM), jnp.float32),
+             shape((_pad(SLOTS, 8), DIM), jnp.float32)),
+            dict(k_valid=SLOTS, tn=512, interpret=False)),
+    }
+
+
+KERNEL_NAMES = ["asym_similarity_kernel", "asym_segment_sum_kernel",
+                "asym_topk_kernel", "hamming_distance_kernel",
+                "hamming_similarity_kernel",
+                "hamming_segment_similarity_kernel",
+                "asym_megascan_segsum_db_kernel",
+                "hamming_megascan_segsum_db_kernel",
+                "asym_megascan_topk_kernel", "asym_megascan_topk_db_kernel",
+                "negsamp_grads_kernel", "kmeans_assign_kernel"]
+
+
+@pytest.mark.parametrize("name", KERNEL_NAMES)
+def test_lowered_kernel_carries_its_name(shape, name):
+    """Every ``pallas_call`` passes a stable ``name=``: the TPU custom
+    call (and so the op in a device trace) is named after it, whatever
+    jitted function wraps the kernel."""
+    fn, args, static = _cases(shape)[name]
+    text = fn.lower(*args, **static).as_text()
+    assert f'kernel_name = "{name}"' in text
