@@ -139,8 +139,10 @@ class ExecutionReport:
     wait, the planner's device wait, the draws, the shared scan with
     its gather, the reduce with cache populate and budget feedback).
     ``counters``: ``shard_visits`` (distinct shards scanned, the union
-    of ``plan``), ``plan_shards`` (the sum of the per-query plan sizes)
-    and ``scan_cpu_us`` (process CPU time over the scan, every thread).
+    of ``plan``), ``plan_shards`` (the sum of the per-query plan sizes),
+    ``scan_cpu_us`` (process CPU time over the scan, every thread) and
+    ``scan_sweep`` (1 when the executor ran the whole scan on the
+    calling thread, ``last_job["sweep"]``; 0 otherwise).
     """
     n_queries: int
     rate: float                          # nominal rate passed to execute
@@ -620,7 +622,9 @@ class QueryBatch:
             balance=balance, budget=budget, degraded=degraded,
             cache=cache_meta, batch=rec.seq, stages=dict(rec.stages),
             counters=dict(shard_visits=visits, plan_shards=sum(sizes),
-                          scan_cpu_us=round(scan_cpu_s * 1e6)))
+                          scan_cpu_us=round(scan_cpu_s * 1e6),
+                          scan_sweep=(int(job.get("sweep", 0))
+                                      if isinstance(job, dict) else 0)))
         return results
 
     def _generation(self) -> Generation:

@@ -30,7 +30,16 @@ tasks run on a worker pool with
     lock convoy makes 4+ workers *slower* than 1-2 — so when the last
     job's median task time falls under ``gil_floor_s`` the pool shrinks
     to 2 workers; it widens back to ``workers`` as soon as tasks are
-    long enough to release the GIL meaningfully.
+    long enough to release the GIL meaningfully,
+  * the *sweep* (on unless ``gil_floor_s=0.0`` or a ``job_deadline_s``
+    is set): a job starts on the calling thread, running its shards in
+    order with no pool, futures or completion queue — for ~10-100 us
+    numpy tasks the pool's hand-offs under the interpreter lock cost
+    more than the work — and hands the rest to the pool once its tasks
+    prove long (median at least ``gil_floor_s``, checked from the first
+    task on, or one past the straggler threshold).
+    ``stats["sweep_jobs"]`` counts the jobs that never left the
+    calling thread, ``last_job["sweep"]`` marks one.
 
 This executor is the *single-host* layer: it treats every shard it is
 handed as locally resident.  Multi-host topologies stack
@@ -227,7 +236,8 @@ class ShardTaskExecutor:
                                       "jobs": 0, "pool_rebuilds": 0,
                                       "lost_shards": 0,
                                       "stale_completions": 0,
-                                      "megascan_jobs": 0}
+                                      "megascan_jobs": 0,
+                                      "sweep_jobs": 0}
         # job epoch: bumped at every job start; completion records are
         # tagged with it so futures abandoned by a deadline-expired job
         # are recognizably stale when they finish late.  The completions
@@ -313,14 +323,17 @@ class ShardTaskExecutor:
     ) -> Dict[int, Any]:
         """Run ``fn(shard)`` for every id; returns {shard_id: result}.
 
-        The completion loop is event-driven: every future signals a
-        queue via ``add_done_callback`` and the scheduler blocks on that
-        queue, so bookkeeping is O(1) per completion.  (The previous
-        ``wait(..., FIRST_COMPLETED)`` polling loop re-registered a
-        waiter on every still-pending future each iteration — O(tasks)
-        per completion, O(tasks^2) per job — which at shared-scan batch
-        sizes cost more than the shard work itself.)  Straggler checks
-        run on 50 ms ticks and on each completion.
+        The job starts as a sweep on the calling thread (``_sweep``) and
+        moves to the warm pool only when its tasks are long enough to
+        gain from threads; a job with a deadline starts on the pool.
+        The pooled completion loop is event-driven: every future
+        signals a queue via ``add_done_callback`` and the scheduler
+        blocks on that queue, so bookkeeping is O(1) per completion.  (The previous ``wait(..., FIRST_COMPLETED)``
+        polling loop re-registered a waiter on every still-pending
+        future each iteration — O(tasks) per completion, O(tasks^2) per
+        job — which at shared-scan batch sizes cost more than the shard
+        work itself.)  Straggler checks run on 50 ms ticks and on each
+        completion.
 
         A ``MegascanSpec``-tagged composite (see ``run_shared_scan``)
         short-circuits the per-shard task fan-out entirely: the whole
@@ -331,33 +344,150 @@ class ShardTaskExecutor:
         if spec is not None and self.megakernel:
             with self._job_lock:
                 return self._run_group_scan(corpus, shard_ids, fn, spec)
-        pool = self._acquire_pool()
-        try:
-            # jobs are serialized: the epoch guard on the shared
-            # completions queue assumes one live job owns the loop
-            with self._job_lock:
-                return self._run_job(pool, corpus, shard_ids, fn)
-        finally:
-            self._release_pool()
+        # jobs are serialized: the epoch guard on the shared
+        # completions queue assumes one live job owns the loop
+        with self._job_lock:
+            return self._run_job(corpus, shard_ids, fn)
 
     def _run_job(
         self,
-        pool: ThreadPoolExecutor,
         corpus,
         shard_ids: Sequence[int],
         fn: Callable[[Any], Any],
     ) -> Dict[int, Any]:
+        """One per-shard job: the sweep on the calling thread first
+        (``gil_floor_s > 0`` and no ``job_deadline_s``), then the pooled
+        loop for whatever the sweep handed off.  ``gil_floor_s=0.0``
+        runs the pooled loop alone, and so does a job with a deadline:
+        only the pool can abandon a stalled task when the deadline
+        expires."""
         ids = [int(s) for s in shard_ids]
         t_job = time.perf_counter()
         deadline = (t_job + self.job_deadline_s
                     if self.job_deadline_s is not None else None)
         self._job_epoch += 1
-        epoch = self._job_epoch
         job = self.stats["jobs"]
         if self.job_hook is not None:
             self.job_hook(job)
         results: Dict[int, Any] = {}
         attempts: Dict[int, int] = {i: 0 for i in ids}
+        durations: list = []
+        rest, timed_out = ids, False
+        sweeping = self.gil_floor_s > 0.0 and deadline is None
+        if sweeping:
+            rest = self._sweep(corpus, ids, fn, job, results, attempts,
+                               durations)
+        swept = sweeping and not rest
+        if rest:
+            pool = self._acquire_pool()
+            try:
+                timed_out = self._run_pooled(
+                    pool, corpus, ids, rest, fn, job, deadline, results,
+                    attempts, durations)
+            finally:
+                self._release_pool()
+        missing = [s for s in ids if s not in results]
+        if missing and not self.allow_partial:
+            if timed_out:
+                raise ShardTaskError(
+                    f"job deadline ({self.job_deadline_s}s) expired; "
+                    f"shards incomplete: {missing}")
+            raise ShardTaskError(f"shards never completed: {missing}")
+        self.stats["lost_shards"] += len(missing)
+        median_task = float(np.median(durations)) if durations else 0.0
+        if durations:
+            # feeds adaptive granularity scaling for the next job
+            self._median_task_s = median_task
+        self.stats["jobs"] += 1
+        self.stats["sweep_jobs"] += swept
+        self.last_job = {
+            "wall_s": time.perf_counter() - t_job,
+            "tasks": float(len(ids)),
+            "median_task_s": median_task,
+            "lost_shards": float(len(missing)),
+            "sweep": float(swept),
+        }
+        return results
+
+    def _sweep(self, corpus, ids: list, fn, job: int,
+               results: Dict[int, Any], attempts: Dict[int, int],
+               durations: list) -> list:
+        """Run the job's shards in order on the calling thread: no pool,
+        futures or completion queue, so a ~10-100 us numpy task costs
+        its own time instead of ~70 us of hand-offs under the
+        interpreter lock.  The per-shard contract is the pooled loop's:
+        both hooks on every attempt, retries up to ``max_retries`` with
+        the same capped backoff, a permanent failure raising
+        ``ShardTaskError`` or, with ``allow_partial``, leaving the shard
+        missing.  A job with a deadline never sweeps (see ``_run_job``).
+
+        Returns the shards not yet run, which ``_run_job`` hands to the
+        pooled loop.  The sweep hands off as soon as the median task
+        wall time reaches ``gil_floor_s`` (checked after the first task
+        and again at doubling counts, so a job of long tasks leaves
+        after one), or one task runs past the pool's straggler
+        threshold.  The pooled loop inherits ``durations``, so its
+        speculation can fire at once.  What the sweep gives up is a
+        speculative duplicate of a stuck task it is running: in one
+        process a sub-millisecond task that stalls is a stall of the
+        interpreter, which a second thread cannot beat."""
+        check_at = 1
+        median = 0.0
+        for n, sid in enumerate(ids):
+            while True:
+                attempts[sid] += 1
+                attempt = attempts[sid]
+                t_run = time.perf_counter()
+                try:
+                    if self.fault_hook is not None:
+                        self.fault_hook(sid, attempt)
+                    if self.task_hook is not None:
+                        self.task_hook(sid, attempt, job)
+                    results[sid] = fn(corpus.shards[sid])
+                except Exception as exc:
+                    _log.warning("shard %d task failed (attempt %d): %s: %s",
+                                 sid, attempt, type(exc).__name__, exc)
+                    if attempt <= self.max_retries:
+                        self.stats["retries"] += 1
+                        time.sleep(min(
+                            self.retry_backoff_cap_s,
+                            self.retry_backoff_s * 2.0 ** (attempt - 1)))
+                        continue
+                    if not self.allow_partial:
+                        raise ShardTaskError(
+                            f"shard {sid} failed after {attempt} "
+                            f"attempts") from exc
+                    break
+                took = time.perf_counter() - t_run
+                durations.append(took)
+                if len(durations) >= check_at:
+                    check_at *= 2
+                    median = float(np.median(durations))
+                    if median >= self.gil_floor_s:
+                        return ids[n + 1:]
+                if took > self.straggler_factor * max(median,
+                                                      self.min_straggler_s):
+                    return ids[n + 1:]
+                break
+        return []
+
+    def _run_pooled(
+        self,
+        pool: ThreadPoolExecutor,
+        corpus,
+        ids: list,
+        todo: list,
+        fn: Callable[[Any], Any],
+        job: int,
+        deadline: Optional[float],
+        results: Dict[int, Any],
+        attempts: Dict[int, int],
+        durations: list,
+    ) -> bool:
+        """The pooled loop: submit ``todo`` to the warm pool and collect
+        into ``results`` / ``durations``, with retries, speculation and
+        the deadline.  Returns whether the deadline expired."""
+        epoch = self._job_epoch
         lock = threading.Lock()
 
         # live[sid][attempt] = when that attempt actually began executing
@@ -382,7 +512,6 @@ class ShardTaskExecutor:
 
         completions = self._completions
         in_flight = 0
-        durations: list = []
         speculated: set = set()
         # retries waiting out their backoff: heap of (due_time, sid)
         delayed: list = []
@@ -442,9 +571,8 @@ class ShardTaskExecutor:
         # the job abandons its in-flight futures on the warm pool and
         # the epoch guard disposes of their late completions.
         fatal: Optional[ShardTaskError] = None
-        lost: set = set()
         timed_out = False
-        for sid in ids:
+        for sid in todo:
             submit(sid)
         while in_flight or delayed:
             now = time.perf_counter()
@@ -488,7 +616,6 @@ class ShardTaskExecutor:
                 if sid not in results:
                     results[sid] = res
                     durations.append(now - t_start)
-                    lost.discard(sid)   # late speculative success
             except Exception as exc:
                 _log.warning("shard %d task failed (attempt %d): %s: %s",
                              sid, attempt, type(exc).__name__, exc)
@@ -500,36 +627,17 @@ class ShardTaskExecutor:
                           # already failing — nothing to redo
                 elif attempts[sid] <= self.max_retries:
                     schedule_retry(sid)
-                elif self.allow_partial:
-                    lost.add(sid)   # degrade instead of failing the job
-                else:
+                elif not self.allow_partial:
                     fatal = ShardTaskError(
                         f"shard {sid} failed after "
                         f"{attempts[sid]} attempts")
+                # with allow_partial the shard stays missing: the job
+                # degrades instead of failing
             if fatal is None:
                 check_stragglers(now)
         if fatal is not None:
             raise fatal
-        missing = [s for s in ids if s not in results]
-        if missing and not self.allow_partial:
-            if timed_out:
-                raise ShardTaskError(
-                    f"job deadline ({self.job_deadline_s}s) expired; "
-                    f"shards incomplete: {missing}")
-            raise ShardTaskError(f"shards never completed: {missing}")
-        self.stats["lost_shards"] += len(missing)
-        median_task = float(np.median(durations)) if durations else 0.0
-        if durations:
-            # feeds adaptive granularity scaling for the next job
-            self._median_task_s = median_task
-        self.stats["jobs"] += 1
-        self.last_job = {
-            "wall_s": time.perf_counter() - t_job,
-            "tasks": float(len(ids)),
-            "median_task_s": median_task,
-            "lost_shards": float(len(missing)),
-        }
-        return results
+        return timed_out
 
     def _run_group_scan(self, corpus, shard_ids: Sequence[int], fn,
                         spec) -> Dict[int, Any]:

@@ -585,6 +585,9 @@ class HostGroupExecutor:
             "hosts": float(len(per_host)),
             "per_host_wall_s": walls,
             "lost_shards": float(len(lost)),
+            # 1 when every host ran its slice as a calling-thread sweep
+            "sweep": float(bool(per_host) and all(
+                j.get("sweep") for j in per_host.values())),
         }
         if audit is not None:
             # estimated (at split time) vs realized (measured) per-host

@@ -66,8 +66,8 @@ worth.  Beside the batch and close-reason counts:
     deadline or size;
   * ``<stage>_us`` sums the self seconds of the engine's stage spans
     (``ExecutionReport.stages``: probe, plan, plan_wait, sample, scan,
-    reduce) and ``scan_cpu_us`` / ``shard_visits`` / ``plan_shards``
-    its counters, once per batch that reports them.
+    reduce) and ``scan_cpu_us`` / ``shard_visits`` / ``plan_shards`` /
+    ``scan_sweep`` its counters, once per batch that reports them.
 
 Each batch runs under the profiler span ``emapprox.window`` (``n``,
 ``reason`` and the engine's ``batch`` number), the parent of the
@@ -134,6 +134,7 @@ class BatchWindow:
             "probe_us": 0, "plan_us": 0, "plan_wait_us": 0,
             "sample_us": 0, "scan_us": 0, "reduce_us": 0,
             "scan_cpu_us": 0, "shard_visits": 0, "plan_shards": 0,
+            "scan_sweep": 0,
         }
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="batch-window")

@@ -236,6 +236,40 @@ def test_query_batch_precise_and_srcs(small_corpus, built_index):
         QueryBatch(small_corpus, built_index, method="nope")
 
 
+_KIND_BATCHES = {
+    "count": [BatchQuery.count([5]), BatchQuery.count([7, 2]),
+              BatchQuery.count([3, 8, 11])],
+    "boolean": [BatchQuery.boolean(parse_boolean([4, "or", 9, "and", 12])),
+                BatchQuery.boolean(parse_boolean([3, "and", 5]))],
+    "ranked": [BatchQuery.ranked([3, 8, 11], k=5),
+               BatchQuery.ranked([1, 2], k=8)],
+}
+
+
+@pytest.mark.parametrize("kind", list(_KIND_BATCHES))
+def test_query_batch_sweep_matches_pool(small_corpus, built_index, kind):
+    """The same batch through an executor that sweeps on the calling
+    thread and through one pinned to the pool: identical answers and
+    executed plans, and the report says which ran."""
+    runs = {}
+    # a floor of 1 s keeps every task of the small corpus in the sweep
+    for mode, floor in (("sweep", 1.0), ("pooled", 0.0)):
+        with ShardTaskExecutor(workers=3, gil_floor_s=floor) as ex:
+            eng = QueryBatch(small_corpus, built_index, executor=ex)
+            got = eng.execute(_KIND_BATCHES[kind], 0.3,
+                              rng=np.random.default_rng(42))
+            runs[mode] = (got, eng.last_report)
+    (sweep, sweep_rep), (pooled, pooled_rep) = runs["sweep"], runs["pooled"]
+    for a, b in zip(sweep, pooled):
+        np.testing.assert_equal(a._replace(elapsed_s=0.0)._asdict(),
+                                b._replace(elapsed_s=0.0)._asdict())
+    assert len(sweep_rep.plan) == len(pooled_rep.plan)
+    for a, b in zip(sweep_rep.plan, pooled_rep.plan):
+        np.testing.assert_array_equal(a, b)
+    assert sweep_rep.counters["scan_sweep"] == 1
+    assert pooled_rep.counters["scan_sweep"] == 0
+
+
 def test_query_batch_under_faults(small_corpus, built_index):
     fails = {"n": 0}
 

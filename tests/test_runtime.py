@@ -106,7 +106,7 @@ def test_executor_failure_drains_in_flight_tasks():
 
 
 def test_executor_warm_pool_persists_across_jobs():
-    ex = ShardTaskExecutor(workers=3)
+    ex = ShardTaskExecutor(workers=3, gil_floor_s=0.0)   # pinned to the pool
     ex.map_shards(_FakeCorpus(10), range(10), lambda s: s.shard_id)
     pool = ex._pool
     assert pool is not None
@@ -138,10 +138,214 @@ def test_executor_adaptive_workers_by_task_granularity():
 
 
 def test_executor_context_manager_closes_pool():
-    with ShardTaskExecutor(workers=2) as ex:
+    with ShardTaskExecutor(workers=2, gil_floor_s=0.0) as ex:
         ex.map_shards(_FakeCorpus(4), range(4), lambda s: 1)
         assert ex._pool is not None
     assert ex._pool is None
+
+
+# ----------------------------------------------------------------------
+# the sweep (calling thread, the default) and the pool (gil_floor_s=0.0)
+# keep one per-shard contract
+# ----------------------------------------------------------------------
+MODES = {"sweep": {}, "pooled": {"gil_floor_s": 0.0}}
+
+
+def _executor(mode, **kw):
+    return ShardTaskExecutor(workers=4, **MODES[mode], **kw)
+
+
+@pytest.mark.parametrize("mode", list(MODES))
+def test_executor_modes_give_equal_results(mode):
+    with _executor(mode) as ex:
+        out = ex.map_shards(_FakeCorpus(16), range(16),
+                            lambda s: (s.shard_id, s.shard_id ** 2))
+        assert out == {i: (i, i * i) for i in range(16)}
+        assert ex.last_job["sweep"] == float(mode == "sweep")
+        assert ex.last_job["tasks"] == 16.0
+        assert ex.last_job["wall_s"] > 0.0
+        assert ex.last_job["lost_shards"] == 0.0
+        assert ex.stats["jobs"] == 1
+
+
+@pytest.mark.parametrize("mode", list(MODES))
+def test_executor_hooks_fire_per_shard_and_attempt(mode):
+    faults, tasks, jobs = [], [], []
+    lock = threading.Lock()
+
+    def fault_hook(sid, attempt):
+        with lock:
+            faults.append((sid, attempt))
+        if sid == 3 and attempt == 1:
+            raise RuntimeError("injected")
+
+    def task_hook(sid, attempt, job):
+        with lock:
+            tasks.append((sid, attempt, job))
+
+    with _executor(mode, fault_hook=fault_hook, task_hook=task_hook,
+                   job_hook=jobs.append, max_retries=1) as ex:
+        ex.map_shards(_FakeCorpus(6), range(6), lambda s: s.shard_id)
+        ex.map_shards(_FakeCorpus(6), [5], lambda s: s.shard_id)
+    assert jobs == [0, 1]
+    assert sorted(faults) == sorted(
+        [(s, 1) for s in range(6)] + [(3, 2), (5, 1)])
+    # the fault hook raised before the task hook on shard 3's first try
+    assert sorted(tasks) == sorted(
+        [(s, 1, 0) for s in range(6) if s != 3] + [(3, 2, 0), (5, 1, 1)])
+
+
+@pytest.mark.parametrize("mode", list(MODES))
+def test_executor_transient_faults_retry_with_backoff(mode):
+    def hook(sid, attempt):
+        if sid == 2 and attempt <= 2:
+            raise RuntimeError("transient")
+
+    with _executor(mode, fault_hook=hook, max_retries=2,
+                   retry_backoff_s=0.03, retry_backoff_cap_s=1.0) as ex:
+        t0 = time.perf_counter()
+        out = ex.map_shards(_FakeCorpus(6), range(6), lambda s: s.shard_id)
+        dt = time.perf_counter() - t0
+    assert out == {i: i for i in range(6)}
+    assert ex.stats["retries"] == 2
+    assert dt >= 0.08            # waited 0.03 then 0.06 before retrying
+
+
+@pytest.mark.parametrize("mode", list(MODES))
+def test_executor_permanent_failure_raises_shard_task_error(mode):
+    def hook(sid, attempt):
+        if sid == 4:
+            raise RuntimeError("dead shard")
+
+    with _executor(mode, fault_hook=hook, max_retries=1) as ex:
+        with pytest.raises(ShardTaskError, match="shard 4 failed"):
+            ex.map_shards(_FakeCorpus(8), range(8), lambda s: s.shard_id)
+        assert ex.stats["retries"] == 1
+        assert ex.stats["jobs"] == 0 and ex.last_job is None
+
+
+@pytest.mark.parametrize("mode", list(MODES))
+def test_executor_allow_partial_records_lost_shards(mode):
+    def hook(sid, attempt):
+        if sid == 5:
+            raise RuntimeError("dead shard")
+
+    with _executor(mode, fault_hook=hook, max_retries=1,
+                   allow_partial=True) as ex:
+        out = ex.map_shards(_FakeCorpus(8), range(8), lambda s: s.shard_id)
+        assert out == {i: i for i in range(8) if i != 5}
+        assert ex.stats["lost_shards"] == 1
+        assert ex.last_job["lost_shards"] == 1.0
+        assert ex.last_job["sweep"] == float(mode == "sweep")
+
+
+@pytest.mark.parametrize("mode", list(MODES))
+@pytest.mark.parametrize("partial", [True, False])
+def test_executor_deadline_abandons_stalled_task_on_pool(mode, partial):
+    """A job with a deadline never sweeps: only the pool can abandon a
+    stalled task when the deadline expires, so both modes run it there."""
+    def work(shard):
+        if shard.shard_id == 2:
+            time.sleep(0.5)      # stalls far past the 0.1 s deadline
+        return shard.shard_id
+
+    with _executor(mode, job_deadline_s=0.1, allow_partial=partial) as ex:
+        t0 = time.perf_counter()
+        if not partial:
+            with pytest.raises(ShardTaskError, match="deadline"):
+                ex.map_shards(_FakeCorpus(10), range(10), work)
+        else:
+            out = ex.map_shards(_FakeCorpus(10), range(10), work)
+        # abandoned at the deadline instead of holding the job open
+        assert time.perf_counter() - t0 < 0.4
+        assert ex.stats["sweep_jobs"] == 0
+        assert ex._pool is not None
+    if not partial:
+        return
+    assert out == {i: i for i in range(10) if i != 2}
+    assert ex.last_job["sweep"] == 0.0
+    assert ex.last_job["lost_shards"] == 1.0
+    assert ex.stats["lost_shards"] == 1
+
+
+@pytest.mark.parametrize("mode", list(MODES))
+def test_executor_sweep_jobs_count(mode):
+    with _executor(mode) as ex:
+        for n in (3, 10, 0):
+            ex.map_shards(_FakeCorpus(10), range(n), lambda s: s.shard_id)
+        assert ex.stats["jobs"] == 3
+        if mode == "sweep":
+            assert ex.stats["sweep_jobs"] == 3
+            assert ex._pool is None          # tiny tasks never left
+        else:
+            assert ex.stats["sweep_jobs"] == 0
+            assert ex._pool is not None
+
+
+def test_executor_sweep_hands_long_tasks_to_pool_and_speculates():
+    """A job whose first task passes ``gil_floor_s`` leaves the sweep
+    after that task, and a 1.5 s straggler among the rest is still
+    speculated on the pool, which inherits the sweep's task times."""
+    seen, threads = {}, {}
+    lock = threading.Lock()
+
+    def work(shard):
+        with lock:
+            n = seen.get(shard.shard_id, 0)
+            seen[shard.shard_id] = n + 1
+            threads[shard.shard_id] = threading.current_thread()
+        time.sleep(1.5 if shard.shard_id == 9 and n == 0 else 0.01)
+        return shard.shard_id
+
+    with ShardTaskExecutor(workers=4, min_completed_for_speculation=4) as ex:
+        out = ex.map_shards(_FakeCorpus(12), range(12), work)
+        assert out == {i: i for i in range(12)}
+        assert ex.last_job["sweep"] == 0.0
+        assert ex.stats["sweep_jobs"] == 0
+        assert ex._pool is not None
+        assert ex.stats["speculative"] >= 1
+        assert seen[9] >= 2
+        # the first ran once, on the calling thread; the rest on the pool
+        assert seen[0] == 1
+        assert threads[0] is threading.current_thread()
+        assert all(threads[i] is not threads[0] for i in range(1, 12))
+
+
+def test_executor_sweep_hands_off_long_tasks_after_the_first():
+    """Four 50 ms tasks that release the interpreter lock run on the
+    pool after the first, not one after another: about 100 ms, where
+    the calling thread alone would take 200 ms."""
+    def work(shard):
+        time.sleep(0.05)
+        return shard.shard_id
+
+    with ShardTaskExecutor(workers=4) as ex:
+        t0 = time.perf_counter()
+        out = ex.map_shards(_FakeCorpus(4), range(4), work)
+        dt = time.perf_counter() - t0
+        assert out == {i: i for i in range(4)}
+        assert ex.last_job["sweep"] == 0.0
+        assert ex.stats["sweep_jobs"] == 0
+    assert dt < 0.15
+
+
+def test_executor_sweep_hands_off_after_one_straggler():
+    """One task past the straggler threshold (3 x 50 ms) hands the
+    rest of the job to the pool even while the median is tiny."""
+    def work(shard):
+        if shard.shard_id == 1:
+            time.sleep(0.25)
+        return shard.shard_id
+
+    with ShardTaskExecutor(workers=2) as ex:
+        out = ex.map_shards(_FakeCorpus(8), range(8), work)
+        assert out == {i: i for i in range(8)}
+        assert ex.last_job["sweep"] == 0.0
+        assert ex._pool is not None
+        # a later job of tiny tasks sweeps again
+        ex.map_shards(_FakeCorpus(8), range(8), lambda s: s.shard_id)
+        assert ex.last_job["sweep"] == 1.0
+        assert ex.stats["sweep_jobs"] == 1
 
 
 # ----------------------------------------------------------------------

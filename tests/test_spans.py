@@ -91,7 +91,7 @@ def test_report_holds_six_stages_within_elapsed(small_corpus, built_index):
     assert rec["batch"] == r.batch
     assert set(rec["stages"]) == set(STAGES)
     assert set(rec["counters"]) == {"shard_visits", "plan_shards",
-                                    "scan_cpu_us"}
+                                    "scan_cpu_us", "scan_sweep"}
 
 
 def test_counters_union_and_sum_of_plan(small_corpus, built_index):

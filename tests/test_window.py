@@ -124,7 +124,7 @@ def test_window_end_to_end_precise(small_corpus, built_index):
     """Window -> QueryBatch -> warm executor at rate 1.0: the precise
     answers must be independent of how arrivals were windowed."""
     from repro.core.queries import BatchQuery, QueryBatch, parse_boolean
-    ex = ShardTaskExecutor(workers=2)
+    ex = ShardTaskExecutor(workers=2, gil_floor_s=0.0)   # pinned to the pool
     engine = QueryBatch(small_corpus, built_index, executor=ex)
     queries = [BatchQuery.count([5]),
                BatchQuery.boolean(parse_boolean([4, "or", 9])),
