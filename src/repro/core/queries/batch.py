@@ -114,7 +114,11 @@ from repro.runtime.qcache import query_cache_vectors, query_key, sampler_class
 from repro.utils import spans
 
 # the stage spans whose self seconds every ExecutionReport carries
-STAGES = ("probe", "plan", "plan_wait", "sample", "scan", "reduce")
+STAGES = ("probe", "plan", "plan_words", "plan_wait", "sample", "scan",
+          "reduce", "reduce_topk")
+# retrieval work counters on every ExecutionReport (0 for a count batch)
+WORK_COUNTERS = ("bool_words", "bool_plans", "topk_candidates",
+                 "topk_queries")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -135,14 +139,20 @@ class ExecutionReport:
     ``batch`` is the engine's sequence number for the call, the
     ``batch=`` of its ``emapprox.*`` profiler spans; ``elapsed_s`` is
     the call's wall time.  ``stages`` holds the self seconds of each
-    stage span (``STAGES``: cache probe, planning outside its device
-    wait, the planner's device wait, the draws, the shared scan with
-    its gather, the reduce with cache populate and budget feedback).
+    stage span (``STAGES``: cache probe, planning outside the Boolean
+    word scoring and the device wait, the Boolean word scoring and its
+    AND/OR algebra, the planner's device wait, the draws, the shared
+    scan with its gather, the reduce with cache populate and budget
+    feedback outside the ranked merges, the ranked merges).
     ``counters``: ``shard_visits`` (distinct shards scanned, the union
     of ``plan``), ``plan_shards`` (the sum of the per-query plan sizes),
-    ``scan_cpu_us`` (process CPU time over the scan, every thread) and
+    ``scan_cpu_us`` (process CPU time over the scan, every thread),
     ``scan_sweep`` (1 when the executor ran the whole scan on the
-    calling thread, ``last_job["sweep"]``; 0 otherwise).
+    calling thread, ``last_job["sweep"]``; 0 otherwise), ``bool_words``
+    (distinct Boolean words scored), ``bool_plans`` (calls of
+    ``ApproxIndex.word_shard_similarities_batch``), ``topk_candidates``
+    (candidates that entered ranked merges) and ``topk_queries``
+    (ranked queries merged).
     """
     n_queries: int
     rate: float                          # nominal rate passed to execute
@@ -345,7 +355,8 @@ class QueryBatch:
     # ------------------------------------------------------------------
     def _probability_rows(
             self, queries: Sequence[BatchQuery], corpus: ShardedCorpus,
-            index: Optional[ApproxIndex]) -> List[np.ndarray]:
+            index: Optional[ApproxIndex],
+            work: Dict[str, int]) -> List[np.ndarray]:
         # corpus/index come in as the refs execute() captured at entry
         # (RCU: a concurrent ingest swap must not split one batch
         # across two content generations)
@@ -364,19 +375,23 @@ class QueryBatch:
         # ... and one for the union of Boolean query words
         bool_pos = [i for i, q in enumerate(queries) if q.kind == "bool"]
         if bool_pos:
-            words = sorted({w for i in bool_pos
-                            for w in queries[i].expr.words()})
-            word_rows = dict(zip(
-                words, index.word_shard_similarities_batch(words)))
+            with spans.span("emapprox.plan.words"):
+                words = sorted({w for i in bool_pos
+                                for w in queries[i].expr.words()})
+                word_rows = dict(zip(
+                    words, index.word_shard_similarities_batch(words)))
 
-            def algebra(e: BoolExpr) -> np.ndarray:
-                if e.op == "word":
-                    return word_rows[e.word]
-                l, r = algebra(e.left), algebra(e.right)
-                return l * r if e.op == "and" else l + r
+                def algebra(e: BoolExpr) -> np.ndarray:
+                    if e.op == "word":
+                        return word_rows[e.word]
+                    l, r = algebra(e.left), algebra(e.right)
+                    return l * r if e.op == "and" else l + r
 
-            for i in bool_pos:
-                rows[i] = similarity_probabilities(algebra(queries[i].expr))
+                for i in bool_pos:
+                    rows[i] = similarity_probabilities(
+                        algebra(queries[i].expr))
+            work["bool_words"] += len(words)
+            work["bool_plans"] += 1
         return rows
 
     # ------------------------------------------------------------------
@@ -441,11 +456,12 @@ class QueryBatch:
         point-in-time decision, never replayable as full fidelity.
 
         The call is the profiler span ``emapprox.execute``, with the
-        stage spans ``emapprox.probe`` / ``.plan`` (``.plan.wait``
-        inside it) / ``.sample`` / ``.scan`` (``.gather`` inside it) /
-        ``.reduce`` nested under it on the calling thread, all tagged
-        with the call's ``batch`` number; their self seconds land on
-        ``last_report.stages``.
+        stage spans ``emapprox.probe`` / ``.plan`` (``.plan.words``, the
+        Boolean word scoring, and ``.plan.wait`` inside it) /
+        ``.sample`` / ``.scan`` (``.gather`` inside it) / ``.reduce``
+        (``.reduce.topk``, each ranked merge, inside it) nested under
+        it on the calling thread, all tagged with the call's ``batch``
+        number; their self seconds land on ``last_report.stages``.
         """
         with spans.batch(next(self._batch_seq), STAGES,
                          n=len(queries)) as rec:
@@ -512,13 +528,14 @@ class QueryBatch:
             samples[i], plan[i] = e.sample, e.plan
         need = [i for i in range(n) if samples[i] is None]
         rows_by_pos: Dict[int, np.ndarray] = {}
+        work = dict.fromkeys(WORK_COUNTERS, 0)
         if need and all(rates[i] >= 1.0 for i in need):
             for i in need:
                 samples[i], plan[i] = census, all_ids
         elif need:
             with spans.span("emapprox.plan"):
                 rows = self._probability_rows(
-                    [queries[i] for i in need], corpus, index)
+                    [queries[i] for i in need], corpus, index, work)
             # aggregation keeps the with-replacement multiset (the
             # Hansen-Hurwitz estimator needs it); retrieval unions docs
             # over the sample, so it draws distinct shards — same
@@ -594,7 +611,7 @@ class QueryBatch:
                 hits[i].result._replace(elapsed_s=elapsed) if i in hits
                 else self._reduce(queries[i], samples[i], plan[i],
                                   per_query[i], elapsed, rates[i] >= 1.0,
-                                  n_shards, lost=lost_per_query[i])
+                                  n_shards, work, lost=lost_per_query[i])
                 for i in range(n)]
 
             # populate: misses and near-hits insert their own
@@ -624,7 +641,8 @@ class QueryBatch:
             counters=dict(shard_visits=visits, plan_shards=sum(sizes),
                           scan_cpu_us=round(scan_cpu_s * 1e6),
                           scan_sweep=(int(job.get("sweep", 0))
-                                      if isinstance(job, dict) else 0)))
+                                      if isinstance(job, dict) else 0),
+                          **work))
         return results
 
     def _generation(self) -> Generation:
@@ -710,7 +728,7 @@ class QueryBatch:
     def _reduce(self, q: BatchQuery, sample: SampleResult,
                 distinct: np.ndarray, by_shard: Dict[int, Any],
                 elapsed: float, precise: bool, n_shards: int,
-                lost: int = 0) -> Any:
+                work: Dict[str, int], lost: int = 0) -> Any:
         conf = (q.budget.confidence if q.budget is not None
                 else self.confidence)
         if lost:
@@ -764,12 +782,15 @@ class QueryBatch:
             return RetrievalResult(np.unique(doc_ids), sample, len(distinct),
                                    n_shards, elapsed, est, lost)
         parts = [by_shard[int(s)] for s in distinct]
-        if parts:
-            ids = np.concatenate([p[0] for p in parts])
-            sc = np.concatenate([p[1] for p in parts])
-        else:
-            ids, sc = np.zeros(0, np.int64), np.zeros(0, np.float64)
-        order = np.argsort(-sc, kind="stable")[:q.k]
+        with spans.span("emapprox.reduce.topk"):
+            if parts:
+                ids = np.concatenate([p[0] for p in parts])
+                sc = np.concatenate([p[1] for p in parts])
+            else:
+                ids, sc = np.zeros(0, np.int64), np.zeros(0, np.float64)
+            order = np.argsort(-sc, kind="stable")[:q.k]
+        work["topk_candidates"] += len(ids)
+        work["topk_queries"] += 1
         est = None
         if self.ci:
             if precise:

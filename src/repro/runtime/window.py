@@ -65,9 +65,11 @@ worth.  Beside the batch and close-reason counts:
     ``submit``), delay the rest — the open window waiting for its
     deadline or size;
   * ``<stage>_us`` sums the self seconds of the engine's stage spans
-    (``ExecutionReport.stages``: probe, plan, plan_wait, sample, scan,
-    reduce) and ``scan_cpu_us`` / ``shard_visits`` / ``plan_shards`` /
-    ``scan_sweep`` its counters, once per batch that reports them.
+    (``ExecutionReport.stages``: probe, plan, plan_words, plan_wait,
+    sample, scan, reduce, reduce_topk) and ``scan_cpu_us`` /
+    ``shard_visits`` / ``plan_shards`` / ``scan_sweep`` /
+    ``bool_words`` / ``bool_plans`` / ``topk_candidates`` /
+    ``topk_queries`` its counters, once per batch that reports them.
 
 Each batch runs under the profiler span ``emapprox.window`` (``n``,
 ``reason`` and the engine's ``batch`` number), the parent of the

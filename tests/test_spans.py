@@ -81,17 +81,51 @@ def test_report_holds_six_stages_within_elapsed(small_corpus, built_index):
     r = eng.last_report
     assert r.batch == first + 1
     assert set(r.stages) == set(STAGES)
+    assert len(STAGES) == 8
     assert all(v >= 0.0 for v in r.stages.values())
     assert sum(r.stages.values()) <= r.elapsed_s
     # the work each stage names happened
-    for key in ("plan", "plan_wait", "sample", "scan", "reduce"):
+    for key in ("plan", "plan_words", "plan_wait", "sample", "scan",
+                "reduce", "reduce_topk"):
         assert r.stages[key] > 0.0, key
     assert r.stages["probe"] == 0.0          # no cache attached
     rec = json.loads(json.dumps(r.record()))
     assert rec["batch"] == r.batch
     assert set(rec["stages"]) == set(STAGES)
     assert set(rec["counters"]) == {"shard_visits", "plan_shards",
-                                    "scan_cpu_us", "scan_sweep"}
+                                    "scan_cpu_us", "scan_sweep",
+                                    "bool_words", "bool_plans",
+                                    "topk_candidates", "topk_queries"}
+
+
+def test_mixed_batch_counts_retrieval_work(small_corpus, built_index):
+    qs = QS + [BatchQuery.boolean(parse_boolean(
+        ["(", 3, "or", 5, ")", "and", 11])),
+               BatchQuery.ranked([5, 8], k=3)]
+    eng = QueryBatch(small_corpus, built_index)
+    results = eng.execute(qs, 0.4, rng=np.random.default_rng(6))
+    r = eng.last_report
+    # the Boolean words {3, 8} and {3, 5, 11}, scored once as a union
+    assert r.counters["bool_words"] == 4
+    assert r.counters["bool_plans"] == 1
+    assert r.counters["topk_queries"] == 2
+    # every document of every shard a ranked query drew is a candidate
+    docs = [sum(len(small_corpus.shards[int(s)].doc_ids) for s in r.plan[i])
+            for i, q in enumerate(qs) if q.kind == "ranked"]
+    assert r.counters["topk_candidates"] == sum(docs) > 0
+    assert r.stages["plan_words"] > 0.0 and r.stages["reduce_topk"] > 0.0
+    assert len(results) == len(qs)
+
+
+def test_count_only_batch_counts_no_retrieval_work(small_corpus,
+                                                   built_index):
+    eng = QueryBatch(small_corpus, built_index)
+    eng.execute(QS[:2], 0.4, rng=np.random.default_rng(7))
+    r = eng.last_report
+    for key in ("bool_words", "bool_plans", "topk_candidates",
+                "topk_queries"):
+        assert r.counters[key] == 0, key
+    assert r.stages["plan_words"] == 0.0 and r.stages["reduce_topk"] == 0.0
 
 
 def test_counters_union_and_sum_of_plan(small_corpus, built_index):
@@ -220,9 +254,9 @@ def test_profiler_trace_nests_stage_spans_under_execute(
     assert len(roots) == 1
     _, a, b, _ = roots[0]
     names = {e[0] for e in evs}
-    assert {"emapprox.plan", "emapprox.plan.wait", "emapprox.sample",
-            "emapprox.scan", "emapprox.gather",
-            "emapprox.reduce"} <= names
+    assert {"emapprox.plan", "emapprox.plan.words", "emapprox.plan.wait",
+            "emapprox.sample", "emapprox.scan", "emapprox.gather",
+            "emapprox.reduce", "emapprox.reduce.topk"} <= names
     for name, s, e, stats in evs:
         assert stats.get("batch") == seq, name
         assert a <= s <= e <= b, name
@@ -233,4 +267,6 @@ def test_profiler_trace_nests_stage_spans_under_execute(
                    for n, ps, pe, _ in evs if n == parent)
 
     assert inside("emapprox.plan.wait", "emapprox.plan")
+    assert inside("emapprox.plan.words", "emapprox.plan")
     assert inside("emapprox.gather", "emapprox.scan")
+    assert inside("emapprox.reduce.topk", "emapprox.reduce")
