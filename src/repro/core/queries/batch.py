@@ -31,6 +31,11 @@ workload should amortize:
   3. **Scan work** — per-shard operators walk the lazily-built CSR
      postings (``data/store.shard_postings``), so the second query to
      touch a shard pays O(matching tokens), not O(shard tokens).
+     Ranked and Boolean queries go further: one group pass per query
+     (``WordScan``) over the corpus-wide word-major postings
+     (``data/store.corpus_postings``) answers all the shards it drew,
+     in place of one per-shard task each; phrase counts keep their
+     per-shard tasks.
 
 Statistical behavior is unchanged: each query still draws its own pps
 sample from its own probability row (paper Eq 11), and the estimators
@@ -92,6 +97,8 @@ from repro.core.queries.retrieval import (
     RetrievalResult,
     _expr_eval_docs,
     bm25_scores_for_shard,
+    bm25_scores_over_table,
+    match_over_table,
 )
 from repro.core.sampling import (
     Estimate,
@@ -105,7 +112,9 @@ from repro.core.sampling import (
     unique_shards,
 )
 from repro.data.store import (
+    CorpusPostings,
     ShardedCorpus,
+    corpus_postings,
     count_phrase_in_shard,
     shard_postings,
 )
@@ -151,8 +160,9 @@ class ExecutionReport:
     calling thread, ``last_job["sweep"]``; 0 otherwise), ``bool_words``
     (distinct Boolean words scored), ``bool_plans`` (calls of
     ``ApproxIndex.word_shard_similarities_batch``), ``topk_candidates``
-    (candidates that entered ranked merges) and ``topk_queries``
-    (ranked queries merged).
+    (candidates that entered ranked merges), ``topk_queries`` (ranked
+    queries merged) and ``group_pairs`` (the (query, shard) pairs the
+    word-driven group scan answered, ``WordScan.answered``).
     """
     n_queries: int
     rate: float                          # nominal rate passed to execute
@@ -226,6 +236,71 @@ class BatchQuery:
         raise ValueError(f"no composed vector for kind {self.kind!r}")
 
 
+class WordScan:
+    """The group scan of a batch's word-driven queries (ranked BM25 and
+    Boolean), over the corpus-wide postings table
+    (``data/store.corpus_postings``): one vectorised pass per query
+    over all the shards it drew, in place of one per-shard task per
+    (query, shard).  Phrase counts are positional and keep their tasks.
+
+    ``run_shared_scan`` tags the batch's composite shard task with this
+    scan; the executor fires its per-shard fault seams and runs the
+    per-shard tasks that are left first, then hands the shards that
+    completed to ``run_group``.  A shard the table does not cover (grown
+    or added by live ingest after the table was built) stays with its
+    per-shard task.  Every (query, shard) result is bit for bit the
+    per-shard task's: ``(shard.doc_ids, scores)`` for a ranked query,
+    the matching global doc ids in ascending local order for a Boolean
+    one.  ``answered`` collects the pairs each pass answered."""
+
+    def __init__(self, corpus: ShardedCorpus, table: CorpusPostings,
+                 queries: Dict[int, BatchQuery], doc_freq: np.ndarray,
+                 n_docs: int, avg_len: float):
+        self.corpus = corpus
+        self.table = table
+        self.queries = queries       # batch index -> word-driven query
+        self.doc_freq = doc_freq
+        self.n_docs = n_docs
+        self.avg_len = avg_len
+        self.answered: List[int] = []
+
+    def covers(self, shard_id: int) -> bool:
+        return self.table.covers(shard_id, self.corpus.shards[shard_id])
+
+    def run_group(self, shard_ids: Sequence[int],
+                  queries_of: Dict[int, Sequence[int]]
+                  ) -> Dict[int, Dict[int, Any]]:
+        """``{shard_id: {query index: result}}`` for every shard of
+        ``shard_ids`` and each of its queries in ``queries_of``."""
+        t = self.table
+        shards_of: Dict[int, List[int]] = {}
+        for sid in shard_ids:
+            for qi in queries_of[sid]:
+                shards_of.setdefault(qi, []).append(sid)
+        out: Dict[int, Dict[int, Any]] = {sid: {} for sid in shard_ids}
+        for qi, sids in shards_of.items():
+            q = self.queries[qi]
+            drawn = np.zeros(len(t.shards), bool)
+            drawn[sids] = True
+            sel = np.asarray(sids, np.int64)
+            lo, hi = t.shard_start[sel], t.shard_start[sel + 1]
+            if q.kind == "ranked":
+                scores = bm25_scores_over_table(
+                    t, drawn, q.words, self.doc_freq, self.n_docs,
+                    self.avg_len)
+                for sid, a, b in zip(sids, lo.tolist(), hi.tolist()):
+                    out[sid][qi] = (t.shards[sid].doc_ids, scores[a:b])
+            else:
+                hit = match_over_table(q.expr, t, drawn)
+                ids = t.doc_ids[hit]
+                a = np.searchsorted(hit, lo).tolist()
+                b = np.searchsorted(hit, hi).tolist()
+                for sid, i, j in zip(sids, a, b):
+                    out[sid][qi] = ids[i:j]
+        self.answered.append(sum(len(s) for s in shards_of.values()))
+        return out
+
+
 class QueryBatch:
     """Plans, samples, and executes a mixed batch of queries end-to-end.
 
@@ -259,7 +334,7 @@ class QueryBatch:
         # the engine's world is ONE tuple so RCU readers capture
         # (corpus, index) with a single atomic attribute load — a
         # concurrent ingest swap can never hand a batch a torn pair
-        self._world = (corpus, index)
+        self.swap_world(corpus, index)
         self.executor = executor
         self.method = method
         self.confidence = confidence
@@ -292,7 +367,7 @@ class QueryBatch:
 
     @corpus.setter
     def corpus(self, corpus) -> None:
-        self._world = (corpus, self._world[1])
+        self.swap_world(corpus, self._world[1])
 
     @property
     def index(self) -> Optional[ApproxIndex]:
@@ -307,7 +382,14 @@ class QueryBatch:
         write side of live ingest.  Individual ``corpus``/``index``
         assignment still works but publishes in two stores; a swap
         that changes both MUST go through here (or a racing reader
-        could capture a torn pair)."""
+        could capture a torn pair).
+
+        The corpus's word-major postings table (``corpus_postings``,
+        which the word-driven group scan reads) is built here, before
+        the corpus is published, so no served batch ever builds it; a
+        corpus from ``append_documents`` inherits its source's table
+        and costs nothing here."""
+        corpus_postings(corpus)
         self._world = (corpus, index)
 
     @property
@@ -411,6 +493,20 @@ class QueryBatch:
             return lambda shard: (shard.doc_ids, bm25_scores_for_shard(
                 shard, q.words, doc_freq, n_docs, avg_len))
         raise ValueError(f"unknown query kind {q.kind!r}")
+
+    @staticmethod
+    def _word_scan(corpus: ShardedCorpus, queries: Sequence[BatchQuery],
+                   doc_freq: np.ndarray, n_docs: int,
+                   avg_len: float) -> Optional[WordScan]:
+        """The group scan of the batch's ranked and Boolean queries, or
+        None when the batch has none or the corpus has no postings
+        table (one published outside ``swap_world``)."""
+        table = getattr(corpus, "_postings_table", None)
+        word = {i: q for i, q in enumerate(queries)
+                if q.kind in ("ranked", "bool")}
+        if table is None or not word:
+            return None
+        return WordScan(corpus, table, word, doc_freq, n_docs, avg_len)
 
     # ------------------------------------------------------------------
     # execution
@@ -565,6 +661,10 @@ class QueryBatch:
             n_docs = corpus.n_docs
             avg_len = corpus.n_tokens / max(n_docs, 1)
         fns = [self._shard_fn(q, doc_freq, n_docs, avg_len) for q in queries]
+        scan = self._word_scan(corpus, queries, doc_freq, n_docs, avg_len)
+        if scan is not None:
+            for i in scan.queries:
+                fns[i].group = scan
 
         # exact hits scan nothing: their slot in the executed plan is
         # empty, and an all-hit batch skips executor dispatch entirely
@@ -642,6 +742,8 @@ class QueryBatch:
                           scan_cpu_us=round(scan_cpu_s * 1e6),
                           scan_sweep=(int(job.get("sweep", 0))
                                       if isinstance(job, dict) else 0),
+                          group_pairs=(sum(scan.answered)
+                                       if scan is not None else 0),
                           **work))
         return results
 
@@ -718,10 +820,14 @@ class QueryBatch:
         """Executor-less fallback: the same union-and-visit-once
         schedule (``run_shared_scan``), run sequentially in-process
         over the corpus ref ``execute`` captured at entry."""
-        from repro.runtime.executor import run_shared_scan
+        from repro.runtime.executor import finish_group_scan, run_shared_scan
 
         def inline_mapper(corpus, shard_ids, fn):
-            return {sid: fn(corpus.shards[sid]) for sid in shard_ids}
+            task = getattr(fn, "per_shard", fn)
+            out = {sid: task(corpus.shards[sid]) for sid in shard_ids}
+            if hasattr(fn, "group"):
+                finish_group_scan(fn, out)
+            return out
 
         return run_shared_scan(inline_mapper, corpus, plan, fns)
 

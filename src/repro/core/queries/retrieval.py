@@ -28,7 +28,7 @@ from repro.core.sampling import (
     similarity_probabilities,
     unique_shards,
 )
-from repro.data.store import DocShard, ShardedCorpus
+from repro.data.store import CorpusPostings, DocShard, ShardedCorpus
 
 
 # ----------------------------------------------------------------------
@@ -232,18 +232,67 @@ def bm25_scores_for_shard(
     from repro.data.store import shard_postings
     lens = np.diff(shard.offsets).astype(np.float64)
     scores = np.zeros(shard.n_docs, np.float64)
-    norm = k1 * (1.0 - b + b * lens / max(avg_doc_len, 1e-9))
     post = shard_postings(shard)
     for w in query_words:
         docs, tf = post.lookup(w)
         if docs.size == 0:
             continue
-        tf = tf.astype(np.float64)
-        df = float(doc_freq[w])
-        idf = np.log(1.0 + (n_docs - df + 0.5) / (df + 0.5))
-        scores[docs] += idf * tf * (k1 + 1.0) / np.maximum(
-            tf + norm[docs], 1e-9)
+        scores[docs] += bm25_term(tf, lens[docs], float(doc_freq[w]),
+                                  n_docs, avg_doc_len, k1, b)
     return scores
+
+
+def bm25_term(tf: np.ndarray, lens: np.ndarray, df: float, n_docs: int,
+              avg_doc_len: float, k1: float = 1.2,
+              b: float = 0.75) -> np.ndarray:
+    """One query word's BM25 contribution to the documents that hold it
+    ``tf`` times and are ``lens`` tokens long.  The one expression both
+    the per-shard and the corpus-wide scan add, so that their scores
+    agree bit for bit."""
+    tf = tf.astype(np.float64)
+    idf = np.log(1.0 + (n_docs - df + 0.5) / (df + 0.5))
+    norm = k1 * (1.0 - b + b * lens / max(avg_doc_len, 1e-9))
+    return idf * tf * (k1 + 1.0) / np.maximum(tf + norm, 1e-9)
+
+
+def bm25_scores_over_table(
+    table: CorpusPostings,
+    drawn: np.ndarray,
+    query_words: Sequence[int],
+    doc_freq: np.ndarray,
+    n_docs: int,
+    avg_doc_len: float,
+) -> np.ndarray:
+    """``bm25_scores_for_shard`` for every shard with ``drawn[shard]``
+    set, in one pass over the corpus-wide postings ``table``: a dense
+    [table positions] score vector whose slice
+    ``shard_start[s]:shard_start[s + 1]`` equals that shard's scores bit
+    for bit (the same terms, added in the same word order)."""
+    scores = np.zeros(table.doc_len.shape[0], np.float64)
+    for w in query_words:
+        docs, tf = table.lookup(w)
+        keep = drawn[table.shard_of[docs]]
+        docs = docs[keep]
+        if docs.size == 0:
+            continue
+        scores[docs] += bm25_term(tf[keep], table.doc_len[docs],
+                                  float(doc_freq[w]), n_docs, avg_doc_len)
+    return scores
+
+
+def match_over_table(expr: BoolExpr, table: CorpusPostings,
+                     drawn: np.ndarray) -> np.ndarray:
+    """The ascending table positions, in shards with ``drawn[shard]``
+    set, of the documents satisfying ``expr``: ``_expr_eval_docs`` for
+    all those shards at once, AND as a sorted intersection, OR as a
+    sorted union."""
+    if expr.op == "word":
+        docs, _ = table.lookup(expr.word)
+        return docs[drawn[table.shard_of[docs]]]
+    l = match_over_table(expr.left, table, drawn)
+    r = match_over_table(expr.right, table, drawn)
+    return (np.intersect1d(l, r, assume_unique=True) if expr.op == "and"
+            else np.union1d(l, r))
 
 
 def bm25_scores_for_shard_scan(

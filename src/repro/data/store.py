@@ -20,6 +20,11 @@ couple of queries touching the shard; the flat-scan implementations are
 kept (``*_scan``) as parity references and for one-shot scans where
 building the cache would be wasted work.
 
+Corpus-wide postings (``corpus_postings``): the same pairs laid out
+word-major over the whole corpus, built once from the shard postings
+and cached on the corpus, so a word-driven query reads each of its
+words once for all the shards it drew (``core/queries/batch.WordScan``).
+
 Persistence: ``ShardedCorpus.save``/``load`` round-trip the per-shard
 CSR payload *and* the postings next to it, so a cold serving process
 opens the corpus with every shard's inverted index already attached —
@@ -115,6 +120,8 @@ class ShardedCorpus:
         self.shards = shards
         self.vocab_size = int(vocab_size)
         self._doc_to_shard = None
+        # the corpus-wide word-major postings (``corpus_postings``)
+        self._postings_table: "CorpusPostings | None" = None
 
     # ------------------------------------------------------------------
     # construction
@@ -178,7 +185,12 @@ class ShardedCorpus:
         within every word row, so the merged postings are bit-for-bit
         what a from-scratch ``build_postings`` of the grown shard
         produces (pinned by tests) at the cost of indexing only the
-        delta."""
+        delta.
+
+        The corpus-wide postings table (``corpus_postings``) carries
+        over unchanged: it still indexes the shard objects the two
+        corpora share, and the grown and spilled shards, which it never
+        saw, answer through their per-shard postings."""
         if not len(docs_tokens):
             return self, np.zeros(0, np.int64), []
         base = self.n_docs
@@ -210,7 +222,9 @@ class ShardedCorpus:
             shards.append(DocShard.from_documents(sid, group))
             affected.append(sid)
         new_ids = np.arange(base, base + len(docs), dtype=np.int64)
-        return ShardedCorpus(shards, self.vocab_size), new_ids, affected
+        grown = ShardedCorpus(shards, self.vocab_size)
+        grown._postings_table = getattr(self, "_postings_table", None)
+        return grown, new_ids, affected
 
     # ------------------------------------------------------------------
     # inspection
@@ -449,6 +463,99 @@ def shard_postings(shard: DocShard) -> ShardPostings:
         post = build_postings(shard)
         shard._postings = post
     return post
+
+
+# ----------------------------------------------------------------------
+# corpus-wide word-major postings (built once, cached on the corpus)
+# ----------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True, eq=False)
+class CorpusPostings:
+    """The shard postings of a whole corpus, laid out word-major, so
+    one word-driven query reads each of its words' postings once for
+    every shard it drew instead of once per shard.
+
+    Documents take a global *position*: shard by shard in shard order,
+    then in the shard's local order, so shard ``s`` holds positions
+    ``shard_start[s]:shard_start[s + 1]`` and a position less
+    ``shard_start[s]`` is its local index.  Row ``w`` of
+    ``indptr`` / ``pos`` / ``tf`` holds word ``w``'s (position, term
+    frequency) pairs in ascending position, that is in shard order,
+    then document order.  The table holds nothing the shard postings do
+    not: it is valid for exactly the shard objects in ``shards``
+    (``covers``); a shard grown or added later is not in it."""
+    shards: tuple             # the DocShard objects indexed, by shard id
+    indptr: np.ndarray        # int64 [vocab + 1]
+    pos: np.ndarray           # int32 (int64 past 2**31 docs) [nnz]
+    tf: np.ndarray            # int32 [nnz]
+    shard_start: np.ndarray   # int64 [n_shards + 1]
+    shard_of: np.ndarray      # int32 [n_docs] position -> shard id
+    doc_len: np.ndarray       # float64 [n_docs] tokens in the document
+    doc_ids: np.ndarray       # int64 [n_docs] position -> global doc id
+
+    def covers(self, shard_id: int, shard: DocShard) -> bool:
+        """Whether the table indexed this very shard object."""
+        return (0 <= shard_id < len(self.shards)
+                and self.shards[shard_id] is shard)
+
+    def lookup(self, word: int) -> "tuple[np.ndarray, np.ndarray]":
+        """(positions, term frequencies) of ``word`` over the corpus."""
+        w = int(word)
+        if w < 0 or w >= self.indptr.shape[0] - 1:
+            return self.pos[:0], self.tf[:0]
+        lo, hi = int(self.indptr[w]), int(self.indptr[w + 1])
+        return self.pos[lo:hi], self.tf[lo:hi]
+
+
+def build_corpus_postings(corpus: ShardedCorpus) -> CorpusPostings:
+    """Lay the shards' postings out word-major: a counting sort by word
+    that scatters each shard's rows, in shard order, behind the rows of
+    the shards before it."""
+    shards = tuple(corpus.shards)
+    posts = [shard_postings(s) for s in shards]
+    counts = np.asarray([s.n_docs for s in shards], np.int64)
+    shard_start = np.zeros(len(shards) + 1, np.int64)
+    np.cumsum(counts, out=shard_start[1:])
+    n_pos = int(shard_start[-1])
+    vocab = max((p.indptr.shape[0] - 1 for p in posts), default=0)
+    rows = np.zeros(vocab, np.int64)
+    for p in posts:
+        rows[: p.indptr.shape[0] - 1] += np.diff(p.indptr)
+    indptr = np.zeros(vocab + 1, np.int64)
+    np.cumsum(rows, out=indptr[1:])
+    pos = np.empty(int(indptr[-1]),
+                   np.int32 if n_pos < 2**31 else np.int64)
+    tf = np.empty(int(indptr[-1]), np.int32)
+    fill = indptr[:-1].copy()   # next free slot of every word row
+    for start, p in zip(shard_start[:-1], posts):
+        nnz = p.doc_idx.shape[0]
+        if not nnz:
+            continue
+        c = np.diff(p.indptr)
+        words = np.repeat(np.arange(c.shape[0]), c)
+        dest = fill[words] + (np.arange(nnz) - p.indptr[words])
+        pos[dest] = p.doc_idx + start
+        tf[dest] = p.tf
+        fill[: c.shape[0]] += c
+    if shards:
+        doc_len = np.concatenate([np.diff(s.offsets) for s in shards])
+        doc_ids = np.concatenate([s.doc_ids for s in shards])
+    else:
+        doc_len = doc_ids = np.zeros(0, np.int64)
+    return CorpusPostings(
+        shards, indptr, pos, tf, shard_start,
+        np.repeat(np.arange(len(shards), dtype=np.int32), counts),
+        doc_len.astype(np.float64), doc_ids.astype(np.int64))
+
+
+def corpus_postings(corpus: ShardedCorpus) -> CorpusPostings:
+    """The corpus's word-major postings, built on first call and cached
+    on the corpus object, as ``shard_postings`` caches on the shard.  A
+    corpus from ``append_documents`` inherits its source's table."""
+    table = getattr(corpus, "_postings_table", None)
+    if table is None:
+        table = build_corpus_postings(corpus)
+        corpus._postings_table = table
+    return table
 
 
 def merge_postings(old: ShardPostings, old_n_docs: int,

@@ -50,7 +50,7 @@ the single-executor results.
 single-host pool and a placement-split host group expose the same
 ``map_shard_batch`` surface.
 
-The shared scan has two dispatch shapes.  Per-shard (the default for
+The shared scan has three dispatch shapes.  Per-shard (the default for
 arbitrary fns): ``run_shared_scan`` builds one composite task per
 shard in the union plan and the executor schedules them across its
 pool — retry, speculation, and chaos injection all at shard-task
@@ -69,7 +69,14 @@ granularity (hooks fire for every shard in the group before the
 launch) while failure/retry is at-least-once at group width;
 ``megakernel=False`` on ``map_shard_batch`` pins the per-shard fused
 path — the parity reference the serving bench's ``megascan`` record
-hard-gates against.
+hard-gates against.  Host-side group scan (the ranked and Boolean
+queries of a ``QueryBatch``): their fns carry the batch's
+``core.queries.batch.WordScan``, and the executor runs the shard
+group's per-shard tasks first — with the per-shard retry, loss and
+hooks of the first shape, and only for what the scan leaves, such as
+phrase counts — then one vectorised pass over the corpus-wide postings
+for the shards that completed, on the calling thread, one per host on
+a ``HostGroupExecutor``.
 
 With a cache attached the serving dataflow per query is cache ->
 window -> executor: the engine probes the ``SemanticQueryCache``

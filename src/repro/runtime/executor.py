@@ -144,7 +144,15 @@ def run_shared_scan(
     bit-for-bit identical across routes.  ``megakernel=True`` asserts
     the fns are fusable (raises otherwise); ``None`` auto-detects;
     ``False`` pins the per-shard fused path (the parity reference and
-    the fallback when grouping is disabled)."""
+    the fallback when grouping is disabled).
+
+    Otherwise, when some fns carry a host-side group scan (``fn.group``:
+    ``core/queries/batch.WordScan`` on a batch's ranked and Boolean
+    queries), the composite is tagged for the executor's group route
+    (``_tag_group_scan``): the shards' per-shard tasks run what the
+    scan does not answer, then one ``run_group`` call answers the rest
+    (``finish_group_scan``).  A mapper that knows no group route calls
+    the composite per shard, which runs every fn as before."""
     if len(plan) != len(fns):
         raise ValueError(f"plan/fns length mismatch: "
                          f"{len(plan)} != {len(fns)}")
@@ -171,6 +179,7 @@ def run_shared_scan(
         def shared_scan(shard):
             return {qi: fns[qi](shard)
                     for qi in queries_of[shard.shard_id]}
+        _tag_group_scan(shared_scan, fns, queries_of)
 
     by_shard = mapper(corpus, sorted(queries_of), shared_scan)
     out: list = [{} for _ in plan]
@@ -179,6 +188,56 @@ def run_shared_scan(
             for qi, res in per_query.items():
                 out[qi][sid] = res
     return out
+
+
+def _tag_group_scan(shared_scan, fns, queries_of) -> None:
+    """Tag the composite for the group route when some fns carry a
+    group scan (``fn.group``, one object with ``covers(shard_id)`` and
+    ``run_group(shard_ids, queries_of)``): ``shared_scan.group`` is the
+    scan, ``.queries_of`` the (shard, query) pairs it answers, the
+    covered shards' tagged queries, and ``.per_shard`` the composite
+    task of what is left.  Untagged fns and uncovered shards keep their
+    per-shard tasks."""
+    scan = next((f.group for f in fns if hasattr(f, "group")), None)
+    if scan is None:
+        return
+    tagged = {qi for qi, f in enumerate(fns)
+              if getattr(f, "group", None) is scan}
+    grouped: Dict[int, list] = {}
+    for sid, qis in queries_of.items():
+        mine = (qis if len(tagged) == len(fns)
+                else [qi for qi in qis if qi in tagged])
+        if mine and scan.covers(sid):
+            grouped[sid] = mine
+    if not grouped:
+        return
+
+    def per_shard(shard):
+        skip = grouped.get(shard.shard_id, ())
+        return {qi: fns[qi](shard)
+                for qi in queries_of[shard.shard_id] if qi not in skip}
+
+    shared_scan.group = scan
+    shared_scan.queries_of = grouped
+    shared_scan.per_shard = per_shard
+    shared_scan.hooks_only = (
+        frozenset(grouped) if len(tagged) == len(fns)
+        else frozenset(sid for sid, qis in grouped.items()
+                       if len(qis) == len(queries_of[sid])))
+
+
+def finish_group_scan(fn, results: Dict[int, Any]) -> None:
+    """The group pass of a group-tagged composite ``fn`` (see
+    ``run_shared_scan``), once its per-shard tasks have run: the scan
+    answers its pairs on the shards in ``results`` in one
+    ``run_group`` call, merged into their per-shard dicts.  A shard
+    missing from ``results`` (lost under ``allow_partial``) is left
+    out."""
+    grouped = fn.queries_of
+    done = [sid for sid in results if sid in grouped]
+    if done:
+        for sid, res in fn.group.run_group(done, grouped).items():
+            results[sid].update(res)
 
 
 class ShardTaskExecutor:
@@ -338,10 +397,14 @@ class ShardTaskExecutor:
         A ``MegascanSpec``-tagged composite (see ``run_shared_scan``)
         short-circuits the per-shard task fan-out entirely: the whole
         group runs as ONE Pallas launch (``_run_group_scan``) when this
-        executor was built with ``megakernel=True``.
+        executor was built with ``megakernel=True``.  A composite tagged
+        with a host-side group scan runs its per-shard tasks as above
+        and then the scan's one pass (``_run_group_scan`` too).
         """
         spec = getattr(fn, "megascan", None)
-        if spec is not None and self.megakernel:
+        if spec is None or not self.megakernel:
+            spec = getattr(fn, "group", None)
+        if spec is not None:
             with self._job_lock:
                 return self._run_group_scan(corpus, shard_ids, fn, spec)
         # jobs are serialized: the epoch guard on the shared
@@ -354,13 +417,20 @@ class ShardTaskExecutor:
         corpus,
         shard_ids: Sequence[int],
         fn: Callable[[Any], Any],
+        hooks_only: frozenset = frozenset(),
     ) -> Dict[int, Any]:
         """One per-shard job: the sweep on the calling thread first
         (``gil_floor_s > 0`` and no ``job_deadline_s``), then the pooled
         loop for whatever the sweep handed off.  ``gil_floor_s=0.0``
         runs the pooled loop alone, and so does a job with a deadline:
         only the pool can abandon a stalled task when the deadline
-        expires."""
+        expires.
+
+        ``hooks_only``: shards whose task has nothing to run (a group
+        scan answers all their queries afterwards).  Where the job
+        sweeps, they fire their hooks, under the same retry contract,
+        and take ``{}`` without a task call or a timing; elsewhere they
+        run ``fn`` like any other shard."""
         ids = [int(s) for s in shard_ids]
         t_job = time.perf_counter()
         deadline = (t_job + self.job_deadline_s
@@ -375,7 +445,15 @@ class ShardTaskExecutor:
         rest, timed_out = ids, False
         sweeping = self.gil_floor_s > 0.0 and deadline is None
         if sweeping:
-            rest = self._sweep(corpus, ids, fn, job, results, attempts,
+            if hooks_only:
+                hooked = (self.fault_hook is not None
+                          or self.task_hook is not None)
+                for sid in ids:
+                    if sid in hooks_only and (not hooked or self._attempt(
+                            corpus, sid, None, job, attempts)[0]):
+                        results[sid] = {}
+                rest = [s for s in ids if s not in hooks_only]
+            rest = self._sweep(corpus, rest, fn, job, results, attempts,
                                durations)
         swept = sweeping and not rest
         if rest:
@@ -434,42 +512,54 @@ class ShardTaskExecutor:
         check_at = 1
         median = 0.0
         for n, sid in enumerate(ids):
-            while True:
-                attempts[sid] += 1
-                attempt = attempts[sid]
-                t_run = time.perf_counter()
-                try:
-                    if self.fault_hook is not None:
-                        self.fault_hook(sid, attempt)
-                    if self.task_hook is not None:
-                        self.task_hook(sid, attempt, job)
-                    results[sid] = fn(corpus.shards[sid])
-                except Exception as exc:
-                    _log.warning("shard %d task failed (attempt %d): %s: %s",
-                                 sid, attempt, type(exc).__name__, exc)
-                    if attempt <= self.max_retries:
-                        self.stats["retries"] += 1
-                        time.sleep(min(
-                            self.retry_backoff_cap_s,
-                            self.retry_backoff_s * 2.0 ** (attempt - 1)))
-                        continue
-                    if not self.allow_partial:
-                        raise ShardTaskError(
-                            f"shard {sid} failed after {attempt} "
-                            f"attempts") from exc
-                    break
-                took = time.perf_counter() - t_run
-                durations.append(took)
-                if len(durations) >= check_at:
-                    check_at *= 2
-                    median = float(np.median(durations))
-                    if median >= self.gil_floor_s:
-                        return ids[n + 1:]
-                if took > self.straggler_factor * max(median,
-                                                      self.min_straggler_s):
+            done, res, took = self._attempt(corpus, sid, fn, job, attempts)
+            if not done:
+                continue
+            results[sid] = res
+            durations.append(took)
+            if len(durations) >= check_at:
+                check_at *= 2
+                median = float(np.median(durations))
+                if median >= self.gil_floor_s:
                     return ids[n + 1:]
-                break
+            if took > self.straggler_factor * max(median,
+                                                  self.min_straggler_s):
+                return ids[n + 1:]
         return []
+
+    def _attempt(self, corpus, sid: int, fn, job: int,
+                 attempts: Dict[int, int]) -> "tuple[bool, Any, float]":
+        """Shard ``sid``'s task on the calling thread, attempted until
+        it succeeds: both hooks on every attempt, up to ``max_retries``
+        retries with the capped backoff, and a permanent failure raising
+        ``ShardTaskError`` or, with ``allow_partial``, giving up.
+        ``fn=None`` runs the hooks alone.  Returns (succeeded, result,
+        seconds of the attempt that succeeded)."""
+        while True:
+            attempts[sid] += 1
+            attempt = attempts[sid]
+            t_run = time.perf_counter()
+            try:
+                if self.fault_hook is not None:
+                    self.fault_hook(sid, attempt)
+                if self.task_hook is not None:
+                    self.task_hook(sid, attempt, job)
+                res = fn(corpus.shards[sid]) if fn is not None else None
+            except Exception as exc:
+                _log.warning("shard %d task failed (attempt %d): %s: %s",
+                             sid, attempt, type(exc).__name__, exc)
+                if attempt <= self.max_retries:
+                    self.stats["retries"] += 1
+                    time.sleep(min(
+                        self.retry_backoff_cap_s,
+                        self.retry_backoff_s * 2.0 ** (attempt - 1)))
+                    continue
+                if not self.allow_partial:
+                    raise ShardTaskError(
+                        f"shard {sid} failed after {attempt} "
+                        f"attempts") from exc
+                return False, None, 0.0
+            return True, res, time.perf_counter() - t_run
 
     def _run_pooled(
         self,
@@ -641,17 +731,36 @@ class ShardTaskExecutor:
 
     def _run_group_scan(self, corpus, shard_ids: Sequence[int], fn,
                         spec) -> Dict[int, Any]:
-        """One-launch megakernel route: the whole shard group is a
-        single composite task (``spec.run_group`` — one Pallas launch
-        over the packed multi-shard payload) instead of one task per
-        shard.  The fault seams keep their per-shard granularity — the
-        ``fault_hook``/``task_hook`` pair fires for every shard in the
-        group before the launch, so chaos scripts targeting individual
-        shards still bite — but failure/retry is at-least-once at
-        *group* granularity: any hook raise or launch failure re-runs
-        the whole group (with the same bounded-exponential backoff),
-        which is exactly the composite-task semantics ``map_shard_batch``
-        already documents, at width = whole group."""
+        """The group route: ``spec.run_group`` answers the composite's
+        grouped (query, shard) pairs for the whole shard group in one
+        call instead of one task per shard.  Two fault contracts:
+
+        * a host-side group scan (the composite has ``per_shard``, see
+          ``run_shared_scan``) keeps today's per-shard one exactly.
+          The job first runs ``per_shard`` — the tasks of the queries
+          left outside the group, often none — as an ordinary per-shard
+          job (``_run_job``: the sweep, the hooks on every shard and
+          attempt, a failing shard retried alone with its backoff, and
+          under ``allow_partial`` lost alone), then one group pass over
+          the shards that completed, on the calling thread;
+          ``last_job`` is that job's, its wall time taken to the end of
+          the pass.
+        * the one-launch megakernel (``MegascanSpec``): the hook pair
+          fires for every shard in the group before the launch, so chaos
+          scripts targeting individual shards still bite, but
+          failure/retry is at-least-once at *group* granularity: any
+          hook raise or launch failure re-runs the whole group (with the
+          same bounded-exponential backoff), which is exactly the
+          composite-task semantics ``map_shard_batch`` already
+          documents, at width = whole group."""
+        per_shard = getattr(fn, "per_shard", None)
+        if per_shard is not None:
+            t_job = time.perf_counter()
+            results = self._run_job(corpus, shard_ids, per_shard,
+                                    fn.hooks_only)
+            finish_group_scan(fn, results)
+            self.last_job["wall_s"] = time.perf_counter() - t_job
+            return results
         ids = [int(s) for s in shard_ids]
         t_job = time.perf_counter()
         deadline = (t_job + self.job_deadline_s

@@ -2,9 +2,9 @@
 sampling rate 0.25) driven through a whole benchmark run at a size the
 CPU holds: the sound run is ``correct`` with every Boolean and ranked
 answer equal to the reference's over the same draws, and a fault
-planted in a shard task (one Boolean hit dropped, one BM25 score off
-by one ulp) makes the run incorrect.  The look for a chip is
-skipped."""
+planted in the group scan's per-shard results (one Boolean hit
+dropped, one BM25 score off by one ulp) makes the run incorrect.  The
+look for a chip is skipped."""
 import json
 import os
 import sys
@@ -50,20 +50,29 @@ def _run(hook, monkeypatch):
 
 
 def _in_shard_tasks(kind, alter):
-    """A hook: in every batch, each ``kind`` query's shard tasks go
-    through ``alter(result, state)``, where ``state`` is the query's own
-    dict for the batch."""
+    """A hook: in every batch, each ``kind`` query's per-shard results
+    from the group scan go through ``alter(result, state)``, in shard
+    order, where ``state`` is the query's own dict for the batch."""
     def hook(stack):
         engine = stack.engine
-        orig = type(engine)._shard_fn
+        orig = type(engine)._word_scan
 
-        def shard_fn(q, *a):
-            fn = orig(q, *a)
-            if q.kind != kind:
-                return fn
-            state = {}
-            return lambda shard: alter(fn(shard), state)
-        engine._shard_fn = shard_fn
+        def word_scan(*a):
+            scan = orig(*a)
+            run = scan.run_group
+            states = {}
+
+            def run_group(shard_ids, queries_of):
+                out = run(shard_ids, queries_of)
+                for sid in sorted(out):
+                    for qi, res in out[sid].items():
+                        if scan.queries[qi].kind == kind:
+                            out[sid][qi] = alter(
+                                res, states.setdefault(qi, {}))
+                return out
+            scan.run_group = run_group
+            return scan
+        engine._word_scan = word_scan
     return hook
 
 

@@ -17,12 +17,14 @@ from bench.trace import Trace  # noqa: E402
 
 STATS = dict(batches=20, served=50, plan_words_us=60_000,
              reduce_topk_us=140_000, topk_candidates=1_500_000,
-             topk_queries=30, bool_words=180, bool_plans=12)
+             topk_queries=30, bool_words=180, bool_plans=12,
+             plan_shards=40_000, group_pairs=30_000)
 
 READERS = {  # metric -> (value on STATS, counter whose denominator is 0)
     "plan_words_ms.retrieval": (3.0, "batches"),
     "topk_ms.retrieval": (7.0, "batches"),
     "topk_cands.retrieval": (50_000.0, "topk_queries"),
+    "group_share.retrieval": (0.75, "plan_shards"),
 }
 SIZES = dict(n_docs=200_000, n_shards=1939, bits=256, dim=100)
 

@@ -95,7 +95,8 @@ def test_report_holds_six_stages_within_elapsed(small_corpus, built_index):
     assert set(rec["counters"]) == {"shard_visits", "plan_shards",
                                     "scan_cpu_us", "scan_sweep",
                                     "bool_words", "bool_plans",
-                                    "topk_candidates", "topk_queries"}
+                                    "topk_candidates", "topk_queries",
+                                    "group_pairs"}
 
 
 def test_mixed_batch_counts_retrieval_work(small_corpus, built_index):
