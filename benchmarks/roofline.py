@@ -15,16 +15,6 @@ per-device program; probe totals reconstruct while-loop trip counts), so
 
 MODEL_FLOPS uses 6*N*D for training (N = params, dense; N_active for
 MoE) and 2*N*D for single-token decode / prefill forward passes.
-
-Megascan records (``kind: "megascan"``, emitted by the serving bench's
-one-launch scan arm) get their own model: the question there is not
-FLOP efficiency but *dispatch share* — what fraction of the scan path
-is per-launch overhead vs streaming/compute.  A per-shard launch
-sequence pays ``launches * DISPATCH_S``; the megascan pays it once and
-overlaps the HBM->VMEM block copies with MXU scoring (double-buffered
-prefetch), so its modeled time is ``DISPATCH_S + max(memory, compute)``
-and the dispatch-bound -> bandwidth-bound claim is the rendered
-``dominant`` column flipping, not prose.
 """
 from __future__ import annotations
 
@@ -55,13 +45,6 @@ def chip_peaks(device_kind: str) -> Dict:
             f"them to benchmarks.roofline.PEAKS with their source") from None
 
 
-# Per-launch dispatch overhead model: host-side Pallas/XLA launch plus
-# the HBM<->VMEM turnaround a fresh kernel pays before its pipeline
-# fills.  ~8 us is the conventional small-kernel launch cost on current
-# TPU runtimes; the absolute value only scales the dispatch column —
-# the per-shard vs megascan *comparison* divides it out.
-DISPATCH_S = 8e-6
-
 SHAPE_TOKENS = {
     "train_4k": 4096 * 256,
     "prefill_32k": 32768 * 32,
@@ -77,48 +60,7 @@ def model_flops(rec: Dict) -> float:
     return mult * n * tokens
 
 
-def analyze_megascan(rec: Dict) -> Dict:
-    """Roofline row for a megascan record (see kernels/megascan): the
-    three terms are dispatch (launches * DISPATCH_S), memory (payload
-    bytes streamed through VMEM once per launch set) and compute
-    (scoring + one-hot reduction flops).  With double-buffered prefetch
-    memory and compute overlap, so the modeled wall is
-    ``dispatch + max(memory, compute)`` and ``overlap_ratio`` says how
-    much of the smaller stream the prefetch hides.  Peaks are those of
-    the record's ``device_kind``."""
-    peaks = chip_peaks(rec["device_kind"])
-    launches = int(rec.get("launches", 1))
-    t_dispatch = launches * DISPATCH_S
-    t_memory = float(rec.get("bytes_streamed", 0)) / peaks["hbm_bw"]
-    t_compute = float(rec.get("flops", 0)) / peaks["flops"]
-    terms = {"dispatch": t_dispatch, "memory": t_memory,
-             "compute": t_compute}
-    dominant = max(terms, key=terms.get)
-    stream = max(t_memory, t_compute)
-    overlap = (min(t_memory, t_compute) / stream) if stream else 0.0
-    modeled = t_dispatch + stream
-    dispatch_share = t_dispatch / modeled if modeled else 0.0
-    return {
-        "kind": "megascan",
-        "name": rec.get("name", f"megascan_x{launches}"),
-        "launches": launches,
-        "shards": int(rec.get("shards", 0)),
-        "shards_per_launch": (rec.get("shards", 0) / launches
-                              if launches else 0.0),
-        "dispatch_s": t_dispatch, "memory_s": t_memory,
-        "compute_s": t_compute, "dominant": dominant,
-        "overlap_ratio": overlap, "modeled_s": modeled,
-        "dispatch_share": dispatch_share,
-        "bytes_streamed": int(rec.get("bytes_streamed", 0)),
-        "measured_wall_s": rec.get("measured_wall_s"),
-        "double_buffer": bool(rec.get("double_buffer", False)),
-        "device_kind": rec["device_kind"],
-    }
-
-
 def analyze_record(rec: Dict) -> Optional[Dict]:
-    if rec.get("kind") == "megascan":
-        return analyze_megascan(rec)
     if rec.get("status") != "ok":
         return None
     probe = rec.get("probe")
@@ -156,23 +98,6 @@ def analyze_record(rec: Dict) -> Optional[Dict]:
 
 
 def suggestion(row: Dict) -> str:
-    if row.get("kind") == "megascan":
-        d = row["dominant"]
-        if d == "dispatch":
-            return ("dispatch-bound: fuse more shards per launch "
-                    "(megakernel route) — per-launch overhead dwarfs "
-                    "the streamed payload")
-        if d == "memory":
-            if row["overlap_ratio"] < 0.5:
-                return ("bandwidth-bound with idle MXU: raise bits or "
-                        "batch more queries per launch to fill the "
-                        "prefetch window")
-            return ("bandwidth-bound and overlapped: the scan streams "
-                    "at HBM speed — only narrower signatures (fewer "
-                    "bits) or more chips help")
-        return ("compute-bound: the one-hot reduction dominates — "
-                "shrink the lane-padded slot axis or lower scoring "
-                "precision")
     d = row["dominant"]
     if d == "compute":
         if row["useful_ratio"] < 0.5:
@@ -223,7 +148,7 @@ def run(out_dir: Optional[str] = None, verbose: bool = True):
     rows = load_rows(out_dir)
     if verbose:
         for r in rows:
-            if r.get("kind") == "megascan" or r["mesh"] != "single":
+            if r["mesh"] != "single":
                 continue
             print(f"roofline_{r['arch']}_{r['shape']},0.0,"
                   f"compute_s={r['compute_s']:.3e};"
@@ -240,7 +165,7 @@ def markdown_table(rows: List[Dict]) -> str:
              "dominant | MODEL/HLO | roofline frac | next lever |",
              "|---|---|---|---|---|---|---|---|---|"]
     for r in rows:
-        if r.get("kind") == "megascan" or r["mesh"] != "single":
+        if r["mesh"] != "single":
             continue
         lines.append(
             f"| {r['arch']} | {r['shape']} | {r['compute_s']:.2e} | "
@@ -250,57 +175,15 @@ def markdown_table(rows: List[Dict]) -> str:
     return "\n".join(lines)
 
 
-def megascan_table(rows: List[Dict]) -> str:
-    """The scan-path roofline: one row per megascan record, the
-    dispatch-share column carrying the dispatch-bound vs
-    bandwidth-bound claim."""
-    lines = ["| scan | launches | shards/launch | dispatch s | "
-             "memory s | compute s | dominant | dispatch share | "
-             "overlap | measured s | next lever |",
-             "|---|---|---|---|---|---|---|---|---|---|---|"]
-    for r in rows:
-        if r.get("kind") != "megascan":
-            continue
-        meas = (f"{r['measured_wall_s']:.2e}"
-                if r.get("measured_wall_s") is not None else "-")
-        lines.append(
-            f"| {r['name']} | {r['launches']} | "
-            f"{r['shards_per_launch']:.1f} | {r['dispatch_s']:.2e} | "
-            f"{r['memory_s']:.2e} | {r['compute_s']:.2e} | "
-            f"{r['dominant']} | {r['dispatch_share']:.2f} | "
-            f"{r['overlap_ratio']:.2f} | {meas} | {suggestion(r)} |")
-    return "\n".join(lines)
-
-
-def serve_megascan_rows(serve_json: str) -> List[Dict]:
-    """Analyzed megascan rows from a serve-bench report JSON (the
-    ``megascan`` record's ``roofline_records`` list)."""
-    with open(serve_json) as f:
-        report = json.load(f)
-    recs = (report.get("megascan") or {}).get("roofline_records", [])
-    return [analyze_megascan(r) for r in recs]
-
-
 if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--dir", default=None,
                     help="dry-run artifact dir (default: latest sweep)")
-    ap.add_argument("--serve", default=None,
-                    help="serve-bench JSON: render its megascan records"
-                         " instead of the dry-run artifacts")
     ap.add_argument("--out", default=None,
-                    help="write the markdown table(s) to this path")
+                    help="write the markdown table to this path")
     args = ap.parse_args()
-    if args.serve:
-        rows = serve_megascan_rows(args.serve)
-        table = megascan_table(rows)
-    else:
-        rows = run(args.dir)
-        table = markdown_table(rows)
-        mega = megascan_table(rows)
-        if mega.count("\n") > 1:
-            table = table + "\n\n" + mega
+    table = markdown_table(run(args.dir))
     if args.out:
         with open(args.out, "w") as f:
             f.write(table + "\n")

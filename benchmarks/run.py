@@ -21,13 +21,9 @@ def main() -> None:
     from repro.utils.compile_cache import enable_compile_cache
     enable_compile_cache()
     from benchmarks import (aggregation_bench, dir_bench, index_bench,
-                            recsys_bench, roofline, sensitivity_bench,
-                            serve_bench)
+                            recsys_bench, roofline, sensitivity_bench)
 
     suites = [
-        ("serve_batched_engine",
-         lambda: serve_bench.run(n_queries=48 if args.fast else 96,
-                                 trials=2 if args.fast else 3)),
         ("fig3_fig4_aggregation",
          lambda: aggregation_bench.run(n_queries=20 if args.fast else 60,
                                        trials=1 if args.fast else 2)),

@@ -174,7 +174,7 @@ def main():
         hosts=args.hosts if args.hosts >= 2 else 0,
         replicas=args.replicas, balanced=balanced,
         workers=args.workers, fault_hook=fault_hook,
-        host_fault_hook=host_hook, adaptive_workers=True,
+        host_fault_hook=host_hook,
         planner=budget_on, ci=budget_on, cache=args.cache,
         window=True, adaptive=not args.static,
         max_batch=args.batch, max_delay_s=args.window_ms / 1e3,
@@ -365,7 +365,8 @@ def main():
               f"{executor.stats['requeued_shards']})")
         print(f"   per-host scans: {executor.stats['scans_per_host']} "
               f"over {executor.stats['jobs']} gather jobs")
-        audit = engine.last_audit
+        report = engine.last_report
+        audit = report.balance if report is not None else None
         if audit is not None:
             print(f"   balance: split {audit['group_sizes']} vs residency "
                   f"{audit['base_group_sizes']} "

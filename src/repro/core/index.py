@@ -44,7 +44,6 @@ import json
 import os
 from typing import Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 

@@ -26,8 +26,7 @@ workload should amortize:
      executed plan is kept on ``last_report.plan`` so callers can audit
      the residency split, and a balanced host group's split decision —
      estimated vs realized per-host makespan, shed count — lands on
-     ``last_report.balance``; the pre-report ``last_plan`` /
-     ``last_audit`` names survive as deprecated read-only properties).
+     ``last_report.balance``).
   3. **Scan work** — per-shard operators walk the lazily-built CSR
      postings (``data/store.shard_postings``), so the second query to
      touch a shard pays O(matching tokens), not O(shard tokens).
@@ -134,10 +133,7 @@ WORK_COUNTERS = ("bool_words", "bool_plans", "topk_candidates",
 class ExecutionReport:
     """Typed, JSON-clean record of one ``QueryBatch.execute`` call.
 
-    Replaces the old mutable ``last_plan`` / ``last_audit`` /
-    ``last_budget`` / ``last_degraded`` attribute grab-bag with one
-    report per batch on ``QueryBatch.last_report`` (the old names
-    survive as deprecated read-only properties reading through it).
+    One report per batch, on ``QueryBatch.last_report``.
 
     ``plan`` is the *executed* plan — one array of scanned shard ids
     per query.  A semantic-cache exact hit executed nothing, so its
@@ -401,38 +397,6 @@ class QueryBatch:
         return self.planner is not None
 
     # ------------------------------------------------------------------
-    # deprecated read-only views of last_report (pre-report callers)
-    # ------------------------------------------------------------------
-    @property
-    def last_plan(self) -> Optional[List[np.ndarray]]:
-        """Deprecated: read ``last_report.plan`` — the executed shard
-        plan (one array of scanned shard ids per query)."""
-        r = self.last_report
-        return list(r.plan) if r is not None else None
-
-    @property
-    def last_audit(self) -> Optional[Dict[str, Any]]:
-        """Deprecated: read ``last_report.balance`` — the balanced
-        host group's split audit, None otherwise."""
-        r = self.last_report
-        return r.balance if r is not None else None
-
-    @property
-    def last_budget(self) -> Optional[Dict[str, Any]]:
-        """Deprecated: read ``last_report.budget`` — the planner's
-        budget audit record, None without a planner."""
-        r = self.last_report
-        return r.budget if r is not None else None
-
-    @property
-    def last_degraded(self) -> Optional[Dict[str, Any]]:
-        """Deprecated: read ``last_report.degraded`` — the partial
-        gather record (lost shards, per-query breakdown), None on the
-        healthy path."""
-        r = self.last_report
-        return r.degraded if r is not None else None
-
-    # ------------------------------------------------------------------
     # planning: one batched scoring pass -> per-query probability rows
     # ------------------------------------------------------------------
     def _probability_rows(
@@ -466,8 +430,8 @@ class QueryBatch:
                 def algebra(e: BoolExpr) -> np.ndarray:
                     if e.op == "word":
                         return word_rows[e.word]
-                    l, r = algebra(e.left), algebra(e.right)
-                    return l * r if e.op == "and" else l + r
+                    lhs, rhs = algebra(e.left), algebra(e.right)
+                    return lhs * rhs if e.op == "and" else lhs + rhs
 
                 for i in bool_pos:
                     rows[i] = similarity_probabilities(
@@ -753,29 +717,16 @@ class QueryBatch:
 
         The *placement* axis comes from the executor's
         ``GenerationClock`` (every RCU placement swap — fleet
-        join/drain/crash, ingest shard growth — bumps it), falling
-        back to the deprecated ``stats["placement_epoch"]`` view for
-        clock-less executors; executors without placement (single
-        host, inline) are placement 0.  The *content* axis comes from
-        the index's clock (live ingest swaps and ``attach_corpus``
-        bump it) — this is what lets the cache see corpus changes that
-        leave placement untouched."""
+        join/drain/crash, ingest shard growth — bumps it); executors
+        without a clock (single host, inline) are placement 0.  The
+        *content* axis comes from the index's clock (live ingest swaps
+        and ``attach_corpus`` bump it) — this is what lets the cache
+        see corpus changes that leave placement untouched."""
         clock = getattr(self.executor, "clock", None)
-        placement = (clock.current().placement if clock is not None
-                     else self._cache_epoch())
+        placement = clock.current().placement if clock is not None else 0
         content = (self.index.clock.current().content
                    if self.index is not None else 0)
         return Generation(placement=placement, content=content)
-
-    def _cache_epoch(self) -> int:
-        """Deprecated: the raw placement int read off executor stats.
-        Kept as the fallback placement source for executors predating
-        ``GenerationClock`` — it cannot see content changes, which is
-        why ``_generation`` exists."""
-        stats = getattr(self.executor, "stats", None)
-        if isinstance(stats, dict):
-            return int(stats.get("placement_epoch", 0))
-        return 0
 
     def _feedback(self, queries: Sequence[BatchQuery],
                   rates: Sequence[float], results: Sequence[Any],

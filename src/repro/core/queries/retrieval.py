@@ -107,9 +107,9 @@ def _expr_shard_similarity(expr: BoolExpr, index: ApproxIndex) -> np.ndarray:
     algebra over per-word exp-similarities."""
     if expr.op == "word":
         return index.word_shard_similarity(expr.word)
-    l = _expr_shard_similarity(expr.left, index)
-    r = _expr_shard_similarity(expr.right, index)
-    return l * r if expr.op == "and" else l + r
+    lhs = _expr_shard_similarity(expr.left, index)
+    rhs = _expr_shard_similarity(expr.right, index)
+    return lhs * rhs if expr.op == "and" else lhs + rhs
 
 
 def _expr_eval_docs(expr: BoolExpr, shard: DocShard) -> np.ndarray:
@@ -123,9 +123,9 @@ def _expr_eval_docs(expr: BoolExpr, shard: DocShard) -> np.ndarray:
         mask = np.zeros(shard.n_docs, bool)
         mask[shard_postings(shard).lookup(expr.word)[0]] = True
         return mask
-    l = _expr_eval_docs(expr.left, shard)
-    r = _expr_eval_docs(expr.right, shard)
-    return (l & r) if expr.op == "and" else (l | r)
+    lhs = _expr_eval_docs(expr.left, shard)
+    rhs = _expr_eval_docs(expr.right, shard)
+    return (lhs & rhs) if expr.op == "and" else (lhs | rhs)
 
 
 def _expr_eval_docs_scan(expr: BoolExpr, shard: DocShard) -> np.ndarray:
@@ -135,9 +135,9 @@ def _expr_eval_docs_scan(expr: BoolExpr, shard: DocShard) -> np.ndarray:
         from repro.data.store import segment_sum_by_offsets
         hit = (shard.tokens == np.int32(expr.word)).astype(np.int64)
         return segment_sum_by_offsets(hit, shard.offsets) > 0
-    l = _expr_eval_docs_scan(expr.left, shard)
-    r = _expr_eval_docs_scan(expr.right, shard)
-    return (l & r) if expr.op == "and" else (l | r)
+    lhs = _expr_eval_docs_scan(expr.left, shard)
+    rhs = _expr_eval_docs_scan(expr.right, shard)
+    return (lhs & rhs) if expr.op == "and" else (lhs | rhs)
 
 
 class RetrievalResult(NamedTuple):
@@ -289,10 +289,10 @@ def match_over_table(expr: BoolExpr, table: CorpusPostings,
     if expr.op == "word":
         docs, _ = table.lookup(expr.word)
         return docs[drawn[table.shard_of[docs]]]
-    l = match_over_table(expr.left, table, drawn)
-    r = match_over_table(expr.right, table, drawn)
-    return (np.intersect1d(l, r, assume_unique=True) if expr.op == "and"
-            else np.union1d(l, r))
+    lhs = match_over_table(expr.left, table, drawn)
+    rhs = match_over_table(expr.right, table, drawn)
+    return (np.intersect1d(lhs, rhs, assume_unique=True)
+            if expr.op == "and" else np.union1d(lhs, rhs))
 
 
 def bm25_scores_for_shard_scan(

@@ -130,8 +130,9 @@ class Estimate(NamedTuple):
         return (self.value - self.error_bound, self.value + self.error_bound)
 
     def covers(self, truth: float) -> bool:
-        """Does the interval contain ``truth``?  (The smoke gate's
-        ground-truth coverage check for count queries.)"""
+        """Does the interval contain ``truth``?  (The ground-truth
+        coverage check for count queries: ``tests/test_batch_engine.py``
+        ``test_budgeted_count_intervals_cover_the_exact_count``.)"""
         lo, hi = self.interval
         return lo <= truth <= hi
 

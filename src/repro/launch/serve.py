@@ -14,12 +14,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.launch.mesh import make_host_mesh
-from repro.launch.steps import (
-    decode_state_shardings,
-    make_decode_step,
-    make_prefill_step,
-    params_shardings,
-)
+from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models import model as M
 
 

@@ -1,7 +1,7 @@
 """One-call construction of the serving stack (``build_serving_stack``).
 
-Every serving consumer — ``examples/serve_queries.py``,
-``benchmarks/serve_bench.py``, the tests — used to hand-assemble the
+Every serving consumer — ``examples/serve_queries.py``, the chip
+benchmark (``bench/deploy.py``), the tests — used to hand-assemble the
 same tower three different ways: placement + executor, budget planner,
 window controller, batching window, semantic cache, fleet manager,
 each with its own kwarg spelling.  ``ServeConfig`` names every knob
@@ -85,7 +85,7 @@ class ServeConfig:
       ``replicas``, ``balanced``, ``workers`` (total across hosts),
       ``allow_partial``, ``fault_hook`` (per-shard-task),
       ``host_fault_hook`` (per-host, host groups only),
-      ``adaptive_workers``, ``max_retries``.
+      ``max_retries``.
     * budget — ``planner`` attaches a ``RatePlanner``
       (``planner_config``) so queries may carry ``QueryBudget``s and
       the engine degrades under pressure.
@@ -124,7 +124,6 @@ class ServeConfig:
     allow_partial: bool = False
     fault_hook: Optional[Callable[[int, int], None]] = None
     host_fault_hook: Optional[Callable[[int, Any], None]] = None
-    adaptive_workers: bool = False
     max_retries: int = 2
     # budget
     planner: bool = False
@@ -405,14 +404,12 @@ def build_serving_stack(corpus, index, config: Optional[ServeConfig] = None,
             allow_partial=cfg.allow_partial,
             host_fault_hook=cfg.host_fault_hook,
             fault_hook=cfg.fault_hook,
-            adaptive_workers=cfg.adaptive_workers,
             max_retries=cfg.max_retries,
             clock=clock)
     else:
         executor = ShardTaskExecutor(
             workers=cfg.workers,
             fault_hook=cfg.fault_hook,
-            adaptive_workers=cfg.adaptive_workers,
             allow_partial=cfg.allow_partial,
             max_retries=cfg.max_retries)
 

@@ -176,10 +176,10 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
             mbs = jax.tree_util.tree_map(reshape, batch)
 
             def body(acc, mb):
-                l, g = jax.value_and_grad(M.loss_fn)(params, mb, cfg)
+                loss, g = jax.value_and_grad(M.loss_fn)(params, mb, cfg)
                 acc = jax.tree_util.tree_map(
                     lambda a, gg: a + gg.astype(a.dtype), acc, g)
-                return acc, l
+                return acc, loss
             zeros = jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, acc_dt), params)
             grads, losses = jax.lax.scan(body, zeros, mbs)

@@ -98,19 +98,19 @@ def chunked_attention(
     vc = v.reshape(b, n_chunks, chunk, kh, hd)
     scale = 1.0 / jnp.sqrt(hd)
 
-    def _pin(m, l, acc):
+    def _pin(m, denom, acc):
         # Pin the scan carry's sharding: unconstrained, GSPMD replicates
         # the fp32 accumulator (measured 21.5 GiB/device at qwen
         # prefill_32k).  Query-seq shards over "model" (context
         # parallelism) because kv-head counts rarely divide the axis.
         m = shard_constraint(m, "batch", "kv_heads", None, "attn_q_seq")
-        l = shard_constraint(l, "batch", "kv_heads", None, "attn_q_seq")
+        denom = shard_constraint(denom, "batch", "kv_heads", None, "attn_q_seq")
         acc = shard_constraint(acc, "batch", "kv_heads", None,
                                "attn_q_seq", None)
-        return m, l, acc
+        return m, denom, acc
 
     def body(carry, inputs):
-        m, l, acc = carry                      # running max / denom / numerator
+        m, denom, acc = carry                  # running max / denom / numerator
         kci, vci, ci = inputs
         scores = jnp.einsum("bskgd,btkd->bkgst", qg, kci) * scale
         scores = shard_constraint(scores, "batch", "kv_heads", None,
@@ -126,7 +126,7 @@ def chunked_attention(
         m_new = jnp.maximum(m, scores.max(axis=-1))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(scores - m_new[..., None])
-        l_new = l * alpha + p.sum(axis=-1)
+        l_new = denom * alpha + p.sum(axis=-1)
         acc_new = acc * alpha[..., None] + jnp.einsum("bkgst,btkd->bkgsd", p, vci)
         return _pin(m_new, l_new, acc_new), None
 
@@ -136,11 +136,11 @@ def chunked_attention(
     m0, l0, acc0 = _pin(m0, l0, acc0)
     # unroll=True flattens the chunk loop (used by the dry-run cost
     # probes: cost_analysis counts while bodies once)
-    (m, l, acc), _ = jax.lax.scan(
+    (m, denom, acc), _ = jax.lax.scan(
         body, (m0, l0, acc0),
         (kc.swapaxes(0, 1), vc.swapaxes(0, 1), jnp.arange(n_chunks)),
         unroll=n_chunks if unroll else 1)
-    out = acc / jnp.maximum(l[..., None], 1e-20)
+    out = acc / jnp.maximum(denom[..., None], 1e-20)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, hd).astype(q.dtype)
 
 

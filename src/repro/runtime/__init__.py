@@ -68,9 +68,9 @@ host per job: the residency split happens first, then each host's
 granularity (hooks fire for every shard in the group before the
 launch) while failure/retry is at-least-once at group width;
 ``megakernel=False`` on ``map_shard_batch`` pins the per-shard fused
-path — the parity reference the serving bench's ``megascan`` record
-hard-gates against.  Host-side group scan (the ranked and Boolean
-queries of a ``QueryBatch``): their fns carry the batch's
+path — the parity reference of ``tests/test_megascan.py``.
+Host-side group scan (the ranked and Boolean queries of a
+``QueryBatch``): their fns carry the batch's
 ``core.queries.batch.WordScan``, and the executor runs the shard
 group's per-shard tasks first — with the per-shard retry, loss and
 hooks of the first shape, and only for what the scan leaves, such as
@@ -102,10 +102,9 @@ decision must not replay as full fidelity.  Cookbook:
     stack.cache.record()                      # hit/near/miss counters
 
 (or hand-wire: ``QueryBatch(corpus, index, executor=...,
-cache=SemanticQueryCache(...))``).  The serving bench's ``--zipf`` arm
-hard-gates the contract: exact hits bit-for-bit equal to uncached
-execution, zero hits across scripted join/drain swaps, and a cached
-p50 strictly below the uncached one on the same skewed stream.
+cache=SemanticQueryCache(...))``).  ``tests/test_qcache.py`` holds
+the contract: exact hits bit-for-bit equal to uncached execution, and
+zero hits across join/drain/crash swaps.
 
 Under overload the controller drives *two actuators*, in order:
 
@@ -185,10 +184,7 @@ Cookbook:
     stack.generation                     # Generation(placement, content)
     stack.ingestor.record()              # steps/docs/swaps counters
 
-The deprecated integer views (``stats["placement_epoch"]``, raw-int
-qcache epochs) still read correctly — they are mirrors of the clock,
-pinned by tests — but new code should mint and compare only through
-``runtime.generation``.
+Mint and compare generations only through ``runtime.generation``.
 
 Every scenario above is testable without wall-clock races via
 ``chaos``: a ``FaultPlan`` is a seeded script compiled onto the
@@ -211,10 +207,10 @@ redraws and can deterministically recover.  The executor side holds up
 its end with bounded-exponential retry backoff, per-job deadlines
 (``job_deadline_s``), graceful partials (``allow_partial``), and a
 job-epoch guard that drops zombie completions from abandoned jobs.
-The serving bench's chaos arm replays kill -> degrade -> join ->
-recover -> drain against all of this and hard-gates zero lost queries,
-bit-for-bit gather parity, and post-join makespan recovery
-(``benchmarks/serve_bench.py --chaos``).
+``tests/test_chaos.py`` replays kill -> degrade -> join -> recover ->
+drain against all of this and checks zero lost queries and bit-for-bit
+gather parity; ``tests/test_fleet.py`` checks the warm-before-serve
+join and the clean drain.
 """
 from repro.runtime.balance import (  # noqa: F401
     BalanceConfig,

@@ -44,8 +44,8 @@ special case of balancing (one code path for both — see
 
 The audit trail (``BalanceAudit`` / ``last_job["balance"]``) keeps the
 estimated per-host costs, the base and chosen group sizes, and the
-estimated makespans of both splits, so the serving bench can compare
-estimate vs realized per-host wall time run over run.
+estimated makespans of both splits, so estimate and realized per-host
+wall time can be compared run over run.
 """
 from __future__ import annotations
 
@@ -170,8 +170,7 @@ class HostLoadModel:
 class BalanceAudit:
     """What the balancer decided and why — attached to
     ``HostGroupExecutor.last_job["balance"]`` for run-over-run audit
-    (the serving bench compares ``est_makespan_s`` against the realized
-    per-host walls)."""
+    (``est_makespan_s`` against the realized per-host walls)."""
 
     groups: Dict[int, List[int]]        # the chosen split
     base_groups: Dict[int, List[int]]   # the residency (primary) split

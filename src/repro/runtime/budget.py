@@ -38,8 +38,8 @@ controller's pressure ``d`` in [0, 1], each query's planned rate slides
 linearly from its plan (d=0) toward its floor (d=1), so overload
 degrades accuracy before it degrades availability.  The decision is
 recorded in a ``BudgetAudit`` (mirroring ``balance.BalanceAudit``) that
-lands on ``last_job["budget"]`` with planned-vs-realized error so the
-serving bench can check the planner's calibration run over run.
+lands on ``last_job["budget"]`` with planned-vs-realized error, so the
+planner's calibration can be checked run over run.
 
 Layering: this module sits beside ``controller`` (it *reads* the
 controller's models, never drives it) and below ``core.queries.batch``

@@ -1,7 +1,7 @@
 """Deterministic, seeded fault injection: the ``FaultPlan`` DSL.
 
-Fault lambdas used to be scattered one-off closures across tests and
-``serve_bench`` — each hand-rolling its own "fail host 1 once" state.
+Fault lambdas used to be scattered one-off closures across the tests
+and benchmarks — each hand-rolling its own "fail host 1 once" state.
 A ``FaultPlan`` is the declarative replacement: a scripted scenario
 
     plan = (FaultPlan(seed=7)
@@ -39,8 +39,7 @@ Hosts that join after ``install`` (FleetManager.join) are hooked
 automatically: the plan wraps ``ensure_host`` so a revived or new slot
 gets its per-host task hook before it can serve.
 
-``record()`` summarizes what actually fired (per-kind counters) for
-the bench's chaos audit.
+``record()`` summarizes what actually fired (per-kind counters).
 """
 from __future__ import annotations
 

@@ -25,12 +25,6 @@ tasks run on a worker pool with
     construction + teardown per batch), rebuilt only when the target
     worker count changes; ``close()`` (or the context manager) tears it
     down,
-  * adaptive worker count by task granularity
-    (``adaptive_workers=True``): tiny numpy tasks are GIL-bound — the
-    lock convoy makes 4+ workers *slower* than 1-2 — so when the last
-    job's median task time falls under ``gil_floor_s`` the pool shrinks
-    to 2 workers; it widens back to ``workers`` as soon as tasks are
-    long enough to release the GIL meaningfully,
   * the *sweep* (on unless ``gil_floor_s=0.0`` or a ``job_deadline_s``
     is set): a job starts on the calling thread, running its shards in
     order with no pool, futures or completion queue — for ~10-100 us
@@ -249,7 +243,6 @@ class ShardTaskExecutor:
         min_completed_for_speculation: int = 4,
         fault_hook: Optional[Callable[[int, int], None]] = None,
         min_straggler_s: float = 0.05,
-        adaptive_workers: bool = False,
         gil_floor_s: float = 1e-3,
         retry_backoff_s: float = 0.0,
         retry_backoff_cap_s: float = 1.0,
@@ -289,7 +282,6 @@ class ShardTaskExecutor:
         # duplicate healthy tasks — a backup task is only worth
         # launching for work at least as long as a scheduling quantum.
         self.min_straggler_s = min_straggler_s
-        self.adaptive_workers = adaptive_workers
         self.gil_floor_s = gil_floor_s
         self.stats: Dict[str, int] = {"retries": 0, "speculative": 0,
                                       "jobs": 0, "pool_rebuilds": 0,
@@ -315,8 +307,6 @@ class ShardTaskExecutor:
         self._pool: Optional[ThreadPoolExecutor] = None
         self._pool_size = 0
         self._pool_lock = threading.Lock()
-        self._active_jobs = 0
-        self._median_task_s: Optional[float] = None
 
     def resize(self, workers: int) -> None:
         """Elastic scaling between jobs (the warm pool is swapped on the
@@ -326,38 +316,20 @@ class ShardTaskExecutor:
     # ------------------------------------------------------------------
     # warm pool management
     # ------------------------------------------------------------------
-    def target_workers(self) -> int:
-        """Worker count the next job will run with: the configured width
-        unless adaptive granularity scaling says the tasks are too small
-        to parallelize (GIL-bound numpy ops favor 1-2 workers)."""
-        w = max(1, int(self.workers))
-        if (self.adaptive_workers and self._median_task_s is not None
-                and self._median_task_s < self.gil_floor_s):
-            w = min(w, 2)
-        return w
-
     def _acquire_pool(self) -> ThreadPoolExecutor:
-        """Check out the long-lived worker pool for one job, (re)built
-        only when the target width changed *and* no other job is using
-        it — a mid-flight swap would shut the pool down under the other
-        job's submits.  Concurrent jobs simply share the current width
-        until the executor goes idle.  Balance with ``_release_pool``."""
+        """The long-lived worker pool, (re)built only when ``workers``
+        changed since it was built (``resize``).  Jobs are serialized on
+        ``_job_lock``, so no job is using the pool when it is swapped."""
         with self._pool_lock:
-            target = self.target_workers()
-            if self._pool is None or (self._pool_size != target
-                                      and self._active_jobs == 0):
+            target = max(1, int(self.workers))
+            if self._pool is None or self._pool_size != target:
                 if self._pool is not None:
                     self._pool.shutdown(wait=False)
                 self._pool = ThreadPoolExecutor(
                     max_workers=target, thread_name_prefix="shard-worker")
                 self._pool_size = target
                 self.stats["pool_rebuilds"] += 1
-            self._active_jobs += 1
             return self._pool
-
-    def _release_pool(self) -> None:
-        with self._pool_lock:
-            self._active_jobs -= 1
 
     def close(self) -> None:
         """Tear down the warm pool (idempotent).  Call when no job is
@@ -457,13 +429,9 @@ class ShardTaskExecutor:
                                durations)
         swept = sweeping and not rest
         if rest:
-            pool = self._acquire_pool()
-            try:
-                timed_out = self._run_pooled(
-                    pool, corpus, ids, rest, fn, job, deadline, results,
-                    attempts, durations)
-            finally:
-                self._release_pool()
+            timed_out = self._run_pooled(
+                self._acquire_pool(), corpus, ids, rest, fn, job, deadline,
+                results, attempts, durations)
         missing = [s for s in ids if s not in results]
         if missing and not self.allow_partial:
             if timed_out:
@@ -473,9 +441,6 @@ class ShardTaskExecutor:
             raise ShardTaskError(f"shards never completed: {missing}")
         self.stats["lost_shards"] += len(missing)
         median_task = float(np.median(durations)) if durations else 0.0
-        if durations:
-            # feeds adaptive granularity scaling for the next job
-            self._median_task_s = median_task
         self.stats["jobs"] += 1
         self.stats["sweep_jobs"] += swept
         self.last_job = {

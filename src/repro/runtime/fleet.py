@@ -45,9 +45,10 @@ come back with it.
 
 Every operation appends an audit event (op, host, ``planned``, shards
 moved/warmed/orphaned, resulting placement epoch) to ``events`` —
-same pattern as ``BalanceAudit`` / ``BudgetAudit`` — and the serving
-bench's chaos arm replays a scripted crash → degrade → join → recover
-scenario against these records (``benchmarks/serve_bench.py``).
+same pattern as ``BalanceAudit`` / ``BudgetAudit``.
+``tests/test_chaos.py`` replays scripted crash → degrade → join →
+recover → drain scenarios against a managed fleet, and
+``tests/test_fleet.py`` checks each operation's record.
 """
 from __future__ import annotations
 
@@ -96,8 +97,6 @@ class FleetManager:
             drains=ops.count("drain"),
             crashes=ops.count("crash"),
             live_hosts=self.live_hosts(),
-            # read through the one generation authority (the stats key
-            # of the same name is the deprecated mirrored view)
             placement_epoch=int(
                 self.executor.clock.current().placement),
             generation=self.executor.clock.current().record(),

@@ -3,8 +3,8 @@
 Through PR 9 three ad-hoc signals accreted that all mean "which
 generation of the world am I reading":
 
-  * ``HostGroupExecutor.stats["placement_epoch"]`` — a bare int bumped
-    inside ``set_placement`` on every fleet membership change;
+  * a bare placement int on the host group's stats, bumped inside
+    ``set_placement`` on every fleet membership change;
   * the semantic query cache's raw-int epoch probe — every cached entry
     recorded that int and ``lookup`` fenced on inequality;
   * the megascan payload cache on ``ApproxIndex`` — keyed on the shard
@@ -32,10 +32,6 @@ thread-safe mint: readers call ``current()``; the two writers call
 creates one clock and binds every layer (executor, index, cache
 epochs, ingestor) to it; standalone constructions get a private clock
 so the API works un-wired too.
-
-Deprecated read-only views (kept for pre-generation callers, pinned by
-tests): ``stats["placement_epoch"]`` mirrors ``current().placement``
-after every bump, and the cache still accepts raw ints as epochs.
 """
 
 from __future__ import annotations

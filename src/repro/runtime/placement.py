@@ -67,7 +67,7 @@ critical-path wall time (what the window controller attributes to the
 shared scan), total task count, and the per-host breakdown (realized
 wall per host, including any injected degradation);
 ``stats["scans_per_host"]`` counts shard visits per host, which the
-serving bench checks against the residency split of the union plan
+tests check against the residency split of the union plan
 (primary-only executors — a balanced executor deliberately deviates
 from residency counts, and its audit lives in
 ``last_job["balance"]``).
@@ -270,13 +270,13 @@ class HostGroupExecutor:
     ``hosts * workers_per_host`` at the single-host width for a fair
     same-machine comparison); remaining keyword arguments are forwarded
     to every per-host ``ShardTaskExecutor`` (``fault_hook``,
-    ``max_retries``, ``adaptive_workers``, ...).  ``host_fault_hook``
+    ``max_retries``, ...).  ``host_fault_hook``
     is the *host*-granularity injection point: called as
     ``(host, shard_ids)`` before the host's scan; raising kills the
     whole host for the current job and triggers replica requeue, while
     a hook that merely sleeps simulates a degraded (hot) host — the
     delay lands in the host's wall-time telemetry, which is how the
-    bench and tests exercise the balancer.
+    tests exercise the balancer.
 
     ``balanced=True`` (or an explicit ``balancer=HostLoadModel(...)``)
     turns on replica-aware load balancing: every split goes through
@@ -338,10 +338,6 @@ class HostGroupExecutor:
             "jobs": 0, "host_jobs": 0, "host_failures": 0,
             "requeued_shards": 0, "shed_shards": 0,
             "lost_shards": 0,
-            # deprecated read-only view of clock.current().placement
-            # (pre-generation callers; pinned by tests) — never bumped
-            # directly, only mirrored after a clock mint
-            "placement_epoch": self.clock.current().placement,
             "scans_per_host": [0] * placement.n_hosts,
         }
         self.last_job: Optional[Dict[str, Any]] = None
@@ -391,8 +387,7 @@ class HostGroupExecutor:
         if self.balancer is not None:
             self.balancer.ensure_hosts(placement.n_hosts)
         self.placement = placement
-        # the clock is the mint; stats carries the deprecated view
-        self.stats["placement_epoch"] = self.clock.bump_placement().placement
+        self.clock.bump_placement()
 
     # ------------------------------------------------------------------
     # coordinator pool (one slot per host; warm across jobs)
@@ -591,7 +586,7 @@ class HostGroupExecutor:
         }
         if audit is not None:
             # estimated (at split time) vs realized (measured) per-host
-            # makespans, for the bench's run-over-run balance audit
+            # makespans, for a run-over-run balance audit
             rec = audit.record()
             rec["realized_wall_s"] = [
                 walls.get(h, 0.0) for h in range(placement.n_hosts)]
@@ -633,7 +628,7 @@ class HostGroupExecutor:
     def residency_split(
             self, plan: Sequence[Sequence[int]]) -> Dict[int, int]:
         """{host: number of union-plan shards resident on it} — the
-        per-host scan counts one batch *should* produce (the serving
-        bench checks observed scans against this)."""
+        per-host scan counts one batch *should* produce (the tests
+        check observed scans against this)."""
         union = sorted(invert_plan(plan))
         return {h: len(g) for h, g in self.placement.split(union).items()}

@@ -44,9 +44,7 @@ Invalidation is layered:
     from the old fleet can never serve the new one, and a cached
     *estimate* computed over the old corpus can never answer a query
     over the new one.  The cache itself only ever compares epochs for
-    equality, so the deprecated raw-int probe (pre-generation callers
-    passing ``stats["placement_epoch"]``) keeps working verbatim —
-    but it cannot see content changes; that gap was the PR-10 bugfix.
+    equality.
   * **TTL** — wall-clock expiry per entry (``ttl_s``).
   * **LRU** — ``max_entries`` bound, least-recently-used evicted.
 
@@ -147,7 +145,7 @@ class _Entry:
         self.sample = sample    # core.sampling.SampleResult (the plan)
         self.plan = plan        # distinct sampled shard ids [k]
         self.result = result    # full memoized result (estimate + CI)
-        self.epoch = epoch      # Generation (or deprecated int) at insert
+        self.epoch = epoch      # Generation at insert
         self.born = born
 
 
@@ -177,8 +175,7 @@ class SemanticQueryCache:
     # ------------------------------------------------------------------
     def _valid(self, e: _Entry, epoch, now: float) -> bool:
         """Drop-on-probe validation; counts the reason.  ``epoch`` is a
-        ``Generation`` (equality compares both axes) or a deprecated
-        raw int — the cache only needs ``!=``."""
+        ``Generation``; equality compares both axes."""
         if e.epoch != epoch:
             del self._entries[e.key]
             self.stats["stale_epoch"] += 1
@@ -193,8 +190,7 @@ class SemanticQueryCache:
                rate: float, epoch) -> Tuple[str, Optional[_Entry]]:
         """("hit" | "near" | "miss", entry-or-None) for one query.
 
-        ``epoch`` is the probing engine's ``Generation`` (or a
-        deprecated raw placement int, still accepted)."""
+        ``epoch`` is the probing engine's ``Generation``."""
         now = self._clock()
         key = (sig.tobytes(), qkey, float(rate))
         e = self._entries.get(key)
@@ -226,9 +222,6 @@ class SemanticQueryCache:
                rate: float, *, probs: Optional[np.ndarray], sample,
                plan: np.ndarray, result: Any, epoch) -> None:
         key = (sig.tobytes(), qkey, float(rate))
-        # the epoch is stored as handed in (Generation or deprecated
-        # int) — validation is pure equality, so no coercion is needed
-        # and int-era callers keep their exact semantics
         self._entries[key] = _Entry(
             key, np.asarray(sig, np.uint32), sampler, float(rate),
             probs, sample, plan, result, epoch, self._clock())

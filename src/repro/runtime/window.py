@@ -24,8 +24,8 @@ open window; ``close()`` drains everything and stops the dispatcher.
 
 The win: low-traffic periods keep latency (a lone query waits at most
 the deadline, not for a full batch), high-traffic periods batch up to
-``max_batch`` and inherit the batched engine's ~6x throughput (see
-BENCH_serve.json's ``windowed`` row).
+``max_batch`` and share one planning call and one scan (the chip
+benchmark's ``wiki-phrase.closed`` cell measures it).
 
 Two optional control loops close the remaining gaps:
 

@@ -8,7 +8,6 @@ os.environ.pop("XLA_FLAGS", None)
 # described topology (tests/test_tpu_compile.py), not a device.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

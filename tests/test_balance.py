@@ -367,7 +367,7 @@ def test_balanced_query_batch_matches_single_executor_under_slow_host(
             got = engine.execute(queries, 0.5,
                                  rng=np.random.default_rng(21 + j))
             _assert_results_identical(got, want)
-        audit = engine.last_audit
+        audit = engine.last_report.balance
     # the slow host was actually detected and shed around
     assert hg.stats["shed_shards"] > 0
     # the executed split is audited on the engine

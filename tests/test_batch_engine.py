@@ -343,7 +343,7 @@ def test_budgeted_queries_plan_their_own_rates(small_corpus, built_index):
     queries = [dataclasses.replace(q, budget=budget)
                for q in _mixed_queries()]
     res = engine.execute(queries, 0.3, rng=np.random.default_rng(5))
-    audit = engine.last_budget
+    audit = engine.last_report.budget
     assert audit is not None
     assert audit["budgeted"] == len(queries)
     assert audit["pressure"] == 0.0 and audit["degraded"] == 0
@@ -380,7 +380,7 @@ def test_budget_pressure_degrades_to_floor(small_corpus, built_index):
                         ci=True)
     res = engine.execute(queries, 0.3, rng=np.random.default_rng(6),
                          pressure=1.0)
-    audit = engine.last_budget
+    audit = engine.last_report.budget
     assert audit["pressure"] == 1.0
     assert audit["at_floor"] == len(queries)
     assert all(r == pytest.approx(0.25) for r in audit["planned_rates"])
@@ -394,6 +394,83 @@ def test_budget_pressure_degrades_to_floor(small_corpus, built_index):
         if q.kind == "count":
             assert not np.isnan(r.estimate.error_bound)
     ex.close()
+
+
+def _budgeted_mixed_workload(corpus, n=48, seed=0):
+    """``n`` queries in thirds (count of one word, ``a OR b AND c``,
+    ranked top-10 over three words) over mid-frequency words, each
+    carrying a budget with floor rate 0.1: counts a relative-error
+    budget, Booleans a looser one, ranked a latency budget with an
+    error cap."""
+    from repro.runtime import QueryBudget
+    counts = np.bincount(np.concatenate([s.tokens for s in corpus.shards]),
+                         minlength=corpus.vocab_size)
+    cand = np.nonzero((counts > 50) & (counts < 1200))[0]
+    words = np.random.default_rng(seed).choice(
+        cand, min(3 * n, len(cand)), replace=False).astype(int)
+    queries = []
+    for i in range(n):
+        w = [int(words[(3 * i + j) % len(words)]) for j in range(3)]
+        if i % 3 == 0:
+            q = BatchQuery.count([w[0]],
+                                 budget=QueryBudget(max_rel_error=0.5,
+                                                    floor_rate=0.1))
+        elif i % 3 == 1:
+            q = BatchQuery.boolean(
+                parse_boolean([w[0], "or", w[1], "and", w[2]]),
+                budget=QueryBudget(max_rel_error=0.6, floor_rate=0.1))
+        else:
+            q = BatchQuery.ranked(
+                w, k=10, budget=QueryBudget(max_rel_error=0.6,
+                                            max_latency_s=0.05,
+                                            floor_rate=0.1))
+        queries.append(q)
+    return queries
+
+
+@pytest.mark.parametrize("pressure,seeds", [(0.0, range(40, 48)),
+                                            (1.0, range(48, 56))],
+                         ids=["planned", "degraded"])
+def test_budgeted_count_intervals_cover_the_exact_count(
+        small_corpus, built_index, pressure, seeds):
+    """The budget record's calibration: with budgets attached, the
+    count queries' 95% intervals cover the exact full-scan count for
+    at least 90% of answers, on a planned pass (pressure 0) and on a
+    fully degraded one (pressure 1, every query at its floor rate).
+    Eight passes an arm (128 count answers): one pass of 16 reads
+    anywhere from 0.75 to 1.0 at this size."""
+    from repro.runtime import RatePlanner
+    rate = 0.15
+    budgeted = _budgeted_mixed_workload(small_corpus)
+    plain_queries = [dataclasses.replace(q, budget=None) for q in budgeted]
+    truths = [r.estimate.value if q.kind == "count" else None
+              for q, r in zip(plain_queries, QueryBatch(
+                  small_corpus, built_index).execute(plain_queries, 1.0))]
+    engine = QueryBatch(small_corpus, built_index,
+                        planner=RatePlanner(small_corpus.n_shards), ci=True)
+    # fit the planner's error curves on served unbudgeted batches, so
+    # the budgeted pass plans from fitted dispersion rather than the
+    # cold seed (which plans a census and makes coverage vacuous)
+    for s in (33, 34):
+        engine.execute(plain_queries, rate, rng=np.random.default_rng(s))
+    covered = total = 0
+    for s in seeds:
+        res = engine.execute(budgeted, rate, rng=np.random.default_rng(s),
+                             pressure=pressure)
+        for q, r, truth in zip(budgeted, res, truths):
+            if q.kind == "count":
+                total += 1
+                covered += bool(r.estimate.covers(truth))
+        audit = engine.last_report.budget
+        assert audit["pressure"] == pressure
+        assert audit["budgeted"] == len(budgeted)
+    if pressure == 1.0:
+        assert audit["at_floor"] == len(budgeted)
+    else:
+        # planned from the fitted curves: not every query at a census
+        assert min(audit["planned_rates"]) < 1.0
+    assert total == len(seeds) * len(budgeted[::3])
+    assert covered / total >= 0.9, f"coverage {covered}/{total}"
 
 
 def test_ci_flag_adds_intervals_to_retrieval(small_corpus, built_index):

@@ -250,7 +250,9 @@ def test_chaos_scenarios_preserve_parity_and_lose_nothing(
             want = ref.execute(queries, 0.5,
                                rng=np.random.default_rng(rng_seed))
             _assert_results_identical(got, want)
-            assert engine.last_degraded is None
+            assert engine.last_report.degraded is None
             if batch in ops:
                 ops[batch]()
         assert hg.stats["lost_shards"] == 0
+        if scenario == "crash_then_join":
+            assert plan.fired["crash"] >= 1   # the kill landed

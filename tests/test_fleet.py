@@ -47,7 +47,7 @@ def test_drain_moves_residency_then_retires():
         assert ev["moved_shards"] == 4 and ev["orphaned_shards"] == 0
         assert 1 in hg.down
         assert not (hg.placement.primary == 1).any()
-        assert hg.stats["placement_epoch"] == 1
+        assert hg.clock.current().placement == 1
         assert fleet.live_hosts() == [0, 2]
         # serving continues on the survivors, nothing lost
         out = _ids(corpus, hg)
@@ -171,7 +171,7 @@ def test_all_replicas_dead_degrades_to_partial_estimates(
         FleetManager(hg).crash(1)
         got = engine.execute(_queries(), 0.9,
                              rng=np.random.default_rng(1))
-        deg = engine.last_degraded
+        deg = engine.last_report.degraded
         assert deg is not None and deg["lost_shards"] > 0
         assert deg["degraded_queries"] >= 1
         # count: reduced over the surviving draws only — imprecise,
@@ -196,5 +196,5 @@ def test_healthy_fleet_reports_no_degradation(small_corpus, built_index):
                            allow_partial=True) as hg:
         engine = QueryBatch(small_corpus, built_index, executor=hg)
         got = engine.execute(_queries(), 0.9, rng=np.random.default_rng(2))
-        assert engine.last_degraded is None
+        assert engine.last_report.degraded is None
         assert all(g.lost_shards == 0 for g in got)

@@ -3,9 +3,8 @@ zero-pause RCU swap.
 
 Pins the PR-10 contracts:
 
-  * ``runtime.generation`` is the single authority — deprecated
-    integer views (``stats["placement_epoch"]``, raw-int qcache
-    epochs) are mirrors, never independently minted.
+  * ``runtime.generation`` is the single authority: placement and
+    content are minted by one clock, nowhere else.
   * CSR postings appends are bit-for-bit a from-scratch rebuild.
   * Frozen-model batch inference is bit-for-bit the per-doc path.
   * ``refresh_appended`` leaves untouched rows byte-identical and
@@ -20,8 +19,8 @@ import threading
 import numpy as np
 import pytest
 
-from repro.core.index import build_index, refresh_appended
-from repro.core.lsh import LSHConfig
+from repro.core.index import refresh_appended
+from repro.core.queries import parse_boolean
 from repro.core.queries.batch import BatchQuery, QueryBatch
 from repro.data.store import (
     DocShard,
@@ -31,11 +30,7 @@ from repro.data.store import (
     merge_postings,
     shard_postings,
 )
-from repro.launch.serve_stack import (
-    Ingestor,
-    ServeConfig,
-    build_serving_stack,
-)
+from repro.launch.serve_stack import ServeConfig, build_serving_stack
 from repro.runtime.generation import Generation, GenerationClock
 from repro.runtime.placement import HostGroupExecutor, PlacementMap
 from repro.runtime.qcache import SemanticQueryCache
@@ -63,9 +58,8 @@ def test_generation_is_hashable_value_type():
     a, b = Generation(2, 3), Generation(2, 3)
     assert a == b and hash(a) == hash(b)
     assert Generation(2, 4) != a and Generation(3, 3) != a
-    # never equal to the deprecated raw ints it replaced — a cache
-    # entry stamped with an int can't accidentally validate against a
-    # Generation probe (or vice versa)
+    # never equal to a bare int: a value stamped with an int can't
+    # validate against a Generation probe (or vice versa)
     assert Generation(1, 0) != 1
 
 
@@ -85,18 +79,17 @@ def test_clock_mints_under_concurrency():
     assert clock.current() == Generation(200, 200)
 
 
-def test_placement_epoch_is_a_mirror_of_the_clock():
-    """The deprecated ``stats["placement_epoch"]`` int is a read-only
-    view of the clock's placement axis — same values the pre-PR-10
-    ``+= 1`` produced, but minted in exactly one place."""
+def test_set_placement_bumps_the_clock_once_per_swap():
+    """Every ``set_placement`` mints exactly one placement generation
+    on the host group's clock and leaves the content axis alone."""
     pm = PlacementMap.blocked(8, 2)
     ex = HostGroupExecutor(pm, workers_per_host=1)
     try:
-        assert ex.stats["placement_epoch"] == 0
         assert ex.clock.current() == Generation(0, 0)
         ex.set_placement(PlacementMap.blocked(8, 2))
-        assert ex.stats["placement_epoch"] == 1
         assert ex.clock.current() == Generation(1, 0)
+        ex.set_placement(PlacementMap.blocked(8, 2))
+        assert ex.clock.current() == Generation(2, 0)
     finally:
         ex.close()
 
@@ -286,8 +279,6 @@ def test_engine_generation_composes_both_axes(small_corpus, built_index):
     assert engine._generation() == Generation(0, 0)
     index.clock.bump_content()
     assert engine._generation() == Generation(0, 1)
-    # no executor -> deprecated placement fallback reads 0
-    assert engine._cache_epoch() == 0
 
 
 # ---------------------------------------------------------------------------
@@ -338,8 +329,9 @@ def test_ingestor_step_swaps_and_bumps(small_corpus, pv_model,
         assert rec["appended"] == 10
         assert rec["generation"] == dict(placement=0, content=1)
         assert stack.generation == Generation(0, 1)
-        c1 = stack.engine.execute([q], 1.0)[0].estimate.value
-        assert c1 == c0 + 10  # freshness: new docs visible post-swap
+        fresh = stack.engine.execute([q], 1.0)[0].estimate
+        assert fresh.value == c0 + 10  # freshness: new docs visible
+        assert fresh.error_bound == 0.0  # and counted by a census
         assert stack.corpus is stack.engine.corpus
         assert stack.index is stack.engine.index
         ing = stack.ingestor.record()
@@ -367,14 +359,17 @@ def test_ingestor_background_source(small_corpus, pv_model, built_index):
             ingest_source=source, refresh_interval_s=0.01,
             ingest_infer_steps=2) as stack:
         assert stack.ingestor.running
-        for _ in range(500):
-            if stack.ingestor.stats["docs_appended"]:
-                break
-            threading.Event().wait(0.01)
+        # the writer has taken the batch once the source has handed it
+        # out; close() stops the writer after its in-flight step (the
+        # join below outlasts close's own bounded one on a slow host)
+        assert fed.wait(timeout=60.0)
+        writer = stack.ingestor._thread
+        stack.ingestor.close()
+        writer.join(timeout=60.0)
+        assert not writer.is_alive()
+        assert not stack.ingestor.running
         rec = stack.ingestor.record()
         assert rec["docs_appended"] == 5 and rec["errors"] == []
-        stack.ingestor.close()
-        assert not stack.ingestor.running
         stack.ingestor.close()  # idempotent
     # stack close after ingestor close is also fine (idempotent path)
 
@@ -402,7 +397,7 @@ def test_read_during_swap_is_pre_or_post_never_torn(small_corpus,
         return tuple(
             (r.estimate.value if r.estimate is not None else None,
              tuple(np.asarray(getattr(r, "doc_ids", []), np.int64)
-                   .tolist()))
+                   .tolist()), r.lost_shards)
             for r in res)
 
     def run_all(engine):
@@ -439,3 +434,90 @@ def test_read_during_swap_is_pre_or_post_never_torn(small_corpus,
     assert after == post  # the swap landed and serves fresh answers
     for s, got in observed:
         assert got == pre[s] or got == post[s], "torn batch during swap"
+
+
+# ---------------------------------------------------------------------------
+# serving around an ingest swap: an idle writer, the cache fence, and
+# placement on a host group
+# ---------------------------------------------------------------------------
+QS = [BatchQuery.count((3, 7)), BatchQuery.ranked((11, 23), k=5),
+      BatchQuery.boolean(parse_boolean([3, "or", 5, "and", 9])),
+      BatchQuery.count((5,))]
+
+
+def _same(a, b):
+    return repr(a._replace(elapsed_s=0.0)) == repr(b._replace(elapsed_s=0.0))
+
+
+def _serve(engine, seed0, rounds=3):
+    return [r for j in range(rounds)
+            for r in engine.execute(QS, 0.5, np.random.default_rng(seed0 + j))]
+
+
+def test_idle_ingestor_leaves_serving_bit_for_bit(small_corpus, pv_model,
+                                                  built_index):
+    """Attaching an Ingestor that never steps changes no answer."""
+    model, pcfg = pv_model
+    with build_serving_stack(small_corpus, built_index, hosts=2) as ref:
+        want = _serve(ref.engine, 70)
+    with build_serving_stack(
+            small_corpus, built_index, hosts=2, ingest=True,
+            ingest_model=model, ingest_pv_cfg=pcfg) as stack:
+        got = _serve(stack.engine, 70)
+        assert stack.generation == Generation(0, 0)
+    assert all(_same(g, w) for g, w in zip(got, want))
+
+
+def test_ingest_swap_fences_every_cached_entry(small_corpus, pv_model,
+                                               built_index):
+    """A warm cache serves no hit across an ingest step: every entry
+    drops as stale, and the re-served answers are a plain engine's on
+    the appended world under the same seeds."""
+    model, pcfg = pv_model
+    extra = _rand_docs(np.random.default_rng(8), 12,
+                       small_corpus.vocab_size)
+    with build_serving_stack(
+            small_corpus, built_index, cache=True, ingest=True,
+            ingest_model=model, ingest_pv_cfg=pcfg,
+            ingest_infer_steps=3) as stack:
+        cache = stack.cache
+        _serve(stack.engine, 100, rounds=1)              # populate
+        _serve(stack.engine, 140, rounds=1)              # warm re-serve
+        assert cache.stats["hits"] == len(QS)            # not vacuous
+        stack.ingestor.step(extra)
+        got = _serve(stack.engine, 180, rounds=1)
+        assert cache.stats["hits"] == len(QS)            # none crossed
+        assert cache.stats["stale_epoch"] == len(QS)
+        want = _serve(QueryBatch(stack.corpus, stack.index,
+                                 executor=stack.executor), 180, rounds=1)
+    assert all(_same(g, w) for g, w in zip(got, want))
+
+
+@pytest.mark.parametrize("shard_tokens,new_shards", [(None, 0), (256, 1)],
+                         ids=["grow", "spill"])
+def test_ingest_on_host_group_bumps_placement_only_when_shards_spill(
+        small_corpus, pv_model, built_index, shard_tokens, new_shards):
+    """On a host group the step mints one content generation, and one
+    placement generation only when appended docs spill into new shards;
+    the appended docs are counted exactly and no shard is lost."""
+    model, pcfg = pv_model
+    phrase = (small_corpus.vocab_size - 2, small_corpus.vocab_size - 1)
+    rng = np.random.default_rng(9)
+    new_docs = [np.concatenate([
+        np.asarray(phrase, np.int32),
+        rng.integers(0, small_corpus.vocab_size - 2, 40).astype(np.int32)])
+        for _ in range(12)]
+    q = BatchQuery.count(phrase)
+    with build_serving_stack(
+            small_corpus, built_index, hosts=2, ingest=True,
+            ingest_model=model, ingest_pv_cfg=pcfg, ingest_infer_steps=3,
+            ingest_shard_tokens=shard_tokens) as stack:
+        c0 = stack.engine.execute([q], 1.0)[0].estimate.value
+        rec = stack.ingestor.step(new_docs)
+        assert (rec["new_shards"] > 0) == bool(new_shards)
+        assert stack.generation == Generation(new_shards, 1)
+        fresh = stack.engine.execute([q], 1.0)[0]
+        assert fresh.estimate.value == c0 + len(new_docs)
+        assert fresh.estimate.error_bound == 0.0
+        assert all(r.lost_shards == 0 for r in _serve(stack.engine, 200))
+        assert stack.executor.stats["lost_shards"] == 0

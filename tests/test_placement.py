@@ -227,7 +227,7 @@ def test_query_batch_host_group_matches_single_executor(
         # the gathered reduce is bit-for-bit the single-executor reduce
         _assert_results_identical(got, want)
         # per-host scans match the residency split of the executed plan
-        split = hg.residency_split(engine.last_plan)
+        split = hg.residency_split(engine.last_report.plan)
         observed = {h: c for h, c in
                     enumerate(hg.stats["scans_per_host"]) if c}
         assert observed == split

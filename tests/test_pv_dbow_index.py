@@ -10,7 +10,6 @@ from repro.core.pv_dbow import (
     PVDBOWConfig,
     corpus_pairs,
     infer_doc_vector,
-    sgns_loss,
     train_pv_dbow,
 )
 
@@ -20,7 +19,7 @@ def test_training_reduces_loss(small_corpus):
     cfg = PVDBOWConfig(dim=16, steps=250, batch_pairs=2048, lr=0.01,
                        temperature=8.0)
     train_pv_dbow(small_corpus, cfg,
-                  callback=lambda s, l: losses.append(l))
+                  callback=lambda s, loss: losses.append(loss))
     assert losses[-1] < losses[0] * 0.85
 
 

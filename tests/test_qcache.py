@@ -15,6 +15,7 @@ from repro.runtime import (
     WindowController,
 )
 from repro.runtime.budget import QueryBudget, RatePlanner
+from repro.runtime.generation import Generation
 from repro.runtime.qcache import (
     QueryCacheConfig,
     SemanticQueryCache,
@@ -145,10 +146,17 @@ def test_ttl_expiry_with_injected_clock():
 
 def test_epoch_fences_entries():
     c = SemanticQueryCache(_cfg())
-    c.insert(_sig(1), ("count", (1,)), "hh", 0.4, probs=None,
-             sample=None, plan=None, result="R", epoch=3)
-    assert c.lookup(_sig(1), ("count", (1,)), "hh", 0.4, 4)[0] == "miss"
-    assert c.stats["stale_epoch"] == 1 and len(c) == 0
+    key = (_sig(1), ("count", (1,)), "hh", 0.4)
+    born = Generation(placement=3, content=0)
+    # a placement bump and a content bump each fence the entry
+    for probe in (Generation(4, 0), Generation(3, 1)):
+        c.insert(*key, probs=None, sample=None, plan=None, result="R",
+                 epoch=born)
+        assert c.lookup(*key, probe)[0] == "miss"
+    assert c.stats["stale_epoch"] == 2 and len(c) == 0
+    c.insert(*key, probs=None, sample=None, plan=None, result="R",
+             epoch=born)
+    assert c.lookup(*key, Generation(3, 0))[0] == "hit"
 
 
 def test_purge_and_record():

@@ -124,19 +124,6 @@ def test_executor_warm_pool_persists_across_jobs():
     ex.close()                                # idempotent
 
 
-def test_executor_adaptive_workers_by_task_granularity():
-    # generous floor so ~us numpy-ish tasks are unambiguously "tiny"
-    ex = ShardTaskExecutor(workers=8, adaptive_workers=True,
-                           gil_floor_s=0.02)
-    assert ex.target_workers() == 8           # no evidence yet
-    ex.map_shards(_FakeCorpus(16), range(16), lambda s: s.shard_id)
-    assert ex.target_workers() == 2           # GIL-bound tasks -> shrink
-    ex.map_shards(_FakeCorpus(4), range(4),
-                  lambda s: time.sleep(0.1) or s.shard_id)
-    assert ex.target_workers() == 8           # long tasks -> widen back
-    ex.close()
-
-
 def test_executor_context_manager_closes_pool():
     with ShardTaskExecutor(workers=2, gil_floor_s=0.0) as ex:
         ex.map_shards(_FakeCorpus(4), range(4), lambda s: 1)

@@ -1,7 +1,8 @@
 """Serving facade (launch/serve_stack) + ExecutionReport: one-call
 construction parity vs hand-built stacks, layer wiring (topology /
 planner / cache / window / fleet), config validation, and the typed
-per-batch report with its deprecated attribute shims."""
+per-batch report."""
+import dataclasses
 import json
 
 import numpy as np
@@ -68,7 +69,7 @@ def test_host_group_topology_and_fleet(small_corpus, built_index):
         assert len(got) == len(QS)
         # the fleet drives the SAME executor the engine serves from
         stack.fleet.drain(1)
-        assert stack.executor.stats["placement_epoch"] == 1
+        assert stack.executor.clock.current().placement == 1
 
 
 def test_cache_wiring_serves_hits(small_corpus, built_index):
@@ -124,7 +125,7 @@ def test_config_validation_errors(small_corpus, built_index):
 
 
 # ----------------------------------------------------------------------
-# ExecutionReport: the typed per-batch record + deprecated shims
+# ExecutionReport: the typed per-batch record
 # ----------------------------------------------------------------------
 def test_execution_report_contents_and_json(small_corpus, built_index):
     eng = QueryBatch(small_corpus, built_index)
@@ -142,24 +143,17 @@ def test_execution_report_contents_and_json(small_corpus, built_index):
     assert all(isinstance(s, int) for p in rec["plan"] for s in p)
 
 
-def test_deprecated_properties_read_through_report(small_corpus,
-                                                   built_index):
+def test_report_absent_before_first_execute_and_frozen(small_corpus,
+                                                        built_index):
     eng = QueryBatch(small_corpus, built_index)
-    # all four are None before the first execute (legacy contract)
-    assert eng.last_plan is None and eng.last_audit is None
-    assert eng.last_budget is None and eng.last_degraded is None
+    assert eng.last_report is None
     eng.execute(QS, 0.4, rng=np.random.default_rng(3))
     r = eng.last_report
-    assert [list(p) for p in eng.last_plan] == [list(p) for p in r.plan]
-    assert eng.last_audit is r.balance
-    assert eng.last_budget is r.budget
-    assert eng.last_degraded is r.degraded
-    # read-only: the grab-bag attributes can no longer be assigned
-    with pytest.raises(AttributeError):
-        eng.last_plan = []
-    # and the report itself is frozen
-    with pytest.raises(Exception):
+    assert r is not None
+    with pytest.raises(dataclasses.FrozenInstanceError):
         r.n_queries = 0
+    with pytest.raises(dataclasses.FrozenInstanceError):
+        r.plan = []
 
 
 def test_report_is_per_batch(small_corpus, built_index):
