@@ -218,9 +218,10 @@ def kernel_parity(s: Sizes, corpus, model, index, rng) -> None:
     # the kernels read the index's word-major device signatures; the
     # oracles read the same signatures row-major ([M, W])
     dev = index._fused_device_arrays()
-    planes, sig, seg = dev["planes"], dev["sig"], dev["seg"]
+    planes, sig, layout = dev["planes"], dev["sig"], dev["layout"]
     doc_sig = index._kernel_sigs(index.doc_sig, "doc")
-    sig_rows = jnp.asarray(index._shard_sorted_docs()[4])
+    _, _, _, seg, sig_rows = index._shard_sorted_docs()
+    seg, sig_rows = jnp.asarray(seg), jnp.asarray(sig_rows)
     doc_rows = jnp.asarray(index.doc_sig)
 
     with oracle:
@@ -230,8 +231,8 @@ def kernel_parity(s: Sizes, corpus, model, index, rng) -> None:
     close("asym sim", aops.asym_exp_similarity(q, doc_sig, planes, bits,
                                                temperature=beta),
           sims, rtol=3e-5)
-    close("asym segsum", aops.asym_exp_segment_sum(
-        q, sig, planes, bits, seg, n_shards, temperature=beta),
+    close("asym segsum", aops.asym_exp_layout_sum(
+        q, sig, planes, bits, layout, n_shards, temperature=beta),
         want_seg, rtol=1e-4)
     idx, vals = aops.asym_exp_topk(q, doc_sig, planes, bits, 10,
                                    temperature=beta)

@@ -26,7 +26,8 @@ Fused reductions: for doc-granular scoring the planner only consumes
 per-shard sums (M = n_docs >> n_shards), so ``shard_similarities_batch
 (..., fused=True)`` routes kernel-backed indices through the fused
 segment-sum kernels — doc signatures are fed shard-sorted and each tile
-reduces into a narrow band of shard slots in VMEM, so the [B, n_docs]
+reduces into a narrow band of shard slots in VMEM (the asym kernel
+contracts only that band: ``segsum_lanes``), so the [B, n_docs]
 intermediate never reaches HBM.  ``topk_doc_similarities_batch`` is the
 ranked analogue (fused in-kernel top-k).  The unfused ``_exp_sim_batch``
 + ``_sum_docs_to_shards_batch`` route is kept as the parity reference
@@ -51,7 +52,8 @@ from repro.core import lsh as lsh_mod
 from repro.core import pv_dbow as pv
 from repro.core.sampling import similarity_probabilities
 from repro.data.store import ShardedCorpus, atomic_savez
-from repro.kernels.common import WordMajorSigs, word_major
+from repro.kernels.common import (WordMajorSigs, segment_layout, slot_pad,
+                                  word_major)
 from repro.runtime.generation import GenerationClock
 from repro.utils import spans
 
@@ -288,17 +290,35 @@ class ApproxIndex:
         return self._shard_sims_from_vectors(self.query_vectors(queries),
                                              fused=fused)
 
+    def _fused_planning(self) -> bool:
+        """Whether batched shard scoring runs a fused segment-sum
+        kernel (doc-granular LSH scoring on the kernel path)."""
+        return (self.granularity == "doc" and self.use_lsh
+                and self.use_kernel and self.doc_sig is not None
+                and self._doc_shard_ids is not None)
+
+    @property
+    def segsum_lanes(self) -> int:
+        """Shard slots that one fused segment-sum call contracts per
+        doc tile: the asym kernel's band width over this index's slot
+        layout (``kernels/common.SegmentLayout``), every padded slot
+        for the Hamming kernel, 0 where planning runs no fused
+        kernel."""
+        if not self._fused_planning():
+            return 0
+        if self.lsh_mode != "asym":
+            return slot_pad(self.shard_vecs.shape[0])
+        return self._fused_device_arrays()["layout"].width
+
     def _shard_sims_from_vectors(self, vecs: np.ndarray, *,
                                  fused: bool = True) -> np.ndarray:
+        if fused and self._fused_planning():
+            return self._fused_doc_shard_sims_batch(vecs)
         doc_granular = self.granularity == "doc" and (
             self.doc_sig is not None or self.doc_vecs is not None)
         if not doc_granular:
             return self._exp_sim_batch(vecs, self.shard_sig,
                                        self.shard_vecs, "shard")
-        if (fused and self.use_lsh and self.use_kernel
-                and self.doc_sig is not None
-                and self._doc_shard_ids is not None):
-            return self._fused_doc_shard_sims_batch(vecs)
         doc_sims = self._exp_sim_batch(vecs, self.doc_sig,
                                        self.doc_vecs, "doc")
         return self._sum_docs_to_shards_batch(doc_sims)
@@ -309,14 +329,21 @@ class ApproxIndex:
         re-uploading (or re-laying-out) the [n_docs, W] doc signature
         database per batch would push the whole index through HBM every
         ~2 ms serving window — traffic that dwarfs the [B, n_docs]
-        intermediate the fusion saves."""
+        intermediate the fusion saves.  The sorted slot map comes as
+        ``layout`` for the asym kernel (padded to the signature rows,
+        with each doc tile's band of slots), as the plain ``seg`` for
+        the Hamming kernel."""
         dev = getattr(self, "_fused_dev", None)
         if dev is None:
             dev = dict(planes=jnp.asarray(self.planes, jnp.float32))
             if self._doc_shard_ids is not None:
                 _, _, _, seg_sorted, sig_sorted = self._shard_sorted_docs()
-                dev["seg"] = jnp.asarray(seg_sorted)
                 dev["sig"] = word_major(sig_sorted)
+                if self.lsh_mode == "asym":
+                    dev["layout"] = segment_layout(
+                        seg_sorted, self.shard_vecs.shape[0], dev["sig"])
+                else:
+                    dev["seg"] = jnp.asarray(seg_sorted)
             object.__setattr__(self, "_fused_dev", dev)
         return dev
 
@@ -329,9 +356,9 @@ class ApproxIndex:
         n_shards = self.shard_vecs.shape[0]
         if self.lsh_mode == "asym":
             from repro.kernels.asym import ops as asym_ops
-            out = asym_ops.asym_exp_segment_sum(
+            out = asym_ops.asym_exp_layout_sum(
                 jnp.asarray(vecs, jnp.float32), dev["sig"], dev["planes"],
-                self.bits, dev["seg"], n_shards,
+                self.bits, dev["layout"], n_shards,
                 temperature=self.temperature)
         else:
             from repro.kernels.hamming import ops as hamming_ops

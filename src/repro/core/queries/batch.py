@@ -124,9 +124,9 @@ from repro.utils import spans
 # the stage spans whose self seconds every ExecutionReport carries
 STAGES = ("probe", "plan", "plan_words", "plan_wait", "sample", "scan",
           "reduce", "reduce_topk")
-# retrieval work counters on every ExecutionReport (0 for a count batch)
+# planning and retrieval work counters on every ExecutionReport
 WORK_COUNTERS = ("bool_words", "bool_plans", "topk_candidates",
-                 "topk_queries")
+                 "topk_queries", "segsum_calls", "segsum_lanes")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -157,8 +157,12 @@ class ExecutionReport:
     (distinct Boolean words scored), ``bool_plans`` (calls of
     ``ApproxIndex.word_shard_similarities_batch``), ``topk_candidates``
     (candidates that entered ranked merges), ``topk_queries`` (ranked
-    queries merged) and ``group_pairs`` (the (query, shard) pairs the
-    word-driven group scan answered, ``WordScan.answered``).
+    queries merged), ``segsum_calls`` (planning calls that ran a fused
+    segment-sum kernel), ``segsum_lanes`` (the sum over those calls of
+    the shard slots the kernel contracted per doc tile,
+    ``ApproxIndex.segsum_lanes``) and ``group_pairs`` (the (query,
+    shard) pairs the word-driven group scan answered,
+    ``WordScan.answered``).
     """
     n_queries: int
     rate: float                          # nominal rate passed to execute
@@ -418,6 +422,10 @@ class QueryBatch:
                 [queries[i].word_ids() for i in vec_pos])
             for row, i in zip(sims, vec_pos):
                 rows[i] = similarity_probabilities(row)
+            lanes = index.segsum_lanes
+            if lanes:
+                work["segsum_calls"] += 1
+                work["segsum_lanes"] += lanes
         # ... and one for the union of Boolean query words
         bool_pos = [i for i, q in enumerate(queries) if q.kind == "bool"]
         if bool_pos:

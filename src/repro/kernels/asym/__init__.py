@@ -19,6 +19,7 @@ never leaves VMEM either) and ``asym_exp_topk`` keeps only per-tile
 top-k candidates for ranked retrieval.
 """
 from repro.kernels.asym.ops import (  # noqa: F401
+    asym_exp_layout_sum,
     asym_exp_segment_sum,
     asym_exp_similarity,
     asym_exp_topk,

@@ -41,10 +41,14 @@ variants keep that intermediate in VMEM:
     into a [TB, S] segment-sum block via a one-hot matmul against the
     doc→shard-slot map (an MXU pass), accumulating across the M grid
     axis in the output block that stays resident in VMEM.  The one-hot
-    is built [S, TM] (slots on sublanes, docs on lanes, the layout the
-    [1, TM] slot row broadcasts into) and contracted on its lane axis.
-    Docs are expected shard-sorted, so each TM tile's one-hot columns
-    hit a narrow band of shard slots; only [B, S] ever reaches HBM.
+    is built [W, TM] (slots on sublanes, docs on lanes, the layout the
+    [1, TM] slot row broadcasts into) and contracted on its lane axis,
+    over each tile's window of W slots: docs come shard-sorted, so a
+    tile's slots lie in a narrow band, and W and the windows' starts
+    are made once from the slot map (``common.SegmentLayout``; the
+    starts arrive by scalar prefetch).  Called without starts, W = S
+    and every window is the whole slot axis.  Only [B, S] ever
+    reaches HBM.
   * ``asym_topk_kernel`` reduces each tile to its per-tile top-k
     (values + global doc indices) with a lane bitonic sort
     (``bitonic_sort_desc``: Mosaic has no in-kernel ``lax.top_k``);
@@ -53,8 +57,10 @@ variants keep that intermediate in VMEM:
 
 VMEM budget per grid step (defaults TB=8, TM=256, bits=256, dim<=128,
 S<=2048): q 4 KiB + planes 128 KiB + packed db 8 KiB + unpacked signs
-256 KiB + one-hot 2 MiB + out 64 KiB — well under the 16 MiB scoped
-VMEM of a v5e core.
+256 KiB + one-hot W x TM x 4 bytes (2 MiB at the full width W = S,
+256 KiB at a band of 256) + out 64 KiB — well under the 16 MiB scoped
+VMEM of a v5e core.  The window starts sit in SMEM, one
+int32 per TM tile (~3 KiB at 200k docs).
 """
 from __future__ import annotations
 
@@ -64,8 +70,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.blocks import F32, segsum_block, topk_block
+from repro.kernels.blocks import F32, segsum_band_block, topk_block
 
 
 def _unpack_signs(db_t: jax.Array, bits: int) -> jax.Array:
@@ -106,17 +113,19 @@ def _asym_sim_kernel(q_ref, planes_ref, db_ref, out_ref, *, bits: int,
                                 bits, temperature)
 
 
-def _asym_segsum_kernel(q_ref, planes_ref, db_ref, seg_ref, out_ref, *,
-                        bits: int, temperature: float):
+def _asym_segsum_kernel(starts_ref, q_ref, planes_ref, db_ref, seg_ref,
+                        out_ref, *, bits: int, temperature: float,
+                        width: int):
     """One (TB, TM) tile reduced into the resident [TB, S] output.
 
     ``seg_ref`` holds the shard slot of each doc column (out-of-range
-    slots for padding docs).  The segment sum is a one-hot matmul: with
-    docs shard-sorted the [S, TM] one-hot matrix is a narrow diagonal
-    band, but correctness does not depend on the ordering.  The output
-    block's index map ignores the M grid axis, so it stays in VMEM and
-    accumulates across all ceil(M/TM) steps — the [B, M] intermediate
-    never reaches HBM."""
+    slots for padding docs).  The segment sum is a one-hot matmul over
+    the tile's window of ``width`` slots from ``starts_ref[j]``: with
+    docs shard-sorted that window is a narrow band, and with width = S
+    (every start 0) it is the whole slot axis, so correctness does not
+    depend on the ordering.  The output block's index map ignores the
+    M grid axis, so it stays in VMEM and accumulates across all
+    ceil(M/TM) steps — the [B, M] intermediate never reaches HBM."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -125,7 +134,7 @@ def _asym_segsum_kernel(q_ref, planes_ref, db_ref, seg_ref, out_ref, *,
 
     tile = exp_sim_tile(q_ref[...], planes_ref[...], db_ref[...], bits,
                         temperature)
-    segsum_block(tile, seg_ref[...], out_ref)
+    segsum_band_block(tile, seg_ref[...], out_ref, starts_ref[j], width)
 
 
 def _asym_topk_kernel(q_ref, planes_ref, db_ref, vals_ref, idx_ref, *,
@@ -180,8 +189,9 @@ def asym_similarity_kernel(
     )(q, planes, db_t)
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "n_segments", "tb", "tm",
-                                             "interpret", "temperature"))
+@functools.partial(jax.jit, static_argnames=("bits", "n_segments", "width",
+                                             "tb", "tm", "interpret",
+                                             "temperature"))
 def asym_segment_sum_kernel(
     q: jax.Array,            # [B, dim] float32, rows unit-normalized
     planes: jax.Array,       # [bits, dim] float32
@@ -190,6 +200,8 @@ def asym_segment_sum_kernel(
     bits: int,
     n_segments: int,         # S (lane-padded by the ops wrapper)
     *,
+    starts: "jax.Array | None" = None,   # [M / tm] int32 window starts
+    width: "int | None" = None,          # slots in each tile's window
     tb: int = 8,
     tm: int = 256,
     interpret: bool = False,
@@ -199,27 +211,41 @@ def asym_segment_sum_kernel(
 
     The M axis is the innermost grid dimension and the output block's
     index map ignores it, so each [TB, S] block accumulates in VMEM
-    across the whole M sweep (the classic K-reduction matmul layout)."""
+    across the whole M sweep (the classic K-reduction matmul layout).
+    Tile j's one-hot spans slots ``[starts[j], starts[j] + width)``;
+    each start is a multiple of 128 with ``start + width <= S``, and
+    the window must hold every real slot of its tile
+    (``common.segment_layout`` makes both).  Without ``starts`` every
+    window is the whole slot axis."""
     b, dim = q.shape
     w, m = db_t.shape
     assert w * 32 >= bits, (w, bits)
+    n_tiles = pl.cdiv(m, tm)
+    if starts is None:
+        starts, width = jnp.zeros((n_tiles,), jnp.int32), n_segments
+    assert width % 128 == 0 and width <= n_segments, (width, n_segments)
+    assert starts.shape == (n_tiles,), (starts.shape, m, tm)
     kernel = functools.partial(_asym_segsum_kernel, bits=int(bits),
-                               temperature=float(temperature))
-    grid = (pl.cdiv(b, tb), pl.cdiv(m, tm))
+                               temperature=float(temperature),
+                               width=int(width))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(pl.cdiv(b, tb), n_tiles),
+        in_specs=[
+            pl.BlockSpec((tb, dim), lambda i, j, s: (i, 0)),
+            pl.BlockSpec((planes.shape[0], dim), lambda i, j, s: (0, 0)),
+            pl.BlockSpec((w, tm), lambda i, j, s: (0, j)),
+            pl.BlockSpec((1, tm), lambda i, j, s: (0, j)),
+        ],
+        out_specs=pl.BlockSpec((tb, n_segments), lambda i, j, s: (i, 0)),
+    )
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tb, dim), lambda i, j: (i, 0)),
-            pl.BlockSpec((planes.shape[0], dim), lambda i, j: (0, 0)),
-            pl.BlockSpec((w, tm), lambda i, j: (0, j)),
-            pl.BlockSpec((1, tm), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((tb, n_segments), lambda i, j: (i, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_segments), jnp.float32),
         interpret=interpret,
         name="asym_segment_sum_kernel",
-    )(q, planes, db_t, seg_ids)
+    )(starts, q, planes, db_t, seg_ids)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "k", "m_total", "tb",

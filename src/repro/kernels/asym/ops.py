@@ -4,10 +4,11 @@ Off-TPU the Pallas bodies run in interpret mode; on TPU the same
 BlockSpecs compile to Mosaic.  Signatures come word-major and
 tile-aligned (``common.WordMajorSigs``, made once per signature set);
 query rows are normalized and padded to tile multiples here, so the
-kernels never see ragged blocks.  The fused-reduction wrappers
-additionally pad the segment axis to lane multiples and give padding
-docs an out-of-range segment slot so they cannot contribute to any
-sum.
+kernels never see ragged blocks.  The segment sum reads its slot map
+as a ``common.SegmentLayout`` (made once per map by the caller, or
+per call here): the segment axis padded to lane multiples, padding docs at an
+out-of-range slot so they cannot contribute to any sum, and the window
+of slots each row tile reduces into.
 """
 from __future__ import annotations
 
@@ -15,8 +16,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.asym import kernel as _k
-from repro.kernels.common import (WordMajorSigs, interpret_mode,
-                                  next_pow2, on_tpu, pad_rows, sig_tile)
+from repro.kernels.common import (SegmentLayout, WordMajorSigs,
+                                  interpret_mode, next_pow2, on_tpu,
+                                  pad_rows, segment_layout, sig_tile)
 
 
 def _prep_queries(query_vecs: jax.Array, tb: int):
@@ -56,20 +58,32 @@ def asym_exp_segment_sum(query_vecs: jax.Array, db: WordMajorSigs,
     doc -> segment slot map, int, [M]).  The [B, M] similarity matrix
     stays in VMEM tile-by-tile and never reaches HBM.
 
-    Rows of ``db`` should be segment-sorted so each TM tile
-    reduces into a narrow band of slots (correctness holds for any
-    order).  The segment axis is padded to a lane multiple in-kernel
-    and sliced back here; padding docs get the out-of-range slot
-    ``s_pad``, so they contribute to nothing."""
-    q, b, tb = _prep_queries(query_vecs, tb)
-    s_pad = max(128, -(-int(n_segments) // 128) * 128)
-    seg = jnp.asarray(seg_ids, jnp.int32).reshape(1, -1)
-    seg = jnp.pad(seg, ((0, 0), (0, db.t.shape[1] - db.n)),
-                  constant_values=s_pad)
-    out = _k.asym_segment_sum_kernel(
-        q, jnp.asarray(planes, jnp.float32), db.t, seg, bits, s_pad,
-        tb=tb, tm=sig_tile(db, tm), interpret=interpret_mode(),
+    The map is laid out here, on every call; a caller that scores
+    against one map many times lays it out once and calls
+    ``asym_exp_layout_sum``."""
+    return asym_exp_layout_sum(
+        query_vecs, db, planes, bits,
+        segment_layout(seg_ids, n_segments, db, tm), n_segments, tb=tb,
         temperature=temperature)
+
+
+def asym_exp_layout_sum(query_vecs: jax.Array, db: WordMajorSigs,
+                        planes: jax.Array, bits: int, layout: SegmentLayout,
+                        n_segments: int,
+                        *, tb: int = 8,
+                        temperature: float = 1.0) -> jax.Array:
+    """``asym_exp_segment_sum`` over a slot map already laid out over
+    ``db`` (``common.segment_layout``; its tile is the row tile).  Rows
+    of ``db`` should be segment-sorted: each TM tile then reduces into
+    a narrow window of slots, and the kernel contracts only that
+    window.  Correctness holds for any order (the window then spans
+    every slot).  The output is sliced back to ``n_segments`` here."""
+    q, b, tb = _prep_queries(query_vecs, tb)
+    out = _k.asym_segment_sum_kernel(
+        q, jnp.asarray(planes, jnp.float32), db.t, layout.seg, bits,
+        layout.s_pad, starts=layout.starts, width=layout.width, tb=tb,
+        tm=layout.seg.shape[1] // layout.starts.shape[0],
+        interpret=interpret_mode(), temperature=temperature)
     return out[:b, :n_segments]
 
 

@@ -5,7 +5,9 @@ the same two ways, and must do so with the same ops so that their
 results agree bit for bit across schedules:
 
   * ``segsum_block`` — a one-hot MXU dot of a [TB, TM] tile into the
-    resident [TB, S] per-segment output;
+    resident [TB, S] per-segment output, and ``segsum_band_block``, the
+    same dot over only the lane-aligned window of W slots that a
+    segment-sorted tile touches;
   * ``topk_block`` — a lane bitonic sort of a [TB, TM] tile
     (``bitonic_sort_desc``; Mosaic has no in-kernel ``lax.top_k``)
     whose first K lanes are the tile's top-k candidates.
@@ -22,6 +24,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 F32 = jax.lax.Precision.HIGHEST
@@ -36,6 +39,25 @@ def segsum_block(tile: jax.Array, seg: jax.Array, out_ref) -> None:
         jnp.int32, (out_ref.shape[1], seg.shape[1]), 0) == seg
               ).astype(jnp.float32)                         # [S, TM]
     out_ref[...] += jax.lax.dot_general(
+        tile, onehot, (((1,), (1,)), ((), ())), precision=F32,
+        preferred_element_type=jnp.float32)
+
+
+def segsum_band_block(tile: jax.Array, seg: jax.Array, out_ref, start,
+                      width: int) -> None:
+    """``segsum_block`` over the window ``[start, start + width)`` of
+    the resident [TB, S] output: the one-hot is [width, TM], its row r
+    standing for slot ``start + r``, and the [TB, width] product is
+    added into that window alone.  ``start`` is a multiple of 128 (a
+    lane-aligned store) and ``start + width <= S``; a row whose slot
+    lies outside the window adds nothing, so the window must hold every
+    real slot of the tile for the sums to be whole.  Every column left
+    out would receive an exact float zero from the full one-hot."""
+    start = pl.multiple_of(start, 128)
+    onehot = (jax.lax.broadcasted_iota(
+        jnp.int32, (width, seg.shape[1]), 0) + start == seg
+              ).astype(jnp.float32)                         # [W, TM]
+    out_ref[:, pl.ds(start, width)] += jax.lax.dot_general(
         tile, onehot, (((1,), (1,)), ((), ())), precision=F32,
         preferred_element_type=jnp.float32)
 

@@ -2,9 +2,10 @@
 
 Every kernel's public wrapper needs the same things: one decision on
 whether Pallas bodies compile for the TPU or run in the interpreter,
-row padding to tile multiples so kernels never see ragged blocks, and
-the word-major signature layout the scoring kernels read.  One copy
-here keeps the wrappers in sync.
+row padding to tile multiples so kernels never see ragged blocks, the
+word-major signature layout the scoring kernels read, and the segment
+layout the banded segment sum reads.  One copy here keeps the wrappers
+in sync.
 """
 from __future__ import annotations
 
@@ -14,6 +15,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _log = logging.getLogger(__name__)
 
@@ -79,6 +81,60 @@ def sig_tile(db: WordMajorSigs, tm: int) -> int:
         raise ValueError(f"row tile {tm} does not divide the "
                          f"{mp} padded signature rows")
     return tm
+
+
+LANES = 128
+
+
+def slot_pad(n_segments: int) -> int:
+    """Segment slots the segment-sum kernels reduce into: the count
+    rounded up to a lane multiple."""
+    return max(LANES, -(-int(n_segments) // LANES) * LANES)
+
+
+class SegmentLayout(NamedTuple):
+    """A doc -> segment-slot map in the layout the banded segment-sum
+    kernel reads, made once per slot map by ``segment_layout``.
+
+    ``seg`` is the [1, M'] int32 slot row over the padded signature
+    rows, padding docs at the out-of-range slot ``s_pad`` (the slot
+    count rounded up to a lane multiple).  Row tile j (``tm`` rows;
+    tm = M' / len(starts)) reduces into the window of ``width`` slots
+    from ``starts[j]``: every start a multiple of 128, every window
+    inside ``[0, s_pad)`` and holding all of its tile's real slots.
+    Segment-sorted docs give a narrow band; any other order gives
+    ``width == s_pad``, every start 0: the full one-hot."""
+    seg: jax.Array
+    starts: jax.Array
+    width: int
+    s_pad: int
+
+
+def segment_layout(seg_ids, n_segments: int, db: WordMajorSigs,
+                   tm: int = 256) -> SegmentLayout:
+    """``SegmentLayout`` of the slot map ``seg_ids`` (one int per row
+    of ``db``) over ``db``'s padded rows, cut into tiles of
+    ``sig_tile(db, tm)``.  Each tile's window starts at its lowest real
+    slot rounded down to a lane multiple; ``width`` is the least lane
+    multiple that reaches every tile's highest real slot from there,
+    capped at ``s_pad``, and starts are then clamped to ``s_pad -
+    width``.  Host numpy, one pass over the map."""
+    ids = np.asarray(seg_ids, np.int32).ravel()
+    if ids.size != db.n:
+        raise ValueError(f"{ids.size} slots for {db.n} signature rows")
+    s_pad = slot_pad(n_segments)
+    seg = np.full(int(db.t.shape[1]), s_pad, np.int32)
+    seg[:ids.size] = ids
+    tiles = seg.reshape(-1, sig_tile(db, tm))
+    real = tiles < s_pad
+    lo = np.where(real, tiles, s_pad).min(axis=1)
+    hi = np.where(real, tiles, -1).max(axis=1)
+    base = np.where(real.any(axis=1), lo // LANES * LANES, 0)
+    reach = int((hi - base + 1).max())
+    width = min(s_pad, max(LANES, -(-reach // LANES) * LANES))
+    starts = np.minimum(base, s_pad - width).astype(np.int32)
+    return SegmentLayout(jnp.asarray(seg[None, :]), jnp.asarray(starts),
+                         width, s_pad)
 
 
 def next_pow2(n: int) -> int:

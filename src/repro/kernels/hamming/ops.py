@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.common import (WordMajorSigs, interpret_mode, pad_rows,
-                                  sig_tile)
+                                  sig_tile, slot_pad)
 from repro.kernels.hamming import kernel as _k
 
 
@@ -54,7 +54,7 @@ def hamming_segment_similarity(q_packed: jax.Array, db: WordMajorSigs,
     n = q_packed.shape[0]
     tn = min(tn, max(1, n))
     q = pad_rows(jnp.asarray(q_packed, jnp.uint32), tn)
-    s_pad = max(128, -(-int(n_segments) // 128) * 128)
+    s_pad = slot_pad(n_segments)
     seg = jnp.asarray(seg_ids, jnp.int32).reshape(1, -1)
     seg = jnp.pad(seg, ((0, 0), (0, db.t.shape[1] - db.n)),
                   constant_values=s_pad)

@@ -69,7 +69,8 @@ worth.  Beside the batch and close-reason counts:
     sample, scan, reduce, reduce_topk) and ``scan_cpu_us`` /
     ``shard_visits`` / ``plan_shards`` / ``scan_sweep`` /
     ``bool_words`` / ``bool_plans`` / ``topk_candidates`` /
-    ``topk_queries`` its counters, once per batch that reports them.
+    ``topk_queries`` / ``segsum_calls`` / ``segsum_lanes`` its
+    counters, once per batch that reports them.
 
 Each batch runs under the profiler span ``emapprox.window`` (``n``,
 ``reason`` and the engine's ``batch`` number), the parent of the

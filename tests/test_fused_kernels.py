@@ -11,7 +11,8 @@ import pytest
 from repro.core import lsh as lsh_mod
 from repro.kernels.asym import ops as aops
 from repro.kernels.asym import ref as aref
-from repro.kernels.common import SIG_ALIGN, sig_tile, word_major
+from repro.kernels.common import (SIG_ALIGN, segment_layout, sig_tile,
+                                  slot_pad, word_major)
 from repro.kernels.hamming import ops as hops
 from repro.kernels.hamming import ref as href
 
@@ -68,6 +69,113 @@ def test_asym_segment_sum_empty_and_unsorted_segments():
     want = np.stack([np.bincount(seg, weights=row, minlength=s)
                      for row in sims])
     np.testing.assert_allclose(got, want, rtol=1e-4)
+
+
+def _bincount_ref(q, db, planes, bits, seg, s, temp):
+    """The unfused [B, M] matrix, reduced per segment by np.bincount."""
+    sims = np.asarray(aref.asym_exp_similarity_ref(q, db, planes, bits,
+                                                   temp), np.float64)
+    return np.stack([np.bincount(seg, weights=row, minlength=s)
+                     for row in sims])
+
+
+def _runs(slots, counts):
+    return np.repeat(np.asarray(slots, np.int32), counts)
+
+
+# case -> (queries, segments, sorted slot map of the docs, band width)
+BAND_CASES = {
+    # tile 1 holds slots 125-151: its band crosses the 128-slot line
+    "straddles_128": (3, 300, _runs(np.arange(100, 160), 10), 256),
+    # 700 docs: tile 2 ends in 68 padding docs, tile 3 is all padding
+    "padded_last_tile": (4, 500, np.sort(np.random.default_rng(1).integers(
+        300, 360, 700)).astype(np.int32), 128),
+    # slots 3, 40, 90, 126 in tile 0 (empty runs between), 700-705 next
+    "empty_runs": (2, 1000, _runs([3, 40, 90, 126, 700, 705],
+                                  [100, 100, 40, 16, 200, 56]), 128),
+    # 19 queries: three row tiles of 8
+    "many_queries": (19, 300, np.sort(np.random.default_rng(2).integers(
+        0, 300, 1000)).astype(np.int32), 256),
+    # 37 segments, one lane's worth of slots
+    "under_128_slots": (5, 37, np.sort(np.random.default_rng(3).integers(
+        0, 37, 613)).astype(np.int32), 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BAND_CASES))
+def test_asym_segment_sum_band_matches_unfused(case):
+    """The banded kernel contracts each doc tile's one-hot over only its
+    window of slots, and sums exactly what the full one-hot did."""
+    b, s, seg, width = BAND_CASES[case]
+    m = seg.size
+    rng, q, planes, db = _asym_setup(b, m, 32, 128, seed=m + s)
+    wm = word_major(db)
+    lay = segment_layout(seg, s, wm)
+    assert lay.width == width < 2048
+    want = _bincount_ref(q, db, planes, 128, seg, s, 4.0)
+    # laid out once, or per call from the plain map
+    for got in (aops.asym_exp_layout_sum(q, wm, planes, 128, lay, s,
+                                         temperature=4.0),
+                aops.asym_exp_segment_sum(q, wm, planes, 128, seg, s,
+                                          temperature=4.0)):
+        assert got.shape == (b, s)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4)
+    ref = aref.asym_exp_segment_sum_ref(q, db, planes, 128, seg, s, 4.0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-4)
+
+
+def test_asym_segment_sum_unsorted_layout_spans_every_slot():
+    """Docs in no slot order: no tile's band is narrower than the whole
+    padded slot axis, so the kernel is the full-width one, and the sums
+    still match."""
+    rng, q, planes, db = _asym_setup(4, 1024, 32, 128, seed=7)
+    seg = rng.integers(0, 1000, 1024).astype(np.int32)
+    wm = word_major(db)
+    lay = segment_layout(seg, 1000, wm)
+    assert lay.width == lay.s_pad == 1024
+    assert not np.asarray(lay.starts).any()
+    got = aops.asym_exp_layout_sum(q, wm, planes, 128, lay, 1000)
+    want = _bincount_ref(q, db, planes, 128, seg, 1000, 1.0)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4)
+
+
+_MAP_RNG = np.random.default_rng(5)
+# name -> (sorted slot map, segments)
+SORTED_MAPS = {
+    "uniform": (np.sort(_MAP_RNG.integers(0, 2000, 20_000)), 2000),
+    # zero-size runs: empty slots between the occupied ones
+    "ragged": (_runs(np.arange(500), _MAP_RNG.integers(0, 200, 500)), 500),
+    "one_slot": (np.full(3000, 77), 80),
+    "tail_slots": (np.sort(_MAP_RNG.integers(1900, 1950, 4000)), 1950),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SORTED_MAPS))
+def test_segment_layout_windows_are_aligned_minimal_covers(name):
+    seg, s = SORTED_MAPS[name]
+    seg = np.asarray(seg, np.int32)
+    wm = word_major(np.zeros((seg.size, 4), np.uint32))
+    tm = sig_tile(wm, 256)
+    lay = segment_layout(seg, s, wm)
+    starts = np.asarray(lay.starts)
+    row = np.asarray(lay.seg)[0]
+    assert lay.s_pad == max(128, -(-s // 128) * 128)
+    assert row.shape == (wm.t.shape[1],)
+    np.testing.assert_array_equal(row[:seg.size], seg)
+    assert (row[seg.size:] == lay.s_pad).all()
+    assert starts.shape == (row.size // tm,)
+    assert lay.width % 128 == 0 and 128 <= lay.width <= lay.s_pad
+    assert (starts % 128 == 0).all()
+    assert (starts >= 0).all() and (starts + lay.width <= lay.s_pad).all()
+    tiles = row.reshape(-1, tm)
+    reach = 0
+    for t, start in zip(tiles, starts):
+        real = t[t < lay.s_pad]
+        if real.size:
+            assert (real >= start).all() and (real < start + lay.width).all()
+            reach = max(reach, real.max() - real.min() // 128 * 128 + 1)
+    # no narrower lane multiple covers every tile from its aligned start
+    assert lay.width == 128 or lay.width - 128 < reach
 
 
 @pytest.mark.parametrize("n,m,s,bits,temp", [
@@ -150,6 +258,31 @@ def test_index_fused_shard_sims_match_unfused(small_corpus, built_index,
     unfused = idx.shard_similarities_batch(QUERIES, fused=False)
     assert fused.shape == (len(QUERIES), small_corpus.n_shards)
     np.testing.assert_allclose(fused, unfused, rtol=1e-4)
+
+
+@pytest.mark.parametrize("lsh_mode", ["asym", "sym"])
+def test_index_segsum_lanes_reads_its_slot_layout(small_corpus, built_index,
+                                                  lsh_mode):
+    """The index's one-hot width per doc tile: the asym kernel's band
+    over the shard-sorted slot map, every padded slot for the Hamming
+    kernel, 0 where planning runs no fused kernel."""
+    idx = _doc_kernel_index(built_index, small_corpus, lsh_mode)
+    dev = idx._fused_device_arrays()
+    _, _, _, seg_sorted, _ = idx._shard_sorted_docs()
+    s_pad = slot_pad(small_corpus.n_shards)
+    if lsh_mode == "asym":
+        lay = dev["layout"]
+        np.testing.assert_array_equal(
+            np.asarray(lay.seg)[0, :seg_sorted.size], seg_sorted)
+        assert lay.s_pad == s_pad and "seg" not in dev
+        want = lay.width
+    else:                    # the Hamming kernel reads the plain map
+        np.testing.assert_array_equal(np.asarray(dev["seg"]), seg_sorted)
+        assert "layout" not in dev
+        want = s_pad
+    assert idx.segsum_lanes == want > 0
+    assert built_index.segsum_lanes == 0         # shard-granular
+    assert dataclasses.replace(idx, use_kernel=False).segsum_lanes == 0
 
 
 def test_index_fused_matches_single_query_loop(small_corpus, built_index):

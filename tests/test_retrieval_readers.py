@@ -18,13 +18,15 @@ from bench.trace import Trace  # noqa: E402
 STATS = dict(batches=20, served=50, plan_words_us=60_000,
              reduce_topk_us=140_000, topk_candidates=1_500_000,
              topk_queries=30, bool_words=180, bool_plans=12,
-             plan_shards=40_000, group_pairs=30_000)
+             plan_shards=40_000, group_pairs=30_000, segsum_calls=14,
+             segsum_lanes=14 * 256)
 
 READERS = {  # metric -> (value on STATS, counter whose denominator is 0)
     "plan_words_ms.retrieval": (3.0, "batches"),
     "topk_ms.retrieval": (7.0, "batches"),
     "topk_cands.retrieval": (50_000.0, "topk_queries"),
     "group_share.retrieval": (0.75, "plan_shards"),
+    "segsum_lanes.retrieval": (256.0, "segsum_calls"),
 }
 SIZES = dict(n_docs=200_000, n_shards=1939, bits=256, dim=100)
 
@@ -66,6 +68,21 @@ def test_reader_gives_none_without_the_counters(name):
                reduce_us=5_000, scan_us=60_000, shard_visits=40)
     trace = _trace([("asym_similarity_kernel.1", 0.0, 2e-5)])
     assert observe.reader(name)(_obs(old, trace)) is None
+
+
+def test_segsum_lanes_reads_the_same_mean_in_both_open_loop_cells():
+    """One reader serves ``segsum_lanes.open`` and ``.retrieval``: the
+    mean band width a fused planning call contracted."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cells = {m["name"]: m["workloads"] for m in bench["per_layer"]
+             if m["name"].startswith("segsum_lanes.")}
+    assert cells == {"segsum_lanes.open": ["wiki-phrase.steady"],
+                     "segsum_lanes.retrieval": ["wiki-retrieval.steady"]}
+    stats = dict(batches=9, served=12, segsum_calls=8,
+                 segsum_lanes=3 * 128 + 5 * 256)
+    for name in cells:
+        assert observe.reader(name)(_obs(stats)) == pytest.approx(208.0)
 
 
 def test_wordsim_cost_counts_the_algorithm():

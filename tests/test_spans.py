@@ -96,6 +96,7 @@ def test_report_holds_six_stages_within_elapsed(small_corpus, built_index):
                                     "scan_cpu_us", "scan_sweep",
                                     "bool_words", "bool_plans",
                                     "topk_candidates", "topk_queries",
+                                    "segsum_calls", "segsum_lanes",
                                     "group_pairs"}
 
 
@@ -127,6 +128,27 @@ def test_count_only_batch_counts_no_retrieval_work(small_corpus,
                 "topk_queries"):
         assert r.counters[key] == 0, key
     assert r.stages["plan_words"] == 0.0 and r.stages["reduce_topk"] == 0.0
+
+
+def test_fused_planning_batch_counts_one_segsum_call(small_corpus,
+                                                     built_index):
+    """A batch whose counts and ranked queries are planned by the fused
+    segment-sum kernel adds one call and the kernel's band width."""
+    idx = _kernel_index(built_index, small_corpus)
+    eng = QueryBatch(small_corpus, idx)
+    eng.execute(QS, 0.4, rng=np.random.default_rng(8))
+    r = eng.last_report
+    assert r.counters["segsum_calls"] == 1
+    assert r.counters["segsum_lanes"] == idx.segsum_lanes > 0
+
+
+def test_boolean_only_batch_counts_no_segsum_call(small_corpus,
+                                                  built_index):
+    eng = QueryBatch(small_corpus, _kernel_index(built_index, small_corpus))
+    eng.execute([QS[2]], 0.4, rng=np.random.default_rng(9))
+    r = eng.last_report
+    assert r.counters["bool_plans"] == 1
+    assert r.counters["segsum_calls"] == r.counters["segsum_lanes"] == 0
 
 
 def test_counters_union_and_sum_of_plan(small_corpus, built_index):

@@ -29,6 +29,7 @@ DIM, BITS, W = 100, 256, 8
 N_DOCS, SLOTS, SLOT_PAD = 200_000, 1_950, 2_048
 B = 32                          # one serving window of queries
 TM, KP = 256, 128               # scoring tile, lane-padded top-k
+BAND = 256                      # slots a shard-sorted doc tile touches
 
 
 def _pad(n, m):
@@ -66,6 +67,17 @@ def test_asym_similarity_compiles(shape):
 
 def test_asym_segment_sum_compiles(shape):
     _compile(shape, "asym_segment_sum_kernel")
+
+
+def test_asym_segment_sum_band_compiles(shape):
+    """The planner's banded segment sum (a 256-slot window per doc
+    tile, its start by scalar prefetch, a lane-dim slice of the
+    resident output) compiles, under the full-width kernel's name: the
+    trace finds the planner's kernel by it."""
+    fn, args, static = _cases(shape)["asym_segment_sum_band_kernel"]
+    lowered = fn.lower(*args, **static)
+    assert 'kernel_name = "asym_segment_sum_kernel"' in lowered.as_text()
+    assert "tpu_custom_call" in lowered.compile().as_text()
 
 
 def test_asym_topk_compiles(shape):
@@ -124,6 +136,11 @@ def _cases(shape):
                                     (q, planes, db, seg),
                                     dict(bits=BITS, n_segments=SLOT_PAD,
                                          tm=TM, **common)),
+        "asym_segment_sum_band_kernel": (
+            asym_k.asym_segment_sum_kernel, (q, planes, db, seg),
+            dict(bits=BITS, n_segments=SLOT_PAD, tm=TM,
+                 starts=shape((m // TM,), jnp.int32), width=BAND,
+                 **common)),
         "asym_topk_kernel": (asym_k.asym_topk_kernel, (q, planes, db),
                              dict(bits=BITS, k=KP, m_total=N_DOCS, tm=TM,
                                   **common)),
